@@ -623,7 +623,9 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     from repro.kernels import autotune
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     unknown = [s for s in args.sections if s not in SECTIONS]
     if unknown:
         p.error(f"unknown sections {unknown}; choose from {list(SECTIONS)}")

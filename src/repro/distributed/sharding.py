@@ -19,20 +19,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 _state = threading.local()
 
-# jax >= 0.5 exposes `jax.shard_map(..., check_vma=)`; 0.4.x has the
-# experimental module with the same semantics under `check_rep=`.
-try:
-    _shard_map_impl = jax.shard_map
-    _SM_CHECK_KW = "check_vma"
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _SM_CHECK_KW = "check_rep"
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable shard_map with replication checking off by default."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_SM_CHECK_KW: check})
+    """``jax.shard_map`` with replication checking off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def current_mesh() -> Optional[Mesh]:

@@ -282,7 +282,8 @@ def tune(op: str, make_call: Callable[[Dict[str, int]], Callable[[], object]],
 
     `make_call` binds the kernel arguments and returns a zero-arg callable
     (one jit signature per block shape).  A candidate that fails to compile
-    or run is skipped, not fatal.  Returns (best_blocks, best_us).
+    or run is skipped; when every candidate fails, raises RuntimeError.
+    Returns (best_blocks, best_us).
     """
     ensure_loaded()
     if candidates is not None:
@@ -293,11 +294,11 @@ def tune(op: str, make_call: Callable[[Dict[str, int]], Callable[[], object]],
         cands = lut4_candidate_blocks(M, K, N)
     else:
         cands = candidate_blocks(M, K, N, group_size)
-    best, best_us = None, float("inf")
+    best, best_us, last_err = None, float("inf"), None
     for blocks in cands:
         try:
             us = timer(make_call(blocks))
-        except _TILE_REJECT_ERRORS:
+        except _TILE_REJECT_ERRORS as e:
             # unsupported tile on this backend: bad block/grid shape
             # (ValueError / AssertionError from the wrapper contracts),
             # no Mosaic lowering (NotImplementedError), or a compile/run
@@ -307,20 +308,18 @@ def tune(op: str, make_call: Callable[[Dict[str, int]], Callable[[], object]],
                 "autotune_tiles_rejected_total",
                 "autotune candidates skipped on lowering/compile failure",
                 op=op).inc()
+            last_err = e
             continue
         if us < best_us:
             best, best_us = blocks, us
     if best is None:
-        # every candidate failed: fall back to defaults but do NOT persist —
-        # float("inf") is not valid JSON and a dead entry would shadow a
-        # future successful search
-        if op in ATTN_OPS:
-            fallback = attn_default_blocks(op, M, K, N, group_size)
-        elif op == LUT4_OP:
-            fallback = lut4_default_blocks(M, K, N)
-        else:
-            fallback = default_blocks(M, K, N, group_size)
-        return fallback, float("inf")
+        # every candidate failed: the kernel does not compile or run at
+        # this shape on this backend.  Handing back the defaults would hide
+        # that until the first serving step fails on the same tiles.
+        raise RuntimeError(
+            f"autotune {op} M={M} K={K} N={N} {dtype}: all {len(cands)} "
+            f"candidate tiles failed on {jax.default_backend()}; last "
+            f"error: {type(last_err).__name__}: {last_err}") from last_err
     entry = {**best, "us": best_us}
     _CACHE[cache_key(op, M, K, N, dtype, group_size, tag=tag)] = entry
     if tag:                                # untagged key serves other sites

@@ -45,18 +45,20 @@ def pad_to(x: jnp.ndarray, mult: int, axis: int, value=0) -> jnp.ndarray:
     return jnp.pad(x, widths, constant_values=value)
 
 
-def sign_extend_nibble(n: jnp.ndarray) -> jnp.ndarray:
-    """Low nibble (two's complement, in [0, 16)) -> int8 in [-8, 7]."""
-    return ((n.astype(jnp.int8) ^ 8) - 8).astype(jnp.int8)
+def unpack_nibbles(p: jnp.ndarray, dtype=jnp.int8
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """uint8 -> (lo, hi) sign-extended nibbles in [-8, 7] as `dtype`, each
+    the same shape as `p`.
 
-
-def unpack_nibbles(p: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """uint8 -> (lo, hi) sign-extended int8, each the same shape as `p`.
-
-    The shift/mask primitive shared by every kernel; what the nibbles *mean*
+    The shift primitive shared by every kernel; what the nibbles *mean*
     (adjacent columns vs planar row halves) is the caller's layout contract.
-    """
-    return sign_extend_nibble(p & 0xF), sign_extend_nibble((p >> 4) & 0xF)
+    Sign extension runs in int32 — shift the nibble to the top of the word,
+    then shift it back arithmetically — because the TPU vector unit has no
+    int8 arithmetic (Mosaic refuses an int8 subtract).  The one cast at the
+    end goes straight to the consumer's dtype (int8 for the MXU int8 dot,
+    the activation dtype for W4A16)."""
+    w = p.astype(jnp.int32)
+    return ((w << 28) >> 28).astype(dtype), ((w << 24) >> 28).astype(dtype)
 
 
 def unpack_interleaved(p: jnp.ndarray) -> jnp.ndarray:

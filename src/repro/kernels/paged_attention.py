@@ -86,9 +86,17 @@ def _round_scores(s, compute_dtype):
 
 
 # ------------------------------------------------------- decode (paged) ----
-def _decode_kernel(tbl_ref, lp_ref, q_ref, *refs, pp: int, ps: int, nj: int,
-                   G: int, bkv: int, hd: int, window: int, quant: bool,
-                   scale: float):
+def _walk_pages(last, j, q_ref, refs, *, pp: int, ps: int, nj: int,
+                bkv: int, hd: int, window: int, quant: bool, scale: float):
+    """One program's share of a page walk, shared by the paged-decode and
+    ragged kernels: `pp` pages of one query row, each KV head of the tile
+    attended by its G query heads with online-softmax accumulation.
+
+    Every value stays a 2-D [rows, lanes] tile: the G query heads of KV head
+    h are the leading-index slice ``q_ref[0, h]`` ([G, hd]), never a reshape
+    of the [H, hd] row — Mosaic cannot re-tile that reshape when G or hd is
+    not a multiple of the (sublane, lane) tiling (G = 7, hd = 64 for
+    qwen2-0.5b)."""
     k_refs = refs[:pp]
     v_refs = refs[pp:2 * pp]
     i = 2 * pp
@@ -98,58 +106,110 @@ def _decode_kernel(tbl_ref, lp_ref, q_ref, *refs, pp: int, ps: int, nj: int,
         i += 2 * pp
     o_ref, acc_ref, m_ref, l_ref = refs[i:i + 4]
 
-    b, j = pl.program_id(0), pl.program_id(2)
-
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    lp = lp_ref[b]
     cd = q_ref.dtype
-    qh = q_ref[0].reshape(bkv, G, hd)              # [bkv, G, hd]
-
     for u in range(pp):                            # static unroll: pages
         kb = k_refs[u][0]                          # [ps, bkv, hd(/2)]
         vb = v_refs[u][0]
         if quant:
             kb = _dequant_slab(kb, ks_refs[u][0], hd)
             vb = _dequant_slab(vb, vs_refs[u][0], hd)
-        # scores [bkv, G, ps]: batch over kv heads, contract hd
-        s = jax.lax.dot_general(
-            qh, kb.transpose(1, 0, 2).astype(cd),
-            (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        s = _round_scores(s, cd) * scale
+        kb = kb.transpose(1, 0, 2).astype(cd)      # [bkv, ps, hd]
+        vb = vb.transpose(1, 0, 2).astype(jnp.float32)
 
-        logical = j * pp + u
-        pos = logical * ps + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, ps), 2)
-        mask = (pos <= lp) & (lp >= 0)
+        pos = (j * pp + u) * ps + jax.lax.broadcasted_iota(
+            jnp.int32, (1, ps), 1)
+        mask = (pos <= last) & (last >= 0)
         if window:
-            mask &= (lp - pos) < window
-        s = jnp.where(mask, s, NEG_INF)
+            mask &= (last - pos) < window
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)   # explicit zero: an
-        # all-masked prefix keeps m at NEG_INF and exp(0)=1 would otherwise
-        # leak the masked slots into l/acc
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, vb.transpose(1, 0, 2).astype(jnp.float32),
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)        # [bkv, G, hd]
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = m_new
+        for h in range(bkv):                       # static unroll: KV heads
+            # scores [G, ps]: contract hd
+            s = jax.lax.dot_general(
+                q_ref[0, h], kb[h], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            s = jnp.where(mask, _round_scores(s, cd) * scale, NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)   # explicit zero:
+            # an all-masked prefix keeps m at NEG_INF and exp(0)=1 would
+            # otherwise leak the masked slots into l/acc
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, vb[h], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)    # [G, hd]
+            m_ref[h] = m_new
 
     @pl.when(j == nj - 1)
     def _emit():
         l = l_ref[...]
-        out = acc_ref[...] / jnp.where(l > 0, l, 1.0)  # inactive row -> 0
-        o_ref[...] = out.reshape(1, bkv * G, hd).astype(o_ref.dtype)
+        out = acc_ref[...] / jnp.where(l > 0, l, 1.0)  # masked row -> 0
+        o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _decode_kernel(tbl_ref, lp_ref, q_ref, *refs, **kw):
+    del tbl_ref                    # consumed by the BlockSpec index_maps
+    _walk_pages(lp_ref[pl.program_id(0)], pl.program_id(2), q_ref, refs, **kw)
+
+
+def page_walk_call(kernel, scalars, q, k_pool, v_pool, k_scale, v_scale, *,
+                   page_index, pp: int, bkv: int, nj: int, window: int,
+                   interpret: bool) -> jnp.ndarray:
+    """The pallas_call both page-walking kernels share: grid (query row,
+    KV-head tile, page block) over q [R, H, hd], with `scalars` riding in
+    as scalar-prefetch operands that `page_index(u)` — an index_map for the
+    u-th page of a program — reads to find a physical pool page.
+
+    q enters as [R, KV, G, hd] (a free reshape here, outside the kernel),
+    so a program's query heads arrive grouped by KV head."""
+    R, H, hd = q.shape
+    ps, KV = k_pool.shape[1:3]
+    assert H % KV == 0, (H, KV)           # query heads tile evenly over KV heads
+    assert KV % bkv == 0, (KV, bkv)       # whole KV heads per program
+    G = H // KV
+    quant = k_scale is not None
+
+    def row_index(r, h, j, *_):
+        return (r, h, 0, 0)
+
+    kv_block = k_pool.shape[-1]                    # hd, or hd//2 packed
+    pool_specs = [pl.BlockSpec((1, ps, bkv, kv_block), page_index(u))
+                  for u in range(pp)]
+    in_specs = [pl.BlockSpec((1, bkv, G, hd), row_index),
+                *pool_specs, *pool_specs]
+    args = [q.reshape(R, KV, G, hd), *([k_pool] * pp), *([v_pool] * pp)]
+    if quant:
+        scale_specs = [pl.BlockSpec((1, ps, bkv, 1), page_index(u))
+                       for u in range(pp)]
+        in_specs += [*scale_specs, *scale_specs]
+        args += [*([k_scale] * pp), *([v_scale] * pp)]
+
+    body = functools.partial(
+        kernel, pp=pp, ps=ps, nj=nj, bkv=bkv, hd=hd, window=window,
+        quant=quant, scale=1.0 / math.sqrt(hd))
+    out = pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(R, KV // bkv, nj),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, bkv, G, hd), row_index),
+            scratch_shapes=[
+                pltpu.VMEM((bkv, G, hd), jnp.float32),
+                pltpu.VMEM((bkv, G, 1), jnp.float32),
+                pltpu.VMEM((bkv, G, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, KV, G, hd), q.dtype),
+        interpret=interpret,
+    )(*scalars, *args)
+    return out.reshape(R, H, hd)
 
 
 @functools.partial(
@@ -167,24 +227,12 @@ def paged_decode_attention(
     bkv: int = 0,              # KV-head tile, 0 = all heads
     interpret: bool = None,
 ) -> jnp.ndarray:
-    B, H, hd = q.shape
-    P, ps, KV = k_pool.shape[:3]
+    P, _, KV = k_pool.shape[:3]
     pps = tbl.shape[1]
-    assert H % KV == 0, (H, KV)           # query heads tile evenly over KV heads
-    G = H // KV
-    quant = k_scale is not None
-
     bkv = _largest_divisor(KV, bkv if bkv > 0 else KV)
-    assert KV % bkv == 0, (KV, bkv)       # _largest_divisor contract
     pp = max(1, min(pp, pps))
-    nj = -(-pps // pp)
-    nh = KV // bkv
-    interpret = default_interpret(interpret)
 
-    tbl = tbl.astype(jnp.int32)
-    last_pos = last_pos.astype(jnp.int32)
-
-    def page_spec(u, heads):
+    def page_index(u):
         # the scalar-prefetched block table turns the logical page into a
         # physical pool index right in the index_map: the pipeline DMAs the
         # page from wherever it lives, no gather ever materializes.  Dead
@@ -192,45 +240,14 @@ def paged_decode_attention(
         # DMA stays in bounds — the kernel masks those positions anyway.
         def index(b, h, j, tbl_ref, lp_ref):
             logical = jnp.minimum(j * pp + u, pps - 1)
-            return (jnp.minimum(tbl_ref[b, logical], P - 1), 0,
-                    h if heads else 0, 0)
+            return (jnp.minimum(tbl_ref[b, logical], P - 1), 0, h, 0)
         return index
 
-    kv_block = k_pool.shape[-1]                    # hd, or hd//2 packed
-    in_specs = [pl.BlockSpec((1, bkv * G, hd), lambda b, h, j, t, l: (b, h, 0))]
-    in_specs += [pl.BlockSpec((1, ps, bkv, kv_block), page_spec(u, True))
-                 for u in range(pp)]
-    in_specs += [pl.BlockSpec((1, ps, bkv, kv_block), page_spec(u, True))
-                 for u in range(pp)]
-    args = [q, *([k_pool] * pp), *([v_pool] * pp)]
-    if quant:
-        in_specs += [pl.BlockSpec((1, ps, bkv, 1), page_spec(u, True))
-                     for u in range(pp)]
-        in_specs += [pl.BlockSpec((1, ps, bkv, 1), page_spec(u, True))
-                     for u in range(pp)]
-        args += [*([k_scale] * pp), *([v_scale] * pp)]
-
-    kernel = functools.partial(
-        _decode_kernel, pp=pp, ps=ps, nj=nj, G=G, bkv=bkv, hd=hd,
-        window=window, quant=quant, scale=1.0 / math.sqrt(hd))
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, nh, nj),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, bkv * G, hd),
-                                   lambda b, h, j, t, l: (b, h, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((bkv, G, hd), jnp.float32),
-                pltpu.VMEM((bkv, G, 1), jnp.float32),
-                pltpu.VMEM((bkv, G, 1), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        interpret=interpret,
-    )(tbl, last_pos, *args)
-    return out
+    return page_walk_call(
+        _decode_kernel, (tbl.astype(jnp.int32), last_pos.astype(jnp.int32)),
+        q, k_pool, v_pool, k_scale, v_scale, page_index=page_index, pp=pp,
+        bkv=bkv, nj=-(-pps // pp), window=window,
+        interpret=default_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("window", "pp"))
@@ -330,7 +347,11 @@ def paged_decode_attention_xla(
 # ------------------------------------------------------- prefill (flash) ----
 def _prefill_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref,
                     acc_ref, m_ref, l_ref, *, nk: int, G: int, bkv: int,
-                    hd: int, window: int, scale: float):
+                    window: int, scale: float):
+    """Head-major tiles: q [bkv*G, bq, hd], k/v [bkv, bk, hd], so each
+    query head is a leading-index [bq, hd] slice and every dot is a plain
+    2-D MXU matmul — no in-kernel reshape or transpose for Mosaic to
+    re-tile."""
     kk = pl.program_id(3)
 
     @pl.when(kk == 0)
@@ -340,43 +361,35 @@ def _prefill_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     cd = q_ref.dtype
-    bq = q_ref.shape[1]
-    bk = k_ref.shape[1]
-    # [bq, bkv*G, hd] -> [bkv, G*bq, hd] so kv heads batch the MXU dots
-    qh = (q_ref[0].reshape(bq, bkv, G, hd).transpose(1, 2, 0, 3)
-          .reshape(bkv, G * bq, hd))
-    kb = k_ref[0].transpose(1, 0, 2)               # [bkv, bk, hd]
-    s = jax.lax.dot_general(
-        qh, kb.astype(cd), (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    s = (_round_scores(s, cd) * scale).reshape(bkv, G, bq, bk)
-
-    qp, kp = qp_ref[0], kp_ref[0]                  # [bq], [bk]
-    mask = (qp[:, None] >= kp[None, :]) & (kp[None, :] >= 0)
+    qp, kp = qp_ref[0], kp_ref[0]                  # [bq, 1], [1, bk]
+    mask = (qp >= kp) & (kp >= 0)
     if window:
-        mask &= (qp[:, None] - kp[None, :]) < window
-    mask = mask[None, None]
-    s = jnp.where(mask, s, NEG_INF)
+        mask &= (qp - kp) < window
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p.reshape(bkv, G * bq, bk),
-        v_ref[0].transpose(1, 0, 2).astype(jnp.float32),
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32).reshape(bkv, G, bq, hd)
-    acc_ref[...] = acc_ref[...] * alpha + pv
-    m_ref[...] = m_new
+    for h in range(bkv):                           # static unroll: KV heads
+        kb = k_ref[0, h].astype(cd)                # [bk, hd]
+        vb = v_ref[0, h].astype(jnp.float32)
+        for g in range(G):                         # ... and their q heads
+            r = h * G + g
+            s = jax.lax.dot_general(
+                q_ref[0, r], kb, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)    # [bq, bk]
+            s = jnp.where(mask, _round_scores(s, cd) * scale, NEG_INF)
+            m_prev = m_ref[r]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            l_ref[r] = l_ref[r] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[r] = acc_ref[r] * alpha + jax.lax.dot_general(
+                p, vb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)    # [bq, hd]
+            m_ref[r] = m_new
 
     @pl.when(kk == nk - 1)
     def _emit():
         l = l_ref[...]
         out = acc_ref[...] / jnp.where(l > 0, l, 1.0)
-        o_ref[...] = (out.transpose(2, 0, 1, 3)
-                      .reshape(1, bq, bkv * G, hd).astype(o_ref.dtype))
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -413,38 +426,42 @@ def flash_prefill(
         widths = [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2)
         return jnp.pad(x, widths, constant_values=value) if pad else x
 
-    qp = padq(q)
-    kp_, vp_ = padk(k), padk(v)
-    qpos = padq(q_positions.astype(jnp.int32), value=-1)
-    kpos = padk(k_positions.astype(jnp.int32), value=-1)
-    nq, nk = qp.shape[1] // bq, kp_.shape[1] // bk
+    # head-major layouts for the kernel (XLA transposes, outside it):
+    # q/out [B, H, S, hd], k/v [B, KV, S, hd]; positions as a q column
+    # [B, S, 1] and a k row [B, 1, S] so the mask is a 2-D broadcast
+    head_major = functools.partial(jnp.transpose, axes=(0, 2, 1, 3))
+    qp = head_major(padq(q))
+    kp_, vp_ = head_major(padk(k)), head_major(padk(v))
+    qpos = padq(q_positions.astype(jnp.int32), value=-1)[:, :, None]
+    kpos = padk(k_positions.astype(jnp.int32), value=-1)[:, None, :]
+    nq, nk = qp.shape[2] // bq, kp_.shape[2] // bk
     nh = KV // bkv
 
     kernel = functools.partial(
-        _prefill_kernel, nk=nk, G=G, bkv=bkv, hd=hd, window=window,
+        _prefill_kernel, nk=nk, G=G, bkv=bkv, window=window,
         scale=1.0 / math.sqrt(hd))
     out = pl.pallas_call(
         kernel,
         grid=(B, nh, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, bkv * G, hd),
-                         lambda b, h, i, kk: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, bkv, hd), lambda b, h, i, kk: (b, kk, h, 0)),
-            pl.BlockSpec((1, bk, bkv, hd), lambda b, h, i, kk: (b, kk, h, 0)),
-            pl.BlockSpec((1, bq), lambda b, h, i, kk: (b, i)),
-            pl.BlockSpec((1, bk), lambda b, h, i, kk: (b, kk)),
+            pl.BlockSpec((1, bkv * G, bq, hd),
+                         lambda b, h, i, kk: (b, h, i, 0)),
+            pl.BlockSpec((1, bkv, bk, hd), lambda b, h, i, kk: (b, h, kk, 0)),
+            pl.BlockSpec((1, bkv, bk, hd), lambda b, h, i, kk: (b, h, kk, 0)),
+            pl.BlockSpec((1, bq, 1), lambda b, h, i, kk: (b, i, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, h, i, kk: (b, 0, kk)),
         ],
-        out_specs=pl.BlockSpec((1, bq, bkv * G, hd),
-                               lambda b, h, i, kk: (b, i, h, 0)),
+        out_specs=pl.BlockSpec((1, bkv * G, bq, hd),
+                               lambda b, h, i, kk: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bkv, G, bq, hd), jnp.float32),
-            pltpu.VMEM((bkv, G, bq, 1), jnp.float32),
-            pltpu.VMEM((bkv, G, bq, 1), jnp.float32),
+            pltpu.VMEM((bkv * G, bq, hd), jnp.float32),
+            pltpu.VMEM((bkv * G, bq, 1), jnp.float32),
+            pltpu.VMEM((bkv * G, bq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(qp, kp_, vp_, qpos, kpos)
-    return out[:, :Sq]
+    return head_major(out)[:, :Sq]
 
 
 @functools.partial(jax.jit, static_argnames=("window", "bk"))

@@ -42,90 +42,29 @@ and exactly last-token for decode rows — one rule covers both.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from .dispatch import default_interpret
 from .paged_attention import (
-    NEG_INF,
-    _dequant_slab,
     _largest_divisor,
-    _round_scores,
+    _walk_pages,
+    page_walk_call,
     paged_decode_attention_xla,
 )
 
 
-def _ragged_kernel(slot_ref, pos_ref, tbl_ref, q_ref, *refs, pp: int,
-                   ps: int, nj: int, G: int, bkv: int, hd: int, window: int,
-                   quant: bool, scale: float):
+def _ragged_kernel(slot_ref, pos_ref, tbl_ref, q_ref, *refs, **kw):
     # slot_ref/tbl_ref are consumed by the BlockSpec index_maps; the body
-    # only needs the token's own position for masking.
+    # only needs the token's own position for masking.  pos <= token_pos is
+    # causal for prefill rows (the chunk's K/V is already in the pool) and
+    # last-token for decode rows; padding rows (token_pos == -1) mask
+    # everything and emit zeros.
     del slot_ref, tbl_ref
-    k_refs = refs[:pp]
-    v_refs = refs[pp:2 * pp]
-    i = 2 * pp
-    if quant:
-        ks_refs = refs[i:i + pp]
-        vs_refs = refs[i + pp:i + 2 * pp]
-        i += 2 * pp
-    o_ref, acc_ref, m_ref, l_ref = refs[i:i + 4]
-
-    t, j = pl.program_id(0), pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    tp = pos_ref[t]
-    cd = q_ref.dtype
-    qh = q_ref[0].reshape(bkv, G, hd)              # [bkv, G, hd]
-
-    for u in range(pp):                            # static unroll: pages
-        kb = k_refs[u][0]                          # [ps, bkv, hd(/2)]
-        vb = v_refs[u][0]
-        if quant:
-            kb = _dequant_slab(kb, ks_refs[u][0], hd)
-            vb = _dequant_slab(vb, vs_refs[u][0], hd)
-        s = jax.lax.dot_general(
-            qh, kb.transpose(1, 0, 2).astype(cd),
-            (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        s = _round_scores(s, cd) * scale
-
-        logical = j * pp + u
-        pos = logical * ps + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, ps), 2)
-        # pos <= token_pos is causal for prefill rows (the chunk's K/V is
-        # already in the pool) and last-token for decode rows; padding rows
-        # (token_pos == -1) mask everything and emit zeros.
-        mask = (pos <= tp) & (tp >= 0)
-        if window:
-            mask &= (tp - pos) < window
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, vb.transpose(1, 0, 2).astype(jnp.float32),
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)        # [bkv, G, hd]
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = m_new
-
-    @pl.when(j == nj - 1)
-    def _emit():
-        l = l_ref[...]
-        out = acc_ref[...] / jnp.where(l > 0, l, 1.0)  # padding row -> 0
-        o_ref[...] = out.reshape(1, bkv * G, hd).astype(o_ref.dtype)
+    _walk_pages(pos_ref[pl.program_id(0)], pl.program_id(2), q_ref, refs,
+                **kw)
 
 
 @functools.partial(
@@ -144,25 +83,12 @@ def ragged_decode_attention(
     bkv: int = 0,              # KV-head tile, 0 = all heads
     interpret: bool = None,
 ) -> jnp.ndarray:
-    T, H, hd = q.shape
-    P, ps, KV = k_pool.shape[:3]
-    maxB, pps = tbl.shape
-    assert H % KV == 0, (H, KV)           # query heads tile evenly over KV heads
-    G = H // KV
-    quant = k_scale is not None
-
+    P, _, KV = k_pool.shape[:3]
+    pps = tbl.shape[1]
     bkv = _largest_divisor(KV, bkv if bkv > 0 else KV)
-    assert KV % bkv == 0, (KV, bkv)       # _largest_divisor contract
     pp = max(1, min(pp, pps))
-    nj = -(-pps // pp)
-    nh = KV // bkv
-    interpret = default_interpret(interpret)
 
-    tbl = tbl.astype(jnp.int32)
-    token_slot = token_slot.astype(jnp.int32)
-    token_pos = token_pos.astype(jnp.int32)
-
-    def page_spec(u):
+    def page_index(u):
         # two scalar hops per program: token row -> table row -> physical
         # page.  Padding rows (slot -1) clamp to row 0 and dead table slots
         # carry the out-of-bounds sentinel (== P); both clamp into bounds
@@ -173,42 +99,12 @@ def ragged_decode_attention(
             return (jnp.minimum(tbl_ref[row, logical], P - 1), 0, h, 0)
         return index
 
-    kv_block = k_pool.shape[-1]                    # hd, or hd//2 packed
-    in_specs = [pl.BlockSpec((1, bkv * G, hd),
-                             lambda t, h, j, s, p_, tb: (t, h, 0))]
-    in_specs += [pl.BlockSpec((1, ps, bkv, kv_block), page_spec(u))
-                 for u in range(pp)]
-    in_specs += [pl.BlockSpec((1, ps, bkv, kv_block), page_spec(u))
-                 for u in range(pp)]
-    args = [q, *([k_pool] * pp), *([v_pool] * pp)]
-    if quant:
-        in_specs += [pl.BlockSpec((1, ps, bkv, 1), page_spec(u))
-                     for u in range(pp)]
-        in_specs += [pl.BlockSpec((1, ps, bkv, 1), page_spec(u))
-                     for u in range(pp)]
-        args += [*([k_scale] * pp), *([v_scale] * pp)]
-
-    kernel = functools.partial(
-        _ragged_kernel, pp=pp, ps=ps, nj=nj, G=G, bkv=bkv, hd=hd,
-        window=window, quant=quant, scale=1.0 / math.sqrt(hd))
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(T, nh, nj),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, bkv * G, hd),
-                                   lambda t, h, j, s, p_, tb: (t, h, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((bkv, G, hd), jnp.float32),
-                pltpu.VMEM((bkv, G, 1), jnp.float32),
-                pltpu.VMEM((bkv, G, 1), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((T, H, hd), q.dtype),
-        interpret=interpret,
-    )(token_slot, token_pos, tbl, *args)
-    return out
+    scalars = (token_slot.astype(jnp.int32), token_pos.astype(jnp.int32),
+               tbl.astype(jnp.int32))
+    return page_walk_call(
+        _ragged_kernel, scalars, q, k_pool, v_pool, k_scale, v_scale,
+        page_index=page_index, pp=pp, bkv=bkv, nj=-(-pps // pp),
+        window=window, interpret=default_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("window", "pp"))

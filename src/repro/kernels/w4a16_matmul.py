@@ -42,10 +42,9 @@ def _pad_rows(s: jnp.ndarray, rows: int) -> jnp.ndarray:
     return jnp.pad(s, [(0, rows - s.shape[0])] + [(0, 0)] * (s.ndim - 1))
 
 
-def _dot(x, w_q, cd):
+def _dot(x, w):
     return jax.lax.dot_general(
-        x, w_q.astype(cd), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
 def _kernel_per_channel(xlo_ref, xhi_ref, w_ref, ws_ref, o_ref, *, nk: int):
@@ -55,9 +54,9 @@ def _kernel_per_channel(xlo_ref, xhi_ref, w_ref, ws_ref, o_ref, *, nk: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    lo, hi = unpack_nibbles(w_ref[...])          # planar [bk/2, bn] int8
-    cd = xlo_ref.dtype
-    o_ref[...] += _dot(xlo_ref[...], lo, cd) + _dot(xhi_ref[...], hi, cd)
+    # planar [bk/2, bn] nibbles, already in the activation dtype
+    lo, hi = unpack_nibbles(w_ref[...], xlo_ref.dtype)
+    o_ref[...] += _dot(xlo_ref[...], lo) + _dot(xhi_ref[...], hi)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -72,14 +71,13 @@ def _kernel_grouped(xlo_ref, xhi_ref, w_ref, slo_ref, shi_ref, o_ref, *,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    lo, hi = unpack_nibbles(w_ref[...])          # planar [bk/2, bn] int8
     x_lo, x_hi = xlo_ref[...], xhi_ref[...]
-    cd = x_lo.dtype
+    lo, hi = unpack_nibbles(w_ref[...], x_lo.dtype)   # planar [bk/2, bn]
     acc = jnp.zeros_like(o_ref)
     for g in range(gpbh):                        # static unroll: whole groups
         rows = slice(g * gsize, (g + 1) * gsize)
-        acc += _dot(x_lo[:, rows], lo[rows], cd) * slo_ref[g]
-        acc += _dot(x_hi[:, rows], hi[rows], cd) * shi_ref[g]
+        acc += _dot(x_lo[:, rows], lo[rows]) * slo_ref[g]
+        acc += _dot(x_hi[:, rows], hi[rows]) * shi_ref[g]
     o_ref[...] += acc
 
 

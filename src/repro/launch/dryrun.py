@@ -155,8 +155,6 @@ def _mem_fields(compiled) -> Dict[str, float]:
 
 def _cost_fields(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):     # jax 0.4.x: one dict per device set
-        ca = ca[0] if ca else {}
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
 

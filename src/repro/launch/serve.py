@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import Runtime, ServingConfig, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.observability import Telemetry, global_registry
 from repro.serving.api import (
     bursty_trace,
@@ -115,6 +116,19 @@ def _quantized_ckpt_report(cfg, rt, ckpt_dir, seed):
     return restored, reference, report
 
 
+def serving_runtime(max_ctx: int, *, attn_impl: str = "flash",
+                    quant_backend="w4a4_packed", quant_plan=None,
+                    cache_dtype="bfloat16") -> Runtime:
+    """The Runtime the server runs: flash prefill and the fused paged decode
+    kernel by default, under `quant_plan` if given, else the uniform
+    `quant_backend`."""
+    return Runtime(scan_layers=True, attn_impl=attn_impl,
+                   attn_chunk_q=min(512, max_ctx), loss_chunk=0,
+                   quant_backend=None if quant_plan else quant_backend,
+                   quant_plan=quant_plan, cache_dtype=cache_dtype,
+                   remat="none")
+
+
 def serve(arch: str, *, reduced=True, layers=None, layout=None, max_batch=4,
           page_size=16, num_pages=48, max_ctx=128, requests=8, rate=0.5,
           prompt_lens=(8, 16, 32), gen_lens=(8, 16), scenario="poisson",
@@ -138,11 +152,9 @@ def serve(arch: str, *, reduced=True, layers=None, layout=None, max_batch=4,
         from repro.serving.chaos import (
             CANCEL_STORM, ChaosConfig, chaos_report,
         )
-        rt = Runtime(scan_layers=True, attn_impl="chunked",
-                     attn_chunk_q=min(512, max_ctx), loss_chunk=0,
-                     quant_backend=None if quant_plan else quant_backend,
-                     quant_plan=quant_plan, cache_dtype=cache_dtype,
-                     remat="none")
+        rt = serving_runtime(max_ctx, attn_impl="chunked",
+                             quant_backend=quant_backend,
+                             quant_plan=quant_plan, cache_dtype=cache_dtype)
         base = CANCEL_STORM if scenario == "cancel_storm" else ChaosConfig()
         chaos = dataclasses.replace(
             base, seed=chaos_seed, n_requests=requests, rate_per_step=rate,
@@ -168,12 +180,10 @@ def serve(arch: str, *, reduced=True, layers=None, layout=None, max_batch=4,
     # compares identical math — flash's online-softmax rescaling rounds
     # differently from the ragged step's page-grouped exact softmax, and on
     # a random-init model that can flip an argmax tie in the prompt logits
-    rt = Runtime(scan_layers=True,
-                 attn_impl="chunked" if layout == "compare" else "flash",
-                 attn_chunk_q=min(512, max_ctx), loss_chunk=0,
-                 quant_backend=None if quant_plan else quant_backend,
-                 quant_plan=quant_plan, cache_dtype=cache_dtype,
-                 remat="none")
+    rt = serving_runtime(
+        max_ctx, attn_impl="chunked" if layout == "compare" else "flash",
+        quant_backend=quant_backend, quant_plan=quant_plan,
+        cache_dtype=cache_dtype)
     if scenario == "shared_prefix":
         trace = shared_prefix_trace(requests, rate, sys_len, prompt_lens,
                                     gen_lens, cfg.vocab, seed=seed)
@@ -418,6 +428,7 @@ def main():
                     help="also write the JSON report to this path")
     args = ap.parse_args()
 
+    enable_compile_cache()
     out = serve(
         args.arch, reduced=args.reduced, layers=args.layers,
         layout=args.layout,

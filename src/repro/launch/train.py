@@ -16,6 +16,7 @@ import json
 import logging
 import os
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,7 @@ from repro.data import SyntheticLMDataset, make_batch_iterator
 from repro.distributed.fault_tolerance import StepTimer, Watchdog, run_with_retries
 from repro.distributed.sharding import mesh_context
 from repro.launch.mesh import make_mesh
-from repro.launch.steps import init_train_state, make_train_step
+from repro.launch.steps import init_train_state, make_train_step, state_specs
 
 log = logging.getLogger("repro.train")
 
@@ -39,7 +40,7 @@ def train(
     batch: int = 8,
     seq: int = 128,
     reduced: bool = True,
-    ckpt_dir: str = "/tmp/repro_ckpt",
+    ckpt_dir: Optional[str] = "/tmp/repro_ckpt",
     save_every: int = 50,
     mesh_spec: str = "",
     peak_lr: float = 3e-4,
@@ -47,7 +48,11 @@ def train(
     step_deadline_s: float = 600.0,
     log_every: int = 10,
     seed: int = 0,
+    grad_norms: Optional[list] = None,
 ):
+    """Train `steps` steps; returns (final state, per-step losses).  Each
+    step's pre-clip global gradient norm is appended to `grad_norms` when a
+    list is given."""
     cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
@@ -62,25 +67,24 @@ def train(
 
     ds = SyntheticLMDataset(vocab=cfg.vocab, seq_len=seq, global_batch=batch,
                             seed=seed)
-    mgr = CheckpointManager(ckpt_dir, save_every=save_every, keep=3)
+    # ckpt_dir=None trains without checkpoints (a full-width train state is
+    # three times the params: a smoke run has no use for writing it)
+    mgr = (CheckpointManager(ckpt_dir, save_every=save_every, keep=3)
+           if ckpt_dir else None)
 
     with mesh_context(mesh):
         state = init_train_state(jax.random.PRNGKey(seed), cfg)
         start_step = 0
-        latest = mgr.latest()
+        latest = mgr.latest() if mgr is not None else None
         if latest is not None:
             state, start_step = mgr.restore(state)
             log.info("resumed from step %d", start_step)
         if mesh is not None:
-            from repro.distributed.sharding import (
-                make_param_shardings, specs_to_shardings)
-            pspec = make_param_shardings(state["params"], mesh)
-            state = {
-                "params": jax.device_put(
-                    state["params"], specs_to_shardings(pspec, mesh)),
-                "opt": state["opt"],
-                "step": state["step"],
-            }
+            # params over TP and the Adam moments over the whole mesh: the
+            # optimizer state is twice the params, and left unsharded it
+            # would all sit on the first device
+            _, shardings = state_specs(cfg, mesh)
+            state = jax.device_put(state, shardings)
 
         step_fn = jax.jit(make_train_step(cfg, rt, peak_lr=peak_lr,
                                           total_steps=max(steps, 1)),
@@ -99,15 +103,18 @@ def train(
             timer.start()
             state, metrics = run_with_retries(one_step, max_retries=2)
             dt = timer.stop()
-            loss = float(metrics["loss"])
+            loss, gnorm = float(metrics["loss"]), float(metrics["grad_norm"])
             history.append(loss)
+            if grad_norms is not None:
+                grad_norms.append(gnorm)
             if step % log_every == 0 or step == steps - 1:
                 log.info("step %5d loss %.4f gnorm %.3f lr %.2e %.0f ms",
-                         step, loss, float(metrics["grad_norm"]),
-                         float(metrics["lr"]), dt * 1e3)
-            mgr.maybe_save(step + 1, state)
+                         step, loss, gnorm, float(metrics["lr"]), dt * 1e3)
+            if mgr is not None:
+                mgr.maybe_save(step + 1, state)
         it.close()
-        mgr.maybe_save(steps, state, force=True)
+        if mgr is not None:
+            mgr.maybe_save(steps, state, force=True)
     return state, history
 
 

@@ -138,6 +138,24 @@ def test_tune_skips_failing_candidates(isolated_cache):
     assert rejected.value == before + 1
 
 
+def test_tune_raises_when_every_candidate_fails(isolated_cache):
+    # a kernel that compiles at no candidate tile must fail the tuning run,
+    # not hand back untested defaults (nor persist a dead entry)
+    def make_call(blocks):
+        def run():
+            raise NotImplementedError("no lowering")
+        return run
+
+    with pytest.raises(RuntimeError, match="all 2 candidate tiles failed"):
+        autotune.tune("int4_matmul", make_call, 64, 512, 256, "int8",
+                      candidates=[{"bm": 32, "bn": 128, "bk": 128},
+                                  {"bm": 64, "bn": 128, "bk": 256}],
+                      timer=lambda fn: (fn(), 10.0)[1])
+    assert not isolated_cache.exists()
+    assert autotune.get_blocks("int4_matmul", 64, 512, 256, "int8") \
+        == autotune.default_blocks(64, 512, 256)
+
+
 def test_tune_propagates_programming_errors(isolated_cache):
     # a TypeError is a bug in make_call, not a rejected tile: the narrowed
     # except must let it escape instead of silently discarding the
