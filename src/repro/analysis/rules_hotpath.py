@@ -65,7 +65,7 @@ def _rhs_is_hostlike(node: ast.AST, host: Set[str]) -> bool:
             return True
         if name in ("len", "int", "float", "bool", "range", "sorted",
                     "list", "dict", "set", "tuple", "sum", "min", "max",
-                    "self.clock", "self.trace.now"):
+                    "self.clock"):
             return True
         return False
     if isinstance(node, (ast.Name, ast.Attribute)):

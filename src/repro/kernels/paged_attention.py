@@ -160,14 +160,15 @@ def _decode_kernel(tbl_ref, lp_ref, q_ref, *refs, **kw):
 
 def page_walk_call(kernel, scalars, q, k_pool, v_pool, k_scale, v_scale, *,
                    page_index, pp: int, bkv: int, nj: int, window: int,
-                   interpret: bool) -> jnp.ndarray:
+                   interpret: bool, name: str) -> jnp.ndarray:
     """The pallas_call both page-walking kernels share: grid (query row,
     KV-head tile, page block) over q [R, H, hd], with `scalars` riding in
     as scalar-prefetch operands that `page_index(u)` — an index_map for the
     u-th page of a program — reads to find a physical pool page.
 
     q enters as [R, KV, G, hd] (a free reshape here, outside the kernel),
-    so a program's query heads arrive grouped by KV head."""
+    so a program's query heads arrive grouped by KV head.  `name` names
+    the kernel in the compiled program and so in the device trace."""
     R, H, hd = q.shape
     ps, KV = k_pool.shape[1:3]
     assert H % KV == 0, (H, KV)           # query heads tile evenly over KV heads
@@ -208,6 +209,7 @@ def page_walk_call(kernel, scalars, q, k_pool, v_pool, k_scale, v_scale, *,
         ),
         out_shape=jax.ShapeDtypeStruct((R, KV, G, hd), q.dtype),
         interpret=interpret,
+        name=name,
     )(*scalars, *args)
     return out.reshape(R, H, hd)
 
@@ -247,7 +249,7 @@ def paged_decode_attention(
         _decode_kernel, (tbl.astype(jnp.int32), last_pos.astype(jnp.int32)),
         q, k_pool, v_pool, k_scale, v_scale, page_index=page_index, pp=pp,
         bkv=bkv, nj=-(-pps // pp), window=window,
-        interpret=default_interpret(interpret))
+        interpret=default_interpret(interpret), name="paged_decode_attention")
 
 
 @functools.partial(jax.jit, static_argnames=("window", "pp"))
@@ -460,6 +462,7 @@ def flash_prefill(
             pltpu.VMEM((bkv * G, bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_prefill",
     )(qp, kp_, vp_, qpos, kpos)
     return head_major(out)[:, :Sq]
 

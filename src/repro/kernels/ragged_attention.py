@@ -104,7 +104,8 @@ def ragged_decode_attention(
     return page_walk_call(
         _ragged_kernel, scalars, q, k_pool, v_pool, k_scale, v_scale,
         page_index=page_index, pp=pp, bkv=bkv, nj=-(-pps // pp),
-        window=window, interpret=default_interpret(interpret))
+        window=window, interpret=default_interpret(interpret),
+        name="ragged_decode_attention")
 
 
 @functools.partial(jax.jit, static_argnames=("window", "pp"))

@@ -138,6 +138,7 @@ def w4a16_matmul(
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
             interpret=interpret,
+            name="w4a16_matmul",
         )(x_lo, x_hi, w_kmajor, pad_to(w_scale, bn, 1))
     else:
         ng2 = (Keven // G) // 2                  # groups per planar half
@@ -154,5 +155,6 @@ def w4a16_matmul(
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
             out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
             interpret=interpret,
+            name="w4a16_matmul",
         )(x_lo, x_hi, w_kmajor, s_lo, s_hi)
     return out[:M, :N]

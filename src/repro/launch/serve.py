@@ -22,6 +22,10 @@ quantized-checkpoint path end-to-end: save packed nibbles + scales + plan,
 restore with no float master, serve from the restored tree, and verify
 bit-identical logits/tokens against the same plan applied to float masters.
 `--sweep` adds the per-site sensitivity table to the report.
+`--trace-out DIR` runs the primary layout's served run under
+``jax.profiler``: the engine's ``engine.*`` step spans land beside the
+device ops on one clock (``DIR/**/perfetto_trace.json.gz`` opens at
+ui.perfetto.dev).
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --reduced
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --reduced \
@@ -35,6 +39,7 @@ bit-identical logits/tokens against the same plan applied to float masters.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import json
 import os
@@ -255,20 +260,23 @@ def serve(arch: str, *, reduced=True, layers=None, layout=None, max_batch=4,
                            prefix_cache=(prefix_cache
                                          and lay != "paged_nocache"))
         # per-engine telemetry (compare-mode engines keep separate
-        # registries); the Perfetto timeline records the primary layout
-        tm = Telemetry(metrics=metrics,
-                       trace=bool(trace_out) and lay == layouts[0])
+        # registries)
+        tm = Telemetry(metrics=metrics)
         engine = InferenceEngine(cfg, rt_lay, sv, params=params,
                                  telemetry=tm)
         engine.warmup(warm_lens)       # compiles excluded from the stats
-        stats, finished = run_trace(engine, trace)
+        # the profiler records the primary layout's served run: the
+        # engine's step spans beside the device ops, on one clock
+        profiled = bool(trace_out) and lay == layouts[0]
+        with (jax.profiler.trace(trace_out, create_perfetto_trace=True)
+              if profiled else contextlib.nullcontext()):
+            stats, finished = run_trace(engine, trace)
+        if profiled:
+            report["trace_out"] = trace_out
         stats["profile"] = engine.profile()   # attn vs GEMM attribution
         stats["profile_at_step"] = stats["profile"].get("at_step")
         report[lay] = stats
         tokens_by_layout[lay] = [r.tokens for r in finished]
-        if tm.trace.enabled:
-            tm.trace.save(trace_out)
-            report["trace_out"] = trace_out
 
     if params_ref is not None:
         # end-to-end: the restored-checkpoint engine must generate exactly
@@ -417,10 +425,12 @@ def main():
                          "many waiting requests shed with a typed error "
                          "(0 = unbounded; chaos scenarios default to "
                          "2*max_batch)")
-    ap.add_argument("--trace-out", default=None,
-                    help="write a Chrome/Perfetto trace_event JSON timeline "
-                         "of the primary layout's run (open at "
-                         "ui.perfetto.dev)")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="profile the primary layout's served run into this "
+                         "directory (jax.profiler): the engine's step spans "
+                         "and the device ops on one clock, as .xplane.pb "
+                         "(TensorBoard / XProf) and perfetto_trace.json.gz "
+                         "(open at ui.perfetto.dev)")
     ap.add_argument("--metrics", default="on", choices=["on", "off"],
                     help="per-engine telemetry registries (off: stats() "
                          "reports empty metrics/recompiles)")

@@ -1,4 +1,4 @@
-"""Serving telemetry substrate (dependency-free, host-side only).
+"""Serving telemetry substrate (host-side only).
 
 Three pillars, one bundle:
 
@@ -6,18 +6,20 @@ Three pillars, one bundle:
                      ``MetricsRegistry``; Prometheus text exposition +
                      JSON snapshot (merged into engine ``stats()`` and the
                      serve.py report).
-  * ``trace``     -- ring-buffered span recorder exporting Chrome/Perfetto
-                     ``trace_event`` JSON (``serve --trace-out t.json``):
-                     the run as a timeline, one lane per request slot.
+  * ``trace``     -- the engine's step spans, ``jax.profiler``
+                     annotations on the profiler's clock: recorded beside
+                     the device ops whenever a profiler runs (``serve
+                     --trace-out DIR``, the benchmark's traced window).
   * ``jit_watch`` -- recompile sentinel: jit cache-miss deltas per step
                      function, tagged with the triggering bucket shape;
                      steady-state recompiles are a loud metric and an
                      optional hard failure (``strict``).
 
 ``Telemetry`` is the bundle the engine threads through the scheduler and
-page pool.  Everything is host-side bookkeeping — no jax imports, nothing
-on the traced path — so telemetry on vs off is token-identical by
-construction (asserted end-to-end in tests/test_observability.py).
+page pool.  ``metrics`` and ``jit_watch`` import no jax; ``trace`` imports
+``jax.profiler`` when a bundle is built.  Nothing here is on the traced
+path, so telemetry on vs off is token-identical by construction (asserted
+end-to-end in tests/test_observability.py).
 """
 
 from repro.observability.jit_watch import (  # noqa: F401
@@ -37,35 +39,31 @@ from repro.observability.metrics import (  # noqa: F401
     NullRegistry,
     global_registry,
 )
-from repro.observability.trace import (  # noqa: F401
-    NULL_TRACE,
-    NullTrace,
-    TraceRecorder,
-)
+from repro.observability.trace import Spans  # noqa: F401
 
 
 class Telemetry:
-    """The per-engine telemetry bundle: a metrics registry, a trace
-    recorder, and a recompile sentinel, each independently enable-able.
+    """The per-engine telemetry bundle: a metrics registry, the step span
+    API, and a recompile sentinel.
 
     Defaults are production-shaped: metrics on (cheap host-side updates),
-    trace off (enable per run via ``trace=True`` / serve ``--trace-out``),
-    sentinel counting but not raising (``strict_recompiles=True`` turns a
-    steady-state recompile into an exception — the tests' mode).
+    the sentinel counting but not raising (``strict_recompiles=True`` turns
+    a steady-state recompile into an exception — the tests' mode).  Spans
+    have no switch here: they are recorded while a profiler runs and cost
+    about a microsecond each otherwise.
     """
 
-    def __init__(self, metrics: bool = True, trace: bool = False,
-                 trace_capacity: int = 1 << 16,
+    def __init__(self, metrics: bool = True,
                  strict_recompiles: bool = False):
         self.registry = MetricsRegistry() if metrics else NULL_REGISTRY
-        self.trace = TraceRecorder(trace_capacity) if trace else NULL_TRACE
+        self.trace = Spans()
         self.jit_watch = (JitWatch(self.registry, strict=strict_recompiles)
                           if metrics else NULL_JIT_WATCH)
 
     @property
     def enabled(self) -> bool:
-        return self.registry.enabled or self.trace.enabled
+        return self.registry.enabled
 
     @classmethod
     def disabled(cls) -> "Telemetry":
-        return cls(metrics=False, trace=False)
+        return cls(metrics=False)

@@ -58,11 +58,6 @@ class ServingAPI:
         return self.engine.stats()
 
     # ------------------------------------------------------- telemetry --
-    @property
-    def trace(self):
-        """The engine's trace recorder (save()/to_chrome() for Perfetto)."""
-        return self.engine.trace
-
     def metrics_text(self) -> str:
         """Prometheus text exposition of the engine's registry — the body
         a network frontend's /metrics endpoint would serve."""

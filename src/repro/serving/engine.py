@@ -31,11 +31,11 @@ warmup-window profile with end-of-run stats.
 Telemetry (``repro.observability``): every engine owns a `Telemetry`
 bundle — a metrics registry fed at the natural seams (TTFT/ITL histograms
 at retire time, queue/pool gauges at step boundaries, token counters at
-prefill/decode), a trace recorder that renders the run as a Perfetto
-timeline (one lane per batch slot: request residency segments, admission
-prefills, preemption ends), and a jit recompile sentinel polled after
-every prefill/decode call.  All of it is host-side bookkeeping off the
-traced path: telemetry on vs off is token-identical by construction.
+prefill/decode), ``engine.*`` spans at each phase boundary of a step
+(``observability/trace.py`` lists them), recorded on the profiler's clock
+whenever a profiler runs, and a jit recompile sentinel polled after every
+prefill/decode call.  All of it is host-side bookkeeping off the traced
+path: telemetry on vs off is token-identical by construction.
 """
 
 from __future__ import annotations
@@ -133,16 +133,10 @@ class InferenceEngine:
         self.cfg, self.rt, self.sv = cfg, rt, sv
         self.clock = clock
         # telemetry bundle: per-engine registry (compare-mode engines don't
-        # share counters), trace recorder, recompile sentinel
+        # share counters), step spans, recompile sentinel
         self.tm = telemetry if telemetry is not None else Telemetry()
         self.metrics = self.tm.registry
-        self.trace = self.tm.trace
-        self.trace.lane(0, "engine")
-        for s in range(sv.max_batch):
-            self.trace.lane(1 + s, f"slot{s}")
-        # rid -> (trace t0, slot): open request-residency segment, emitted
-        # as one span on the slot's lane when the request retires/preempts
-        self._seg: Dict[int, tuple] = {}
+        self.span = self.tm.trace.span
         self.params = params if params is not None \
             else build_params(cfg, rt, seed)
 
@@ -382,17 +376,22 @@ class InferenceEngine:
         if self._inject_fault is not None:
             exc, self._inject_fault = self._inject_fault, None
             raise exc
-        for req in self.scheduler.expire(self.clock()):
-            self._finish_aborted(req)
-        wd = self._watchdog
-        if wd is not None:
-            wd.arm()
-        try:
-            n = (self._step_ragged() if self._ragged is not None
-                 else self._step_bucketed())
-        finally:
+        sch = self.scheduler
+        with self.span("engine.step", waiting=len(sch.waiting),
+                       running=len(sch.running)):
+            with self.span("engine.schedule"):
+                expired = sch.expire(self.clock())
+            for req in expired:
+                self._finish_aborted(req)
+            wd = self._watchdog
             if wd is not None:
-                wd.disarm()
+                wd.arm()
+            try:
+                n = (self._step_ragged() if self._ragged is not None
+                     else self._step_bucketed())
+            finally:
+                if wd is not None:
+                    wd.disarm()
         if wd is not None and wd.fired.is_set():
             self.metrics.counter(
                 "serving_step_deadline_exceeded_total",
@@ -405,29 +404,22 @@ class InferenceEngine:
 
     def _step_bucketed(self) -> int:
         t0 = time.perf_counter()
-        tt0 = self.trace.now()
         now = self.clock()
         if self.t_start is None:
             self.t_start = now
-        admitted = self.scheduler.admit(now)
-        n_tail = sum(1 for r in admitted if r.n_cached)
+        with self.span("engine.schedule"):
+            admitted = self.scheduler.admit(now)
         for req in admitted:
             self._prefill_request(req)
         self._retire()                 # a 1-token request is done at prefill
-        for req in self.scheduler.ensure_decode():
-            # recompute-style preemption ends the slot residency: close the
-            # segment so the timeline shows the slot going dark
-            seg = self._seg.pop(req.rid, None)
-            if seg is not None:
-                self.trace.complete(f"r{req.rid}", 1 + seg[1], seg[0],
-                                    rid=req.rid, outcome="preempted",
-                                    gen=len(req.tokens))
-        batch = self.scheduler.batch()
+        with self.span("engine.schedule"):
+            self.scheduler.ensure_decode()
+            batch = self.scheduler.batch()
         if batch:
             self._decode_batch(batch)
         self.n_steps += 1
         self._retire()
-        self._observe_step(t0, tt0, admitted, n_tail, batch)
+        self._observe_step(t0, batch)
         return len(self.scheduler.running)
 
     def _step_ragged(self) -> int:
@@ -438,12 +430,11 @@ class InferenceEngine:
         drains through the planner as chunks, possibly across several steps,
         interleaved with everyone else's decode tokens."""
         t0 = time.perf_counter()
-        tt0 = self.trace.now()
         now = self.clock()
         if self.t_start is None:
             self.t_start = now
-        admitted = self.scheduler.admit(now)
-        n_tail = sum(1 for r in admitted if r.n_cached)
+        with self.span("engine.schedule"):
+            admitted = self.scheduler.admit(now)
         for req in admitted:
             # prefix-cache hits are realized at admission (the planner only
             # ever feeds prefix[n_cached:]) — account them here, where the
@@ -453,13 +444,8 @@ class InferenceEngine:
             self.metrics.counter(
                 "prefix_hit_tokens_total",
                 "prompt/resume tokens served from cached pages").inc(hit)
-            self._seg.setdefault(req.rid, (self.trace.now(), req.slot))
-        for req in self.scheduler.ensure_decode():
-            seg = self._seg.pop(req.rid, None)
-            if seg is not None:
-                self.trace.complete(f"r{req.rid}", 1 + seg[1], seg[0],
-                                    rid=req.rid, outcome="preempted",
-                                    gen=len(req.tokens))
+        with self.span("engine.schedule"):
+            self.scheduler.ensure_decode()
         # the budget must cover every decode token plus one prefill-chunk
         # slot whenever a prefill-phase request is running — a saturated
         # decode set would otherwise starve later slots indefinitely (the
@@ -472,13 +458,15 @@ class InferenceEngine:
         need = n_decoding + (1 if any(not r.decoding for r in running) else 0)
         if need > self._budget:
             self._grow_budget(need)
-        plan = self.scheduler.plan_tokens(self._budget)
+        with self.span("engine.schedule"):
+            plan = self.scheduler.plan_tokens(self._budget)
         if plan:
-            self._ragged_exec(plan)
+            with self.span("engine.ragged",
+                           tokens=sum(n for _, _, n in plan)):
+                self._ragged_exec(plan)
         self.n_steps += 1
         self._retire()
-        self._observe_step(t0, tt0, admitted, n_tail,
-                           [r for r, _, _ in plan if r.decoding])
+        self._observe_step(t0, [r for r, _, _ in plan if r.decoding])
         return len(self.scheduler.running)
 
     def _ragged_exec(self, plan) -> None:
@@ -487,122 +475,119 @@ class InferenceEngine:
         its block table *before* attention (write-then-attend), so one mask
         rule — key position <= query position — is exactly causal for
         prefill chunks and exactly last-token for decode rows."""
+        span = self.span
         T = self._budget
         mb = self.sv.max_batch
-        tokens = np.zeros((1, T), np.int32)
-        positions = np.full((1, T), -1, np.int32)   # -1 = pad: writes drop
-        slots = np.full((T,), -1, np.int32)
-        emit_rows = np.full((mb,), -1, np.int32)
-        used = 0
-        for req, start, n in plan:
-            tokens[0, used:used + n] = req.prefix[start:start + n]
-            positions[0, used:used + n] = np.arange(start, start + n)
-            slots[used:used + n] = req.slot
-            if start + n == len(req.prefix):
-                # chunk reaches the prefix end: this row's logits emit the
-                # request's next token (for decode rows, n == 1, always)
-                emit_rows[req.slot] = used + n - 1
-            used += n
+        with span("engine.ragged.build"):
+            tokens = np.zeros((1, T), np.int32)
+            positions = np.full((1, T), -1, np.int32)  # -1 = pad: writes drop
+            slots = np.full((T,), -1, np.int32)
+            emit_rows = np.full((mb,), -1, np.int32)
+            used = 0
+            for req, start, n in plan:
+                tokens[0, used:used + n] = req.prefix[start:start + n]
+                positions[0, used:used + n] = np.arange(start, start + n)
+                slots[used:used + n] = req.slot
+                if start + n == len(req.prefix):
+                    # chunk reaches the prefix end: this row's logits emit
+                    # the request's next token (decode rows: n == 1, always)
+                    emit_rows[req.slot] = used + n - 1
+                used += n
         self._observe_packing(used, T)
         self._sync_tables([r for r, _, _ in plan])
-        tp0 = self.trace.now()
-        nxt, self.caches = self._ragged(
-            self.params, jnp.asarray(tokens), self.caches,
-            jnp.asarray(positions), self._tbl, jnp.asarray(slots),
-            jnp.asarray(emit_rows))
-        self._strip_tables()
-        self._poll_jit("ragged", (1, T))
-        # the step's ONE sanctioned device->host sync: token readback
-        nxt = np.asarray(nxt)  # repro: ignore[host-sync-in-hot-path]
-        ps = self.sv.page_size
-        for req, start, n in plan:
-            end = start + n
-            if req.decoding:
-                self.n_decode_tokens += 1
-                self.metrics.counter(
-                    "decode_tokens_total",
-                    "tokens emitted by decode steps").inc()
-            else:
-                self.n_prefill_tokens += n
-                self.metrics.counter(
-                    "prefill_tokens_total",
-                    "tokens pushed through prefill").inc(n)
-                if self.trace.enabled:
-                    self.trace.complete("chunk_prefill", 1 + req.slot, tp0,
-                                        rid=req.rid, tokens=n, start=start)
-            req.n_cached = end
-            if emit_rows[req.slot] >= 0:
-                if not req.decoding:
-                    # prefill just completed: register its full pages before
-                    # the emitted token joins the prefix (mirrors the
-                    # bucketed engine's post-prefill registration)
-                    self.kv.register_upto(req.rid, req.prefix, end)
-                req.tokens.append(int(nxt[req.slot]))
-                if req.t_first is None:
-                    req.t_first = self.clock()
-                req.decoding = True
-                if end % ps == 0 and len(req.tokens) > 1:
-                    # a generated-token page just filled (decode rows only —
-                    # end counts the token written this step)
-                    self.kv.register_upto(req.rid, req.prefix, end)
+        with span("engine.ragged.dispatch"):
+            nxt, self.caches = self._ragged(
+                self.params, jnp.asarray(tokens), self.caches,
+                jnp.asarray(positions), self._tbl, jnp.asarray(slots),
+                jnp.asarray(emit_rows))
+            self._strip_tables()
+            self._poll_jit("ragged", (1, T))
+        with span("engine.ragged.readback"):
+            # the step's ONE sanctioned device->host sync: token readback
+            nxt = np.asarray(nxt)  # repro: ignore[host-sync-in-hot-path]
+        with span("engine.ragged.apply"):
+            ps = self.sv.page_size
+            for req, start, n in plan:
+                end = start + n
+                if req.decoding:
+                    self.n_decode_tokens += 1
+                    self.metrics.counter(
+                        "decode_tokens_total",
+                        "tokens emitted by decode steps").inc()
+                else:
+                    self.n_prefill_tokens += n
+                    self.metrics.counter(
+                        "prefill_tokens_total",
+                        "tokens pushed through prefill").inc(n)
+                req.n_cached = end
+                if emit_rows[req.slot] >= 0:
+                    if not req.decoding:
+                        # prefill just completed: register its full pages
+                        # before the emitted token joins the prefix (mirrors
+                        # the bucketed engine's post-prefill registration)
+                        self.kv.register_upto(req.rid, req.prefix, end)
+                    req.tokens.append(int(nxt[req.slot]))
+                    if req.t_first is None:
+                        req.t_first = self.clock()
+                    req.decoding = True
+                    if end % ps == 0 and len(req.tokens) > 1:
+                        # a generated-token page just filled (decode rows
+                        # only — end counts the token written this step)
+                        self.kv.register_upto(req.rid, req.prefix, end)
 
-    def _observe_step(self, t0: float, tt0: float, admitted: List[Request],
-                      n_tail: int, batch: List[Request]) -> None:
+    def _observe_step(self, t0: float, batch: List[Request]) -> None:
         """Per-step telemetry: wall time + batch composition into the
-        registry, occupancy gauges sampled at the step boundary, and the
-        engine-lane step span."""
-        m = self.metrics
-        m.counter("steps_total", "engine decode-step boundaries").inc()
-        m.histogram("step_wall_us",
-                    "wall time per engine step").observe(
-                        (time.perf_counter() - t0) * 1e6)
-        if batch:
-            m.histogram("decode_batch_size", "running rows per decode step",
-                        buckets=COUNT_BUCKETS).observe(len(batch))
-        m.gauge("queue_depth",
-                "requests waiting for admission").set(
-                    len(self.scheduler.waiting))
-        m.gauge("running_requests",
-                "requests in the decode batch").set(
-                    len(self.scheduler.running))
-        # token utilization of this step's padded capacity (both step
-        # modes): useful rows over useful+padding rows computed since the
-        # previous boundary
-        du = self.n_tokens_packed - self._last_packed
-        dw = self.n_tokens_wasted - self._last_wasted
-        self._last_packed = self.n_tokens_packed
-        self._last_wasted = self.n_tokens_wasted
-        if du + dw:
-            m.gauge("token_utilization",
-                    "useful fraction of the step's padded token capacity"
-                    ).set(du / (du + dw))
-        if self.sv.layout == "paged":
-            m.gauge("kv_pool_in_use_pages",
-                    "pages held by running requests").set(self.kv.in_use)
-            m.gauge("kv_pool_warm_pages",
-                    "refcount-0 pages still indexed").set(len(self.kv.warm))
-            m.gauge("kv_pool_blank_pages",
-                    "free pages with no content").set(len(self.kv.blank))
-            m.gauge("kv_pool_occupancy",
-                    "in-use fraction of the page pool").set(
-                        self.kv.in_use / self.sv.num_pages)
-            m.gauge("kv_pool_high_water_pages",
-                    "peak concurrent in-use pages").set(self.kv.high_water)
-        if self.trace.enabled:
-            self.trace.complete(
-                "step", 0, tt0,
-                decode_rows=len(batch),
-                prefills=len(admitted) - n_tail, tail_prefills=n_tail,
-                queue_depth=len(self.scheduler.waiting),
-                pool_in_use=getattr(self.kv, "in_use", 0))
+        registry, occupancy gauges sampled at the step boundary."""
+        with self.span("engine.observe"):
+            m = self.metrics
+            m.counter("steps_total", "engine decode-step boundaries").inc()
+            m.histogram("step_wall_us",
+                        "wall time per engine step").observe(
+                            (time.perf_counter() - t0) * 1e6)
+            if batch:
+                m.histogram("decode_batch_size",
+                            "running rows per decode step",
+                            buckets=COUNT_BUCKETS).observe(len(batch))
+            m.gauge("queue_depth",
+                    "requests waiting for admission").set(
+                        len(self.scheduler.waiting))
+            m.gauge("running_requests",
+                    "requests in the decode batch").set(
+                        len(self.scheduler.running))
+            # token utilization of this step's padded capacity (both step
+            # modes): useful rows over useful+padding rows computed since the
+            # previous boundary
+            du = self.n_tokens_packed - self._last_packed
+            dw = self.n_tokens_wasted - self._last_wasted
+            self._last_packed = self.n_tokens_packed
+            self._last_wasted = self.n_tokens_wasted
+            if du + dw:
+                m.gauge("token_utilization",
+                        "useful fraction of the step's padded token capacity"
+                        ).set(du / (du + dw))
+            if self.sv.layout == "paged":
+                m.gauge("kv_pool_in_use_pages",
+                        "pages held by running requests").set(self.kv.in_use)
+                m.gauge("kv_pool_warm_pages",
+                        "refcount-0 pages still indexed").set(
+                            len(self.kv.warm))
+                m.gauge("kv_pool_blank_pages",
+                        "free pages with no content").set(len(self.kv.blank))
+                m.gauge("kv_pool_occupancy",
+                        "in-use fraction of the page pool").set(
+                            self.kv.in_use / self.sv.num_pages)
+                m.gauge("kv_pool_high_water_pages",
+                        "peak concurrent in-use pages").set(
+                            self.kv.high_water)
 
     def _retire(self) -> None:
-        now = self.clock()
-        for req in list(self.scheduler.running.values()):
-            if req.done:
-                self.scheduler.finish(req, now)
-                self._finished.append(req)
-                self._observe_retire(req)
+        with self.span("engine.retire"):
+            now = self.clock()
+            for req in list(self.scheduler.running.values()):
+                if req.done:
+                    self.scheduler.finish(req, now)
+                    self._finished.append(req)
+                    self._observe_retire(req)
 
     def _finish_aborted(self, req: Request) -> None:
         """Land a scheduler-aborted request (cancel, deadline expiry) in the
@@ -650,12 +635,6 @@ class InferenceEngine:
                             outcome=out).observe(
                                 (req.t_finish - req.t_first) * 1e6
                                 / (len(req.tokens) - 1))
-        seg = self._seg.pop(req.rid, None)
-        if seg is not None:
-            self.trace.complete(f"r{req.rid}", 1 + seg[1], seg[0],
-                                rid=req.rid, outcome=out,
-                                gen=len(req.tokens),
-                                preempts=req.n_preempts)
 
     def run_until_idle(self, max_steps: int = 100_000) -> None:
         for _ in range(max_steps):
@@ -710,23 +689,25 @@ class InferenceEngine:
         """Upload block-table rows whose page allocation changed since the
         last upload (admission, page growth).  This is the only host->device
         block-table traffic — steady-state decode uploads nothing."""
-        for req in batch:
-            ver = (req.slot, tuple(self.kv.pages.get(req.rid, ())))
-            if self._tbl_ver.get(req.rid) != ver:
-                self._tbl = self._tbl.at[req.slot].set(
-                    jnp.asarray(self.kv.table_row(req.rid)))
-                self._tbl_ver[req.rid] = ver
-                self.metrics.counter(
-                    "block_table_uploads_total",
-                    "host->device block-table row uploads").inc()
-        # drop versions of finished/preempted requests so dead entries don't
-        # accumulate.  Correctness doesn't ride on this prune: versions key
-        # on (slot, page ids), so a resumed rid re-admitting with the very
-        # same refcount-held pages into the same slot genuinely needs no
-        # re-upload, and any change in slot or ids forces one.
-        running = self.scheduler.running
-        for rid in [r for r in self._tbl_ver if r not in running]:
-            del self._tbl_ver[rid]
+        with self.span("engine.sync_tables", rows=len(batch)):
+            for req in batch:
+                ver = (req.slot, tuple(self.kv.pages.get(req.rid, ())))
+                if self._tbl_ver.get(req.rid) != ver:
+                    self._tbl = self._tbl.at[req.slot].set(
+                        jnp.asarray(self.kv.table_row(req.rid)))
+                    self._tbl_ver[req.rid] = ver
+                    self.metrics.counter(
+                        "block_table_uploads_total",
+                        "host->device block-table row uploads").inc()
+            # drop versions of finished/preempted requests so dead entries
+            # don't accumulate.  Correctness doesn't ride on this prune:
+            # versions key on (slot, page ids), so a resumed rid re-admitting
+            # with the very same refcount-held pages into the same slot
+            # genuinely needs no re-upload, and any change in slot or ids
+            # forces one.
+            running = self.scheduler.running
+            for rid in [r for r in self._tbl_ver if r not in running]:
+                del self._tbl_ver[rid]
 
     def _prefill_request(self, req: Request) -> None:
         """Prefill a (re-)admitted request's uncached prefix tail (batch of
@@ -744,100 +725,118 @@ class InferenceEngine:
         tail = prefix[hit:]
         n = len(tail)
         Lb = self._prompt_pad(n)
-        tokens = np.zeros((1, Lb), np.int32)
-        tokens[0, Lb - n:] = tail
-        base = np.arange(Lb, dtype=np.int32) - (Lb - n)
-        # pad rows must stay negative (dropped writes / masked queries) even
-        # after the hit offset shifts the real tail to hit..L-1
-        positions = np.where(base >= 0, base + hit, -1)[None, :]
+        span = self.span
+        with span("engine.prefill", rid=req.rid, tokens=n, bucket=Lb,
+                  hit=hit):
+            with span("engine.prefill.build"):
+                tokens = np.zeros((1, Lb), np.int32)
+                tokens[0, Lb - n:] = tail
+                base = np.arange(Lb, dtype=np.int32) - (Lb - n)
+                # pad rows must stay negative (dropped writes / masked
+                # queries) even after the hit offset shifts the real tail
+                # to hit..L-1
+                positions = np.where(base >= 0, base + hit, -1)[None, :]
+            if self.sv.layout == "paged":
+                self._sync_tables([req])
+                with span("engine.prefill.dispatch"):
+                    step = self._prefill_tail if hit else self._prefill
+                    tok, self.caches = step(
+                        self.params, jnp.asarray(tokens), self.caches,
+                        jnp.asarray(positions), self._tbl,
+                        jnp.asarray([req.slot], jnp.int32))
+                    self._strip_tables()
+                    self._poll_jit("prefill_tail" if hit else "prefill",
+                                   (1, Lb))
+            else:
+                with span("engine.prefill.dispatch"):
+                    # a fresh init row IS the reset: prefill into it, then
+                    # scatter the row into the slot (evicting any previous
+                    # tenant's state)
+                    row = init_caches(self.cfg, self.rt, batch=1,
+                                      seq=self.sv.max_ctx)
+                    tok, row = self._prefill(
+                        self.params, jnp.asarray(tokens), row,
+                        jnp.asarray(positions))
+                    self.caches = scatter_rows(self.caches, row, [req.slot])
+                    self._poll_jit("prefill", (1, Lb))
 
-        # open the slot-residency segment (resumes re-open a fresh one) and
-        # record this prefill as a span at its start
-        self._seg.setdefault(req.rid, (self.trace.now(), req.slot))
-        tp0 = self.trace.now()
-        if self.sv.layout == "paged":
-            self._sync_tables([req])
-            step = self._prefill_tail if hit else self._prefill
-            tok, self.caches = step(
-                self.params, jnp.asarray(tokens), self.caches,
-                jnp.asarray(positions), self._tbl,
-                jnp.asarray([req.slot], jnp.int32))
-            self._strip_tables()
-            self._poll_jit("prefill_tail" if hit else "prefill", (1, Lb))
-        else:
-            # a fresh init row IS the reset: prefill into it, then scatter
-            # the row into the slot (evicting any previous tenant's state)
-            row = init_caches(self.cfg, self.rt, batch=1, seq=self.sv.max_ctx)
-            tok, row = self._prefill(
-                self.params, jnp.asarray(tokens), row, jnp.asarray(positions))
-            self.caches = scatter_rows(self.caches, row, [req.slot])
-            self._poll_jit("prefill", (1, Lb))
-        self.trace.complete("tail_prefill" if hit else "prefill",
-                            1 + req.slot, tp0, rid=req.rid, tokens=n,
-                            hit=hit, bucket=Lb)
-
-        req.n_cached = L
-        self.n_prefill_tokens += n
-        self.n_prefix_hit_tokens += hit
-        m = self.metrics
-        m.counter("prefill_tokens_total",
-                  "tokens pushed through prefill").inc(n)
-        if hit:
-            m.counter("tail_prefill_tokens_total",
-                      "prefill tokens behind a prefix-cache hit").inc(n)
-        m.counter("prefix_hit_tokens_total",
-                  "prompt/resume tokens served from cached pages").inc(hit)
-        self._observe_packing(n, Lb)
-        self.kv.register_upto(req.rid, prefix, L)   # index newly-full pages
-        req.tokens.append(int(tok[0]))
-        if req.t_first is None:
-            req.t_first = self.clock()
+            req.n_cached = L
+            self.n_prefill_tokens += n
+            self.n_prefix_hit_tokens += hit
+            m = self.metrics
+            m.counter("prefill_tokens_total",
+                      "tokens pushed through prefill").inc(n)
+            if hit:
+                m.counter("tail_prefill_tokens_total",
+                          "prefill tokens behind a prefix-cache hit").inc(n)
+            m.counter("prefix_hit_tokens_total",
+                      "prompt/resume tokens served from cached pages").inc(
+                          hit)
+            self._observe_packing(n, Lb)
+            self.kv.register_upto(req.rid, prefix, L)  # index full pages
+            with span("engine.prefill.readback"):
+                req.tokens.append(int(tok[0]))
+                if req.t_first is None:
+                    req.t_first = self.clock()
 
     def _decode_batch(self, batch: List[Request]) -> None:
         """One decode step over the running set, padded to a bucket."""
         n = len(batch)
         nb = self.sv.decode_bucket(n)
-        tok = np.zeros((nb, 1), np.int32)
-        pos = np.full((nb, 1), -1, np.int32)
-        for i, req in enumerate(batch):
-            tok[i, 0] = req.tokens[-1]      # feed the newest generated token
-            pos[i, 0] = req.n_cached        # ... at the next cache position
+        span = self.span
+        with span("engine.decode", rows=n, bucket=nb):
+            with span("engine.decode.build"):
+                tok = np.zeros((nb, 1), np.int32)
+                pos = np.full((nb, 1), -1, np.int32)
+                for i, req in enumerate(batch):
+                    tok[i, 0] = req.tokens[-1]  # the newest generated token
+                    pos[i, 0] = req.n_cached    # ... at the next position
+                # pad rows point at slot 0 (paged) or the last slot
+                # (contiguous): their positions are -1, so writes drop and
+                # their (masked) attention output is discarded
+                slots = np.zeros((nb,), np.int32)
+                slots[:n] = [r.slot for r in batch]
 
-        if self.sv.layout == "paged":
-            # pad rows point at slot 0: their positions are -1, so writes
-            # drop and their (masked) attention output is discarded
-            self._sync_tables(batch)
-            slots = np.zeros((nb,), np.int32)
-            slots[:n] = [r.slot for r in batch]
-            nxt, self.caches = self._decode(
-                self.params, jnp.asarray(tok), self.caches,
-                jnp.asarray(pos), self._tbl, jnp.asarray(slots))
-            self._strip_tables()
-        else:
-            rows = [r.slot for r in batch] \
-                + [self.sv.max_batch - 1] * (nb - n)   # pads write nothing
-            sub = gather_rows(self.caches, rows)
-            nxt, sub = self._decode(
-                self.params, jnp.asarray(tok), sub, jnp.asarray(pos))
-            # scatter only the active rows back (a pad row may alias an
-            # active slot, and duplicate scatter indices would race)
-            self.caches = scatter_rows(
-                self.caches, gather_rows(sub, np.arange(n)), rows[:n])
-        self._poll_jit("decode", (nb, 1))
-        self._observe_packing(n, nb)
-        self.metrics.counter("decode_tokens_total",
-                             "tokens emitted by decode steps").inc(n)
-        # the step's ONE sanctioned device->host sync: token readback
-        nxt = np.asarray(nxt)  # repro: ignore[host-sync-in-hot-path]
-        ps = self.sv.page_size
-        for i, req in enumerate(batch):
-            req.n_cached += 1
-            req.tokens.append(int(nxt[i]))
-            if self.sv.layout == "paged" and req.n_cached % ps == 0:
-                # a generated-token page just filled: index it so preempted
-                # or follow-up requests sharing this prefix can hit it
-                self.kv.register_upto(req.rid, req.prefix, req.n_cached)
-        self.n_decode_tokens += n
+            if self.sv.layout == "paged":
+                self._sync_tables(batch)
+                with span("engine.decode.dispatch"):
+                    nxt, self.caches = self._decode(
+                        self.params, jnp.asarray(tok), self.caches,
+                        jnp.asarray(pos), self._tbl, jnp.asarray(slots))
+                    self._strip_tables()
+                    self._poll_jit("decode", (nb, 1))
+            else:
+                with span("engine.decode.dispatch"):
+                    rows = [r.slot for r in batch] \
+                        + [self.sv.max_batch - 1] * (nb - n)  # pads: no write
+                    sub = gather_rows(self.caches, rows)
+                    nxt, sub = self._decode(
+                        self.params, jnp.asarray(tok), sub, jnp.asarray(pos))
+                    # scatter only the active rows back (a pad row may
+                    # alias an active slot, and duplicate scatter indices
+                    # would race)
+                    self.caches = scatter_rows(
+                        self.caches, gather_rows(sub, np.arange(n)),
+                        rows[:n])
+                    self._poll_jit("decode", (nb, 1))
+            self._observe_packing(n, nb)
+            self.metrics.counter("decode_tokens_total",
+                                 "tokens emitted by decode steps").inc(n)
+            with span("engine.decode.readback"):
+                # the step's ONE sanctioned device->host sync: token readback
+                nxt = np.asarray(nxt)  # repro: ignore[host-sync-in-hot-path]
+            with span("engine.decode.apply"):
+                ps = self.sv.page_size
+                for i, req in enumerate(batch):
+                    req.n_cached += 1
+                    req.tokens.append(int(nxt[i]))
+                    if self.sv.layout == "paged" and req.n_cached % ps == 0:
+                        # a generated-token page just filled: index it so
+                        # preempted or follow-up requests sharing this
+                        # prefix can hit it
+                        self.kv.register_upto(req.rid, req.prefix,
+                                              req.n_cached)
+                self.n_decode_tokens += n
 
     # ------------------------------------------------------- stop/resume --
     def snapshot(self) -> Dict:

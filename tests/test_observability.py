@@ -1,9 +1,10 @@
 """Serving telemetry: metrics registry semantics (percentile edges, text
-exposition), trace ring overflow + Perfetto JSON round-trip, the recompile
-sentinel (once per new bucket shape, loud on steady-state), and end-to-end
+exposition), the recompile sentinel (once per new bucket shape, loud on
+steady-state), the engine's step spans (recorded only under a profiler,
+nested by phase), the names the device trace reads, and end-to-end
 neutrality — telemetry on vs off generates identical tokens."""
 
-import json
+import glob
 
 import jax
 import jax.numpy as jnp
@@ -13,12 +14,10 @@ import pytest
 from repro.configs import Runtime, ServingConfig, get_config
 from repro.observability import (
     NULL_REGISTRY,
-    NULL_TRACE,
     JitWatch,
     MetricsRegistry,
     RecompileError,
     Telemetry,
-    TraceRecorder,
 )
 from repro.serving.api import poisson_trace, run_trace
 from repro.serving.engine import InferenceEngine
@@ -106,61 +105,6 @@ def test_null_registry_is_inert():
     assert NULL_REGISTRY.render_text() == ""
 
 
-# ------------------------------------------------------------------- trace --
-def test_trace_ring_overflow_keeps_newest():
-    tr = TraceRecorder(capacity=4)
-    for i in range(10):
-        tr.instant(f"i{i}", tid=0)
-    assert tr.dropped == 6
-    names = [ev["name"] for ev in tr.events()]
-    assert names == ["i6", "i7", "i8", "i9"]          # oldest-first unroll
-    assert tr.to_chrome()["otherData"]["dropped_events"] == 6
-
-
-def test_trace_span_and_complete():
-    tr = TraceRecorder()
-    t0 = tr.now()
-    with tr.span("work", tid=1, rid=7):
-        pass
-    tr.complete("manual", tid=2, t0=t0, t1=t0 + 100.0)
-    evs = tr.events()
-    assert [e["ph"] for e in evs] == ["X", "X"]
-    assert evs[0]["args"] == {"rid": 7}
-    assert evs[1]["dur"] == pytest.approx(100.0)
-    assert all(e["ts"] >= 0 and e["dur"] >= 0 for e in evs)
-
-
-def test_trace_perfetto_json_round_trip(tmp_path):
-    tr = TraceRecorder()
-    tr.lane(0, "engine")
-    tr.lane(1, "slot0")
-    with tr.span("step", tid=0, decode_rows=2):
-        pass
-    path = str(tmp_path / "trace.json")
-    tr.save(path)
-    with open(path) as f:
-        doc = json.load(f)
-    evs = doc["traceEvents"]
-    meta = [e for e in evs if e["ph"] == "M"]
-    lanes = {e["tid"]: e["args"]["name"] for e in meta
-             if e["name"] == "thread_name"}
-    assert lanes == {0: "engine", 1: "slot0"}
-    spans = [e for e in evs if e["ph"] == "X"]
-    assert len(spans) == 1 and spans[0]["name"] == "step"
-    assert set(spans[0]) >= {"ts", "dur", "pid", "tid"}
-    assert doc["displayTimeUnit"] == "ms"
-
-
-def test_null_trace_is_inert(tmp_path):
-    with NULL_TRACE.span("x", tid=0):
-        pass
-    assert NULL_TRACE.now() == 0.0
-    path = str(tmp_path / "empty.json")
-    NULL_TRACE.save(path)
-    with open(path) as f:
-        assert json.load(f)["traceEvents"] == []
-
-
 # --------------------------------------------------------------- jit watch --
 class _FakeJit:
     """Stub with a controllable cache: set .size to simulate compiles."""
@@ -245,30 +189,91 @@ def reduced_cfg():
     return get_config("qwen2-0.5b").reduced()
 
 
-def _engine(cfg, telemetry=None, clock=None):
+def _engine(cfg, telemetry=None, clock=None, **sv_kw):
     rt = Runtime(quant_backend="float", cache_dtype="bfloat16", remat="none",
                  loss_chunk=0)
-    sv = ServingConfig(layout="paged", max_batch=2, page_size=8,
-                       num_pages=32, max_ctx=32)
+    sv = ServingConfig(**{"layout": "paged", "max_batch": 2, "page_size": 8,
+                          "num_pages": 32, "max_ctx": 32, **sv_kw})
     kw = {"clock": clock} if clock is not None else {}
     return InferenceEngine(cfg, rt, sv, seed=0, telemetry=telemetry, **kw)
 
 
-def test_engine_telemetry_is_token_identity_neutral(reduced_cfg):
+def _profiled(log_dir, fn):
+    """Run `fn()` under the profiler; its result and the engine spans it
+    recorded: one list per host thread of (name, start, end, args),
+    ordered by start, parents before their children."""
+    with jax.profiler.trace(str(log_dir)):
+        out = fn()
+    (path,) = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    threads = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = sorted(((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                           dict(ev.stats)) for ev in line.events
+                          if ev.name.startswith("engine.")),
+                         key=lambda e: (e[1], -e[2]))
+            if evs:
+                threads.append(evs)
+    return out, threads
+
+
+def _span_tree(threads):
+    """(span, [child spans]) for every span, a child being a span that
+    lies directly inside its parent on the same thread."""
+    nodes = []
+    for evs in threads:
+        stack = []
+        for ev in evs:
+            while stack and ev[1] >= stack[-1][0][2]:
+                stack.pop()
+            node = (ev, [])
+            if stack:
+                assert ev[2] <= stack[-1][0][2], (ev, stack[-1][0])
+                stack[-1][1].append(ev)
+            stack.append(node)
+            nodes.append(node)
+    return nodes
+
+
+def _children(nodes, name):
+    return [(ev, [c[0] for c in kids]) for ev, kids in nodes
+            if ev[0] == name]
+
+
+STEP_PHASES = {"engine.schedule", "engine.prefill", "engine.decode",
+               "engine.ragged", "engine.retire", "engine.observe"}
+
+
+def _check_steps(nodes, n_steps):
+    steps = _children(nodes, "engine.step")
+    assert len(steps) == n_steps
+    for ev, kids in steps:
+        assert {"waiting", "running"} <= set(ev[3])
+        assert "engine.schedule" in kids
+        assert set(kids) <= STEP_PHASES, kids
+    # every phase span sits inside a step
+    inside = sum((kids for _, kids in steps), [])
+    assert sorted(inside) == sorted(
+        ev[0] for ev, _ in nodes if ev[0] in STEP_PHASES)
+
+
+def test_engine_telemetry_is_token_identity_neutral(reduced_cfg, tmp_path):
     trace = poisson_trace(4, 1.0, [8], [4], reduced_cfg.vocab, seed=5)
-    # full telemetry, strict sentinel: a steady-state recompile would raise
-    tm = Telemetry(metrics=True, trace=True, strict_recompiles=True)
+    # full telemetry, strict sentinel (a steady-state recompile would
+    # raise), spans recorded under the profiler ...
+    tm = Telemetry(metrics=True, strict_recompiles=True)
     eng = _engine(reduced_cfg, telemetry=tm)
     eng.warmup([8])
-    stats, fin = run_trace(eng, trace)
+    (stats, fin), threads = _profiled(tmp_path, lambda: run_trace(eng, trace))
+    # ... against no registry and no profiler: the spans are inert
     _, fin_off = run_trace(_engine(reduced_cfg, Telemetry.disabled()), trace)
     assert [r.tokens for r in fin] == [r.tokens for r in fin_off]
 
-    # the trace covers every engine step, plus a residency span per request
-    names = [e["name"] for e in tm.trace.events()]
-    assert names.count("step") == stats["steps"]
-    for r in fin:
-        assert f"r{r.rid}" in names
+    # the profile covers every engine step
+    assert sum(1 for evs in threads for ev in evs
+               if ev[0] == "engine.step") == stats["steps"]
     # registry agrees with the engine's own counts; latency histograms
     # carry the typed outcome label (all four requests finished cleanly)
     hists = stats["metrics"]["histograms"]
@@ -286,6 +291,86 @@ def test_engine_telemetry_is_token_identity_neutral(reduced_cfg):
     assert stats["recompiles"]["total"] > 0
     # Prometheus exposition renders the same registry
     assert 'ttft_us_count{outcome="ok"} 4' in eng.metrics.render_text()
+
+
+@pytest.mark.parametrize("layout", ["paged", "contiguous"])
+def test_engine_step_spans_nest_by_phase(reduced_cfg, tmp_path, layout):
+    trace = poisson_trace(4, 1.0, [8, 16], [3], reduced_cfg.vocab, seed=3)
+    eng = _engine(reduced_cfg, layout=layout)
+    eng.warmup([8, 16])
+    (stats, fin), threads = _profiled(tmp_path, lambda: run_trace(eng, trace))
+    nodes = _span_tree(threads)
+    _check_steps(nodes, stats["steps"])
+    sync = ["engine.sync_tables"] if layout == "paged" else []
+    prefills = _children(nodes, "engine.prefill")
+    assert sorted(ev[3]["rid"] for ev, _ in prefills) == \
+        sorted(r.rid for r in fin)
+    for ev, kids in prefills:
+        assert ev[3]["bucket"] >= ev[3]["tokens"] and "hit" in ev[3]
+        assert kids == ["engine.prefill.build", *sync,
+                        "engine.prefill.dispatch", "engine.prefill.readback"]
+    decodes = _children(nodes, "engine.decode")
+    assert sum(ev[3]["rows"] for ev, _ in decodes) == stats["decode_tokens"]
+    for ev, kids in decodes:
+        assert 1 <= ev[3]["rows"] <= ev[3]["bucket"]
+        assert kids == ["engine.decode.build", *sync,
+                        "engine.decode.dispatch", "engine.decode.readback",
+                        "engine.decode.apply"]
+    for ev, kids in _children(nodes, "engine.sync_tables"):
+        assert "rows" in ev[3] and kids == []
+
+
+def test_engine_ragged_step_spans_nest_by_phase(reduced_cfg, tmp_path):
+    trace = poisson_trace(3, 1.0, [8, 16], [3], reduced_cfg.vocab, seed=3)
+    eng = _engine(reduced_cfg, step="ragged", token_budget=16)
+    eng.warmup()
+    (stats, fin), threads = _profiled(tmp_path, lambda: run_trace(eng, trace))
+    assert len(fin) == 3
+    nodes = _span_tree(threads)
+    _check_steps(nodes, stats["steps"])
+    assert not _children(nodes, "engine.prefill")
+    assert not _children(nodes, "engine.decode")
+    ragged = _children(nodes, "engine.ragged")
+    assert ragged
+    for ev, kids in ragged:
+        assert 1 <= ev[3]["tokens"] <= 16
+        assert kids == ["engine.ragged.build", "engine.sync_tables",
+                        "engine.ragged.dispatch", "engine.ragged.readback",
+                        "engine.ragged.apply"]
+
+
+def test_spans_record_only_under_a_profiler(tmp_path):
+    span = Telemetry.disabled().trace.span
+    with span("engine.before", rid=1):
+        pass
+
+    def during():
+        with span("engine.during", rid=2):
+            pass
+
+    _, threads = _profiled(tmp_path, during)
+    assert [(ev[0], ev[3]) for evs in threads for ev in evs] == \
+        [("engine.during", {"rid": 2})]
+
+
+def test_serving_step_jits_carry_the_trace_module_names(reduced_cfg):
+    # the benchmark finds each step in the device trace by the XLA module
+    # its jit lowers to
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "bench"))
+    from harness.layers import DECODE_MODULE, PREFILL_MODULE
+
+    eng = _engine(reduced_cfg)
+    tok = jnp.zeros((1, 8), jnp.int32)
+    args = (eng.params, tok, eng.caches, jnp.full((1, 8), -1, jnp.int32),
+            eng._tbl, jnp.zeros((1,), jnp.int32))
+    for fn, want in ((eng._decode, DECODE_MODULE),
+                     (eng._prefill, PREFILL_MODULE),
+                     (eng._prefill_tail, PREFILL_MODULE)):
+        text = fn.lower(*args).as_text()
+        assert f"module @{want} " in text, text[:200]
 
 
 def test_engine_stats_with_zero_finished_requests(reduced_cfg):
@@ -329,8 +414,10 @@ def test_engine_profile_stamped_with_step(reduced_cfg):
 
 def test_telemetry_bundle_modes():
     tm = Telemetry()
-    assert tm.registry.enabled and not tm.trace.enabled
+    assert tm.registry.enabled
     assert tm.enabled
     off = Telemetry.disabled()
     assert not off.enabled
     assert off.jit_watch.after_call("decode", (1, 1)) == 0
+    # spans have no switch of their own: the profiler is the switch
+    assert off.trace.span is tm.trace.span is jax.profiler.TraceAnnotation

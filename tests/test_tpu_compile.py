@@ -11,7 +11,11 @@ same tests and only the worker that runs this file loads the TPU compiler.
 """
 
 import functools
+import importlib
 import os
+import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +62,7 @@ def _compile(fn, one_chip, *shapes):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 @pytest.mark.parametrize("M", [8, 512], ids=["decode", "prefill"])
@@ -114,3 +119,65 @@ def test_flash_prefill_compiles(one_chip, no_persistent_cache):
                                bkv=b["bn"], interpret=False),
              one_chip, ((1, S, H, HD), jnp.bfloat16), kv, kv,
              ((1, S), jnp.int32), ((1, S), jnp.int32))
+
+
+def _trace_names(kernel):
+    """The names the benchmark reads a kernel's ops by in the device
+    trace (``bench/work/<kernel>.py``)."""
+    bench = str(Path(__file__).resolve().parents[1] / "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    return importlib.import_module(f"work.{kernel}").TRACE_NAMES
+
+
+def _serving_call(kernel):
+    """(call, operand shapes) of a serving-path kernel at qwen2-0.5b
+    widths."""
+    pool = ((PAGES, PS, KV, HD), jnp.bfloat16)
+    if kernel == "w4a16_matmul":
+        b = autotune.default_blocks(B, D, F, group_size=0)
+        return (functools.partial(w4a16_matmul, group_size=0,
+                                  interpret=False, **b),
+                [((B, D), jnp.bfloat16), ((D // 2, F), jnp.uint8),
+                 ((1, F), jnp.float32)])
+    if kernel == "paged_decode_attention":
+        return (functools.partial(paged_decode_attention, interpret=False,
+                                  **_paged_blocks("attn.paged_decode")),
+                [((B, H, HD), jnp.bfloat16), pool, pool,
+                 ((B, MAX_CTX // PS), jnp.int32), ((B,), jnp.int32)])
+    if kernel == "ragged_decode_attention":
+        return (functools.partial(ragged_decode_attention, interpret=False,
+                                  **_paged_blocks("attn.ragged")),
+                [((T, H, HD), jnp.bfloat16), pool, pool,
+                 ((B, MAX_CTX // PS), jnp.int32), ((T,), jnp.int32),
+                 ((T,), jnp.int32)])
+    b = autotune.attn_default_blocks("attn.prefill", S, S, H * HD)
+    kv = ((1, S, KV, HD), jnp.bfloat16)
+    return (functools.partial(flash_prefill, bq=b["bm"], bk=b["bk"],
+                              bkv=b["bn"], interpret=False),
+            [((1, S, H, HD), jnp.bfloat16), kv, kv, ((1, S), jnp.int32),
+             ((1, S), jnp.int32)])
+
+
+@pytest.mark.parametrize("kernel", ["w4a16_matmul", "paged_decode_attention",
+                                    "flash_prefill",
+                                    "ragged_decode_attention"])
+def test_serving_kernels_carry_their_trace_names(one_chip,
+                                                 no_persistent_cache,
+                                                 kernel):
+    names = (_trace_names(kernel) if kernel != "ragged_decode_attention"
+             else (kernel,))
+    call, shapes = _serving_call(kernel)
+
+    def serving_step(*args):            # a caller of another name
+        return call(*args)
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    lowered = jax.jit(serving_step).lower(*args)
+    # the Mosaic kernel is named by its pallas_call ...
+    assert set(re.findall(r'kernel_name = "([^"]*)"', lowered.as_text())) \
+        == set(names)
+    # ... and the compiled custom call, which the trace lists, likewise
+    calls = re.findall(r"%([\w.-]+) = \S+ custom-call\(.*tpu_custom_call",
+                       lowered.compile().as_text())
+    assert calls and {c.rsplit(".", 1)[0] for c in calls} == set(names)
