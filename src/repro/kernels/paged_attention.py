@@ -15,9 +15,13 @@ data movement is gone, so attention must consume the pages where they live.
     a scalar-prefetch operand so the BlockSpec index_map fetches *physical*
     pages straight from the pool — no gather, no dense intermediate.  Each
     program walks its row's logical pages ``pages_per_program`` at a time
-    with flash-style online-softmax accumulation in VMEM scratch; slots past
-    ``last_pos`` (and fully inactive rows, ``last_pos == -1``) are masked
-    in-kernel.  int8/int4 pools dequantize per fetched page with the same
+    with flash-style online-softmax accumulation in VMEM scratch.  The walk
+    stops at each row's last live page: past ``last_pos`` the index_map
+    keeps returning a page the program already holds, so the pipeline
+    starts no copy, and the body skips every page block that holds no live
+    position; fully inactive rows (``last_pos == -1``) skip every block and
+    emit zeros.  Slots past ``last_pos`` inside a live block are masked.
+    int8/int4 pools dequantize per fetched page with the same
     ``q * scale -> bf16`` rounding as ``serving.kv_pages`` gather path.
 
 ``flash_prefill``
@@ -49,6 +53,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -86,11 +91,75 @@ def _round_scores(s, compute_dtype):
 
 
 # ------------------------------------------------------- decode (paged) ----
+def live_page(j, u, last, *, pp: int, ps: int, window: int):
+    """The logical page that the u-th page spec of page block `j` fetches
+    for a query row whose newest position is `last` (-1 = idle row).
+
+    A live page maps to itself.  Past the row's last live page a spec keeps
+    the page it held in the last block where it was live, so its block
+    index stops changing and the pipeline starts no copy for it; a spec
+    that is never live holds the last live page (page 0 for an idle row).
+    With a window, pages wholly before it map to its first page.
+
+    Plain `lax` ops on non-negative scalars: each page spec traces its own
+    index map, in every compile of every decode bucket."""
+    end = lax.div(lax.max(last, 0), ps)
+    block = lax.min(lax.div(lax.max(lax.sub(end, u), 0), pp), j)
+    page = lax.min(lax.add(lax.mul(block, pp), u), end)
+    if window:
+        page = lax.max(page, lax.div(lax.max(lax.sub(last, window - 1), 0),
+                                     ps))
+    return page
+
+
+@functools.partial(jax.jit, static_argnames=("hd", "window", "scale"))
+def _attend_page(q, kb, vb, ks, vs, start, last, state, *, hd: int,
+                 window: int, scale: float):
+    """One page of a query row's online softmax: q the G query heads of
+    each KV head ([G, hd] each), kb/vb the page [ps, bkv, hd(/2)] (ks/vs
+    its scales, or None), `start` its first position; `state` the
+    per-head (m, l, acc), returned updated.  Jitted, so that a kernel
+    traces it once for all the pages it unrolls: traced inline, the
+    unrolled pages made tracing the decode step's most expensive part of
+    the engine's warmup."""
+    cd = q[0].dtype
+    if ks is not None:
+        kb = _dequant_slab(kb, ks, hd)
+        vb = _dequant_slab(vb, vs, hd)
+    kb = kb.transpose(1, 0, 2).astype(cd)          # [bkv, ps, hd]
+    vb = vb.transpose(1, 0, 2).astype(jnp.float32)
+    pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, kb.shape[1]), 1)
+    mask = pos <= last
+    if window:
+        mask &= (last - pos) < window
+    new = []
+    for h, (m_prev, l_prev, acc_prev) in enumerate(zip(*state)):
+        # scores [G, ps]: contract hd
+        s = jax.lax.dot_general(q[h], kb[h], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(mask, _round_scores(s, cd) * scale, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # explicit zero: exp(0)=1 would leak masked slots into l/acc while
+        # m is still NEG_INF
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        new.append((m_new,
+                    l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                    acc_prev * alpha + jax.lax.dot_general(
+                        p, vb[h], (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)))    # [G, hd]
+    return tuple(zip(*new))
+
+
 def _walk_pages(last, j, q_ref, refs, *, pp: int, ps: int, nj: int,
                 bkv: int, hd: int, window: int, quant: bool, scale: float):
     """One program's share of a page walk, shared by the paged-decode and
     ragged kernels: `pp` pages of one query row, each KV head of the tile
-    attended by its G query heads with online-softmax accumulation.
+    attended by its G query heads with online-softmax accumulation.  A block
+    with no position in ``(last - window, last]`` is skipped whole (an idle
+    row, ``last == -1``, skips every block).  In a live block, a page spec
+    past the row's end or before its window holds another live page of the
+    row (`live_page`), whose positions the mask removes.
 
     Every value stays a 2-D [rows, lanes] tile: the G query heads of KV head
     h are the leading-index slice ``q_ref[0, h]`` ([G, hd]), never a reshape
@@ -112,39 +181,26 @@ def _walk_pages(last, j, q_ref, refs, *, pp: int, ps: int, nj: int,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    cd = q_ref.dtype
-    for u in range(pp):                            # static unroll: pages
-        kb = k_refs[u][0]                          # [ps, bkv, hd(/2)]
-        vb = v_refs[u][0]
-        if quant:
-            kb = _dequant_slab(kb, ks_refs[u][0], hd)
-            vb = _dequant_slab(vb, vs_refs[u][0], hd)
-        kb = kb.transpose(1, 0, 2).astype(cd)      # [bkv, ps, hd]
-        vb = vb.transpose(1, 0, 2).astype(jnp.float32)
+    start = j * pp * ps
+    live = start <= last                           # False for idle rows
+    if window:
+        live &= start + pp * ps > last - window + 1
 
-        pos = (j * pp + u) * ps + jax.lax.broadcasted_iota(
-            jnp.int32, (1, ps), 1)
-        mask = (pos <= last) & (last >= 0)
-        if window:
-            mask &= (last - pos) < window
-
-        for h in range(bkv):                       # static unroll: KV heads
-            # scores [G, ps]: contract hd
-            s = jax.lax.dot_general(
-                q_ref[0, h], kb[h], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            s = jnp.where(mask, _round_scores(s, cd) * scale, NEG_INF)
-            m_prev = m_ref[h]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)   # explicit zero:
-            # an all-masked prefix keeps m at NEG_INF and exp(0)=1 would
-            # otherwise leak the masked slots into l/acc
-            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                p, vb[h], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)    # [G, hd]
-            m_ref[h] = m_new
+    @pl.when(live)
+    def _block():
+        q = tuple(q_ref[0, h] for h in range(bkv))
+        state = tuple(tuple(r[h] for h in range(bkv))
+                      for r in (m_ref, l_ref, acc_ref))
+        for u in range(pp):                        # static unroll: pages
+            state = _attend_page(
+                q, k_refs[u][0], v_refs[u][0],
+                ks_refs[u][0] if quant else None,
+                vs_refs[u][0] if quant else None,
+                start + u * ps, last, state, hd=hd, window=window,
+                scale=scale)
+        for r, values in zip((m_ref, l_ref, acc_ref), state):
+            for h in range(bkv):
+                r[h] = values[h]
 
     @pl.when(j == nj - 1)
     def _emit():
@@ -229,7 +285,7 @@ def paged_decode_attention(
     bkv: int = 0,              # KV-head tile, 0 = all heads
     interpret: bool = None,
 ) -> jnp.ndarray:
-    P, _, KV = k_pool.shape[:3]
+    P, ps, KV = k_pool.shape[:3]
     pps = tbl.shape[1]
     bkv = _largest_divisor(KV, bkv if bkv > 0 else KV)
     pp = max(1, min(pp, pps))
@@ -237,11 +293,13 @@ def paged_decode_attention(
     def page_index(u):
         # the scalar-prefetched block table turns the logical page into a
         # physical pool index right in the index_map: the pipeline DMAs the
-        # page from wherever it lives, no gather ever materializes.  Dead
-        # table slots carry the out-of-bounds sentinel (== P); clamp so the
-        # DMA stays in bounds — the kernel masks those positions anyway.
+        # page from wherever it lives, no gather ever materializes.  Past
+        # the row's last live page the index stops changing (`live_page`),
+        # so dead pages are never copied.  An idle row's table slot may
+        # carry the out-of-bounds sentinel (== P); clamp so the DMA stays
+        # in bounds — the kernel skips that page anyway.
         def index(b, h, j, tbl_ref, lp_ref):
-            logical = jnp.minimum(j * pp + u, pps - 1)
+            logical = live_page(j, u, lp_ref[b], pp=pp, ps=ps, window=window)
             return (jnp.minimum(tbl_ref[b, logical], P - 1), 0, h, 0)
         return index
 

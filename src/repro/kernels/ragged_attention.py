@@ -51,6 +51,7 @@ from .dispatch import default_interpret
 from .paged_attention import (
     _largest_divisor,
     _walk_pages,
+    live_page,
     page_walk_call,
     paged_decode_attention_xla,
 )
@@ -60,8 +61,8 @@ def _ragged_kernel(slot_ref, pos_ref, tbl_ref, q_ref, *refs, **kw):
     # slot_ref/tbl_ref are consumed by the BlockSpec index_maps; the body
     # only needs the token's own position for masking.  pos <= token_pos is
     # causal for prefill rows (the chunk's K/V is already in the pool) and
-    # last-token for decode rows; padding rows (token_pos == -1) mask
-    # everything and emit zeros.
+    # last-token for decode rows; padding rows (token_pos == -1) skip
+    # every page and emit zeros.
     del slot_ref, tbl_ref
     _walk_pages(pos_ref[pl.program_id(0)], pl.program_id(2), q_ref, refs,
                 **kw)
@@ -83,19 +84,22 @@ def ragged_decode_attention(
     bkv: int = 0,              # KV-head tile, 0 = all heads
     interpret: bool = None,
 ) -> jnp.ndarray:
-    P, _, KV = k_pool.shape[:3]
+    P, ps, KV = k_pool.shape[:3]
     pps = tbl.shape[1]
     bkv = _largest_divisor(KV, bkv if bkv > 0 else KV)
     pp = max(1, min(pp, pps))
 
     def page_index(u):
         # two scalar hops per program: token row -> table row -> physical
-        # page.  Padding rows (slot -1) clamp to row 0 and dead table slots
+        # page.  The walk stops at the token's own position (`live_page`:
+        # later pages keep an index already fetched, so they are never
+        # copied).  Padding rows (slot -1) clamp to row 0, whose slot may
         # carry the out-of-bounds sentinel (== P); both clamp into bounds
-        # and mask away in the kernel body.
+        # and the kernel body skips them.
         def index(t, h, j, slot_ref, pos_ref, tbl_ref):
             row = jnp.maximum(slot_ref[t], 0)
-            logical = jnp.minimum(j * pp + u, pps - 1)
+            logical = live_page(j, u, pos_ref[t], pp=pp, ps=ps,
+                                window=window)
             return (jnp.minimum(tbl_ref[row, logical], P - 1), 0, h, 0)
         return index
 

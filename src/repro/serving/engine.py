@@ -799,6 +799,18 @@ class InferenceEngine:
 
             if self.sv.layout == "paged":
                 self._sync_tables(batch)
+                # the pages the decode attention walks (each row up to its
+                # newest position) against the bucket's whole block table:
+                # their ratio is the share of the table the walk visits
+                ps = self.sv.page_size
+                self.metrics.counter(
+                    "paged_decode_pages_live_total",
+                    "KV pages holding a decode row's positions").inc(
+                        sum(r.n_cached // ps + 1 for r in batch))
+                self.metrics.counter(
+                    "paged_decode_pages_table_total",
+                    "block-table pages of the decode buckets").inc(
+                        nb * self.sv.pages_per_seq)
                 with span("engine.decode.dispatch"):
                     nxt, self.caches = self._decode(
                         self.params, jnp.asarray(tok), self.caches,

@@ -3,6 +3,8 @@ reference across page sizes, ragged contexts, cache dtypes, and window
 masking; flash prefill vs the dense core; engine-level bit-exactness of the
 fused path including a preempt->resume trace; autotune attn tags."""
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -11,6 +13,7 @@ import pytest
 from repro.configs import Runtime, ServingConfig, get_config
 from repro.kernels import autotune, ops
 from repro.kernels import paged_attention as pa
+from repro.kernels.ragged_attention import ragged_decode_attention
 from repro.models.attention import attention_core, quantize_kv
 from repro.serving.api import poisson_trace, run_trace
 from repro.serving.engine import InferenceEngine, build_params
@@ -96,6 +99,136 @@ def test_decode_pallas_kernel_matches_reference(cache_dtype):
         np.testing.assert_allclose(np.float32(out)[act],
                                    np.float32(ref)[act], atol=2e-2)
         assert (np.float32(out)[~act] == 0).all()
+
+
+# rows ending mid-page (5), on a page's last slot (7), on a page's first
+# slot (8), on a 4-page block's last and first slot (15, 16), at position 0
+# and at max_ctx - 1 (31), and an idle row; ps 4, 8 pages a row
+_ENDS = [5, 7, 8, 15, 16, 0, 31, -1]
+_PS, _PPS = 4, 8
+
+
+def _walk(kernel, q, cache, last, window=0, pp=4):
+    """Run one of the two page-walking kernels (interpret mode) over a
+    batch of query rows; the ragged kernel gets each row as one token of
+    its own table row (idle rows as padding, slot -1)."""
+    scales = cache.get("k_scale"), cache.get("v_scale")
+    if kernel == "paged":
+        return pa.paged_decode_attention(
+            q, cache["k"], cache["v"], cache["tbl"], last, *scales,
+            window=window, pp=pp, interpret=True)
+    slot = jnp.where(last >= 0, jnp.arange(last.shape[0], dtype=jnp.int32),
+                     -1)
+    return ragged_decode_attention(
+        q, cache["k"], cache["v"], cache["tbl"], slot, last, *scales,
+        window=window, pp=pp, interpret=True)
+
+
+@pytest.mark.parametrize("window", [0, 6])
+@pytest.mark.parametrize("pp", [1, 4, _PPS])
+@pytest.mark.parametrize("cache_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("kernel", ["paged", "ragged"])
+def test_decode_kernels_match_reference_at_row_ends(kernel, cache_dtype, pp,
+                                                    window):
+    """Both page-walking kernels stop at each row's last live page; rows
+    that end anywhere in a page or a page block, and idle rows, still
+    match the gather reference."""
+    rng = np.random.default_rng(pp * 10 + window)
+    KV, G, hd = 2, 2, 16
+    cache = _pool_setup(rng, len(_ENDS), KV, hd, _PS, _PPS, cache_dtype)
+    q = jnp.asarray(rng.standard_normal((len(_ENDS), KV * G, hd)),
+                    jnp.bfloat16)
+    last = jnp.asarray(_ENDS, jnp.int32)
+    ref = _reference(q, cache, last, window=window)
+    out = np.float32(_walk(kernel, q, cache, last, window=window, pp=pp))
+    act = np.asarray(last) >= 0
+    np.testing.assert_allclose(out[act], np.float32(ref)[act], atol=2e-2)
+    assert (out[~act] == 0).all()
+
+
+@pytest.mark.parametrize("window", [0, 6])
+@pytest.mark.parametrize("pp", [1, 3, 4, _PPS])
+def test_live_page_fetches_each_live_page_once(pp, window):
+    """The index map of the page walk: every live page maps to itself, no
+    spec ever leaves the row's live pages, and past the row's end a spec's
+    page stops changing from one block to the next, so the pipeline copies
+    each live page once, the row's last page once more for each spec that
+    is never live, and no dead page at all."""
+    nj = -(-_PPS // pp)
+    j = np.arange(nj, dtype=np.int32)[:, None]
+    u = np.arange(pp, dtype=np.int32)[None, :]
+    lasts = np.arange(-1, _PS * _PPS, dtype=np.int32)
+    pages = np.asarray(jnp.vectorize(functools.partial(
+        pa.live_page, pp=pp, ps=_PS, window=window))(
+            j[None], u[None], lasts[:, None, None]))
+    for last, page in zip(lasts.tolist(), pages):
+        end = max(last, 0) // _PS
+        first = max(last - window + 1, 0) // _PS if window else 0
+        logical = j * pp + u
+        live = (logical >= first) & (logical <= end) & (last >= 0)
+        assert (page[live] == logical[live]).all(), last
+        assert ((page >= first) & (page <= end)).all(), last
+        fetched = np.ones_like(page, bool)
+        fetched[1:] = page[1:] != page[:-1]
+        assert not (fetched & (logical > end))[1:].any(), last
+        if not window:
+            assert fetched.sum() == live.sum() + (last < 0) + max(
+                0, pp - end - 1), last
+
+
+def _poisoned_pool(rng, B, KV, hd, ends, cache_dtype):
+    """A pool in which every page that holds no live position of any row
+    is NaN (float pools) or has NaN scales (int8), with the clean pool the
+    reference reads.  Rows own distinct pages drawn from all but the last
+    physical page; past its last live page a row's table holds the
+    out-of-bounds sentinel (even rows, clamped to the last page) or other
+    dead pages (odd rows)."""
+    P = B * _PPS + 4
+    clean = _pool_setup(rng, B, KV, hd, _PS, _PPS, cache_dtype)
+    n_live = [e // _PS + 1 if e >= 0 else 0 for e in ends]
+    perm = rng.permutation(P - 1)
+    tbl = np.full((B, _PPS), P, np.int32)
+    off = 0
+    for b, n in enumerate(n_live):
+        tbl[b, :n] = perm[off:off + n]
+        off += n
+    live = set(perm[:off].tolist())
+    dead = np.asarray([p for p in range(P) if p not in live])
+    for b, n in enumerate(n_live):
+        if b % 2:
+            tbl[b, n:] = rng.choice(dead, _PPS - n)
+    clean["tbl"] = jnp.asarray(tbl)
+    poisoned = dict(clean)
+    names = ("k_scale", "v_scale") if "k_scale" in clean else ("k", "v")
+    for name in names:
+        poisoned[name] = clean[name].at[dead].set(jnp.nan)
+    return clean, poisoned
+
+
+@pytest.mark.parametrize("pp", [1, 4])
+@pytest.mark.parametrize("cache_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("kernel", ["paged", "ragged"])
+def test_decode_kernels_never_read_dead_pages(kernel, cache_dtype, pp):
+    """Dead pages are neither attended nor multiplied by a zero weight: a
+    NaN in every page that holds no live position (the pages a row's
+    table maps past its end, and the last physical page, where sentinel
+    slots clamp) leaves live rows finite and equal to the reference, and
+    idle rows, and an all-idle batch, exactly zero."""
+    rng = np.random.default_rng(31 + pp)
+    KV, G, hd = 2, 2, 16
+    B = len(_ENDS)
+    clean, poisoned = _poisoned_pool(rng, B, KV, hd, _ENDS, cache_dtype)
+    q = jnp.asarray(rng.standard_normal((B, KV * G, hd)), jnp.bfloat16)
+    last = jnp.asarray(_ENDS, jnp.int32)
+    act = np.asarray(last) >= 0
+    out = np.float32(_walk(kernel, q, poisoned, last, pp=pp))
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out[act], np.float32(_reference(q, clean,
+                                                               last))[act],
+                               atol=2e-2)
+    assert (out[~act] == 0).all()
+    idle = jnp.full((B,), -1, jnp.int32)
+    assert (np.float32(_walk(kernel, q, poisoned, idle, pp=pp)) == 0).all()
 
 
 def test_decode_window_masking():
@@ -196,6 +329,31 @@ def test_engine_fused_vs_gather_bit_identical(reduced_cfg):
     rt_g = dataclasses.replace(rt, paged_attn="gather")
     _, fin_g = run_trace(_engine(reduced_cfg, rt_g, params=params), trace)
     assert [r.tokens for r in fin_f] == [r.tokens for r in fin_g]
+
+
+def test_engine_counts_live_and_table_pages(reduced_cfg):
+    """Each paged decode step adds its rows' live pages (up to the newest
+    position) and its bucket's whole table to two counters."""
+    rt = Runtime(quant_backend="float", cache_dtype="bfloat16", remat="none",
+                 loss_chunk=0)
+    eng = _engine(reduced_cfg, rt, page_size=4)
+    steps = []
+    decode = eng._decode_batch
+
+    def spy(batch):
+        steps.append(([r.n_cached for r in batch],
+                      eng.sv.decode_bucket(len(batch))))
+        decode(batch)
+
+    eng._decode_batch = spy
+    run_trace(eng, poisson_trace(3, 1.0, [8, 13], [6], reduced_cfg.vocab,
+                                 seed=3))
+    assert any(len(n) == 2 for n, _ in steps)
+    counters = eng.metrics.snapshot()["counters"]
+    assert counters["paged_decode_pages_live_total"] == sum(
+        c // 4 + 1 for n, _ in steps for c in n)
+    assert counters["paged_decode_pages_table_total"] == sum(
+        nb * eng.sv.pages_per_seq for _, nb in steps)
 
 
 def test_engine_fused_preempt_resume_matches_gather(reduced_cfg):

@@ -112,6 +112,36 @@ def test_ragged_decode_attention_compiles(one_chip, no_persistent_cache):
              ((T,), jnp.int32))
 
 
+@pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("kernel", ["paged_decode_attention",
+                                    "ragged_decode_attention"])
+def test_page_walks_compile_at_chat_shapes(one_chip, no_persistent_cache,
+                                           kernel, cache_dtype):
+    """The page walks that stop at each row's last live page, at the chat
+    deployment's shapes: a 64-row bucket, max_ctx 2560 in 16-token pages
+    over an 81,920-token pool."""
+    rows, max_ctx, pages = 64, 2560, 81920 // PS
+    pool = ((pages, PS, KV, HD), cache_dtype)
+    scales = ([((pages, PS, KV, 1), jnp.float32)] * 2
+              if cache_dtype == jnp.int8 else [])
+    tbl = ((rows, max_ctx // PS), jnp.int32)
+    b = autotune.attn_default_blocks(
+        "attn.paged_decode" if kernel == "paged_decode_attention"
+        else "attn.ragged", rows, max_ctx, H * HD, group_size=PS)
+    blocks = {"pp": b["bk"] // PS, "bkv": b["bn"]}
+    if kernel == "paged_decode_attention":
+        call = functools.partial(paged_decode_attention, interpret=False,
+                                 **blocks)
+        rest = [((rows,), jnp.int32)]
+    else:
+        call = functools.partial(ragged_decode_attention, interpret=False,
+                                 **blocks)
+        rest = [((rows,), jnp.int32)] * 2
+    _compile(call, one_chip, ((rows, H, HD), jnp.bfloat16), pool, pool, tbl,
+             *rest, *scales)
+
+
 def test_flash_prefill_compiles(one_chip, no_persistent_cache):
     b = autotune.attn_default_blocks("attn.prefill", S, S, H * HD)
     kv = ((1, S, KV, HD), jnp.bfloat16)
