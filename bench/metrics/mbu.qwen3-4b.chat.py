@@ -1,0 +1,10 @@
+"""`mbu.chat`, read in the qwen3-4b chat cell
+by the same code."""
+
+from harness.layers import load_metric
+
+UNIT = "%"
+
+
+def read(run):
+    return load_metric("mbu.chat").read(run)

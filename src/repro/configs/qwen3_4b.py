@@ -1,7 +1,7 @@
 """qwen3-4b [dense] — GQA + qk_norm.
 
 36L d_model=2560 32H (GQA kv=8) d_ff=9728 vocab=151936.
-Source: hf:Qwen/Qwen3-4B (per-assignment citation hf:Qwen/Qwen3-8B). [hf tier]
+Tied head. Source: hf:Qwen/Qwen3-4B config.json. [hf tier]
 """
 
 from .base import ArchConfig
@@ -18,6 +18,7 @@ CONFIG = ArchConfig(
     vocab=151936,
     qk_norm=True,
     rope="rope",
+    tie_embeddings=True,
     rope_theta=1000000.0,
-    source="hf:Qwen/Qwen3-8B [hf]",
+    source="hf:Qwen/Qwen3-4B [hf]",
 )

@@ -71,7 +71,9 @@ def make_serving_steps(cfg: ArchConfig, rt: Runtime, paged: bool = False):
     continuous-batching engine.
 
     All donate the cache argument (the KV pool is the dominant buffer and
-    is threaded through every step) and run greedy argmax *inside* the jit,
+    is threaded through every step; the layer loop writes it in place, so
+    a step holds one copy of it: ``models.transformer.forward``) and run
+    greedy argmax *inside* the jit,
     so the only device->host traffic per step is one int32 per row.  jit
     re-specializes per input shape, so the engine's batch/prompt bucketing
     bounds the number of compilations — one per (bucket) signature, cached

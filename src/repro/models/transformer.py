@@ -216,6 +216,10 @@ def forward(
     per_repeat = layers_per_repeat(params)
     use_scan = (rt.scan_layers and not per_repeat
                 and plan_repeat_uniform(active_plan(cfg, rt), cfg))
+    # The stacked caches are read and written back one unit at a time in
+    # the buffer they arrived in (`_take_unit`/`_put_unit`), never scanned
+    # as xs/ys: an output stack beside the input stack is a second copy of
+    # the KV pool for the whole step, however the step donates its caches.
     if use_scan:
         body = make_body([f"block[{j}]" for j in range(P)])
         if rep_caches is None:
@@ -223,23 +227,30 @@ def forward(
                 lambda c, p: body(c, (p, None)), (x, aux0), params["layers"]
             )
         else:
-            (x, aux_sum), new_rep = jax.lax.scan(
-                body, (x, aux0), (params["layers"], rep_caches)
+            def cached_body(carry, xs):
+                xc, aux_acc, rep = carry
+                unit_p, r = xs
+                (xc, aux_acc), nc = body((xc, aux_acc),
+                                         (unit_p, _take_unit(rep, r)))
+                return (xc, aux_acc, _put_unit(rep, nc, r)), None
+
+            (x, aux_sum, new_rep), _ = jax.lax.scan(
+                cached_body, (x, aux0, rep_caches),
+                (params["layers"], jnp.arange(cfg.n_repeats)),
             )
     else:
-        new_rep_list = []
+        new_rep = rep_caches
         carry = (x, aux0)
         for r in range(cfg.n_repeats):
             unit_p = (params["layers"][f"r{r}"] if per_repeat
                       else jax.tree.map(lambda a: a[r], params["layers"]))
-            unit_c = (jax.tree.map(lambda a: a[r], rep_caches)
+            unit_c = (_take_unit(new_rep, r)
                       if rep_caches is not None else None)
             body = make_body([f"block[{r * P + j}]" for j in range(P)])
             carry, nc = body(carry, (unit_p, unit_c))
-            new_rep_list.append(nc)
+            if rep_caches is not None:
+                new_rep = _put_unit(new_rep, nc, r)
         x, aux_sum = carry
-        new_rep = (jax.tree.map(lambda *xs: jnp.stack(xs), *new_rep_list)
-                   if rep_caches is not None else None)
 
     new_caches = None
     if caches is not None:
@@ -260,6 +271,18 @@ def forward(
 
     logits = _logits(params, x, cfg, rt)
     return logits, new_caches, aux_mean
+
+
+def _take_unit(rep, r):
+    """Repeat unit `r`'s caches out of the stacked tree."""
+    return jax.tree.map(lambda a: a[r], rep)
+
+
+def _put_unit(rep, unit, r):
+    """Write unit `r`'s caches back into the stacked tree, in place."""
+    return jax.tree.map(
+        lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, r, 0),
+        rep, unit)
 
 
 def _logits(params, x, cfg: ArchConfig, rt: Runtime):

@@ -217,6 +217,10 @@ class InferenceEngine:
         self._last_packed = 0
         self._last_wasted = 0
         self.t_start = None
+        # device memory, set by warmup: the KV cache's bytes and, per step
+        # jit, the largest temporary allocation over its compiled buckets
+        self.kv_pool_bytes = 0
+        self.step_temp_bytes: Dict[str, int] = {}
         self._profile: Optional[Dict] = None
         self._profile_step: Optional[int] = None
 
@@ -287,7 +291,16 @@ class InferenceEngine:
         new prompt bucket mid-run; that compile is attributed to the run.
 
         Ragged mode has exactly ONE signature — the token budget — so
-        warmup is one dummy call regardless of the trace's prompt mix."""
+        warmup is one dummy call regardless of the trace's prompt mix.
+
+        Warmup also records the device memory the engine holds: the KV
+        cache's bytes (``kv_pool_bytes``) and each step jit's largest
+        temporary allocation over its buckets (``step_temp_bytes``)."""
+        self.kv_pool_bytes = sum(a.nbytes
+                                 for a in jax.tree.leaves(self.caches))
+        self.metrics.gauge(
+            "kv_pool_bytes", "device bytes of the KV cache (every layer)"
+        ).set(self.kv_pool_bytes)
         if self._ragged is not None:
             self._warm_ragged()
             return
@@ -295,44 +308,67 @@ class InferenceEngine:
             tokens = jnp.zeros((1, L), jnp.int32)
             positions = jnp.full((1, L), -1, jnp.int32)
             if self.sv.layout == "paged":
-                _, self.caches = self._prefill(
-                    self.params, tokens, self.caches, positions,
-                    self._tbl, jnp.zeros((1,), jnp.int32))
+                _, self.caches = self._warm_call(
+                    "prefill", self._prefill, self.params, tokens,
+                    self.caches, positions, self._tbl,
+                    jnp.zeros((1,), jnp.int32))
                 self._strip_tables()
                 self._poll_jit("prefill", (1, L))
                 if self.sv.prefix_cache:
                     # prefix hits run the tail-prefill step over the same
                     # bucket set (a tail can also land in a smaller bucket
                     # mid-run; that compile is attributed to the run)
-                    _, self.caches = self._prefill_tail(
-                        self.params, tokens, self.caches, positions,
-                        self._tbl, jnp.zeros((1,), jnp.int32))
+                    _, self.caches = self._warm_call(
+                        "prefill_tail", self._prefill_tail, self.params,
+                        tokens, self.caches, positions, self._tbl,
+                        jnp.zeros((1,), jnp.int32))
                     self._strip_tables()
                     self._poll_jit("prefill_tail", (1, L))
             else:
                 row = init_caches(self.cfg, self.rt, batch=1,
                                   seq=self.sv.max_ctx)
-                self._prefill(self.params, tokens, row, positions)
+                self._warm_call("prefill", self._prefill, self.params,
+                                tokens, row, positions)
                 self._poll_jit("prefill", (1, L))
         for nb in self.sv.buckets:
             tok = jnp.zeros((nb, 1), jnp.int32)
             pos = jnp.full((nb, 1), -1, jnp.int32)
             if self.sv.layout == "paged":
-                _, self.caches = self._decode(
-                    self.params, tok, self.caches, pos,
-                    self._tbl, jnp.zeros((nb,), jnp.int32))
+                _, self.caches = self._warm_call(
+                    "decode", self._decode, self.params, tok, self.caches,
+                    pos, self._tbl, jnp.zeros((nb,), jnp.int32))
                 self._strip_tables()
             else:
                 sub = gather_rows(self.caches, [0] * nb)
-                self._decode(self.params, tok, sub, pos)
+                self._warm_call("decode", self._decode, self.params, tok,
+                                sub, pos)
             self._poll_jit("decode", (nb, 1))
+
+    def _warm_call(self, step: str, fn, *args):
+        """One warmup call of a step jit, then the temporary bytes of the
+        program it compiled: lowering the same arguments again hits the
+        jit's caches, so nothing compiles twice.  ``step_temp_bytes`` keeps
+        the largest over the step's buckets."""
+        out = fn(*args)
+        lower = getattr(fn, "lower", None)      # None: a wrapped step
+        mem = lower(*args).compile().memory_analysis() if lower else None
+        if mem is not None:
+            most = max(self.step_temp_bytes.get(step, 0),
+                       int(mem.temp_size_in_bytes))
+            self.step_temp_bytes[step] = most
+            self.metrics.gauge(
+                "step_temp_bytes",
+                "largest temporary allocation of a step's compiled buckets",
+                step=step).set(most)
+        return out
 
     def _warm_ragged(self) -> None:
         """Compile the single ragged signature at the current budget.  All
         positions/slots are -1 (pure padding): writes drop, pool untouched."""
         T = self._budget
-        _, self.caches = self._ragged(
-            self.params, jnp.zeros((1, T), jnp.int32), self.caches,
+        _, self.caches = self._warm_call(
+            "ragged", self._ragged, self.params,
+            jnp.zeros((1, T), jnp.int32), self.caches,
             jnp.full((1, T), -1, jnp.int32), self._tbl,
             jnp.full((T,), -1, jnp.int32),
             jnp.full((self.sv.max_batch,), -1, jnp.int32))
@@ -1156,6 +1192,8 @@ class InferenceEngine:
             "ttft_p95_s": pct(ttft, 95),
             "ttft_mean_s": mean(ttft),
             "kv_pages_high_water": getattr(self.kv, "high_water", 0),
+            "kv_pool_bytes": self.kv_pool_bytes,
+            "step_temp_bytes": dict(self.step_temp_bytes),
             "paged_attn": self.rt.paged_attn
             if self.sv.layout == "paged" else None,
             "metrics": self.metrics.snapshot(),

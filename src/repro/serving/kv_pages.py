@@ -119,9 +119,10 @@ def paged_write(cache: Dict, k, v, abs_pos) -> Dict:
     to an out-of-bounds page index and dropped.
 
     One batched 2D scatter per pool leaf — no per-row host loop and no flat
-    reshape round-trip, so when the pool rides through a jit with the cache
-    argument donated (launch.steps.make_serving_steps) XLA updates the
-    donated buffer in place instead of copying it."""
+    reshape round-trip.  The layer loop (``models.transformer.forward``)
+    hands this one layer's slice of the stacked pool and writes the result
+    back into the donated stack in place, so a step holds a layer's slice
+    beside the pool, never a second pool."""
     P, ps = cache["k"].shape[:2]
     tbl = cache["tbl"]
     logical = jnp.clip(abs_pos // ps, 0, tbl.shape[1] - 1)       # [B, n]
