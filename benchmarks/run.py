@@ -302,14 +302,15 @@ def bench_paged_attention(do_tune: bool = False):
     where the dense gather's traffic penalty is fully visible on CPU."""
     from repro.kernels import autotune, ops
     from repro.models.attention import attention_core
-    from repro.serving.kv_pages import paged_read
+    from repro.serving.kv_pages import encode_kv, paged_read, pool_storage
 
     rng = np.random.default_rng(3)
     B, KV, G, hd = (PA_SHAPE[k] for k in ("B", "KV", "G", "hd"))
     H = KV * G
 
     def gather_attn(q, pk, pv, tbl, last):
-        kf, vf, kpos = paged_read({"tbl": tbl, "k": pk, "v": pv}, last)
+        kf, vf, kpos = paged_read({"tbl": tbl, "k": pk, "v": pv, "layer": 0},
+                                  last, KV)
         return attention_core(
             q[:, None], kf, vf, q_positions=last[:, None], k_positions=kpos,
             window=0, impl="full", chunk_q=512)
@@ -318,15 +319,19 @@ def bench_paged_attention(do_tune: bool = False):
         pps = PA_MAX_CTX // ps
         P = B * pps + 8
         q = jnp.asarray(rng.standard_normal((B, H, hd)), jnp.float32)
-        pk = jnp.asarray(rng.standard_normal((P, ps, KV, hd)), jnp.float32)
-        pv = jnp.asarray(rng.standard_normal((P, ps, KV, hd)), jnp.float32)
+        # a one-layer stack as the served cache holds it
+        pk, _ = encode_kv(jnp.asarray(rng.standard_normal((1, P, ps, KV, hd)),
+                                      jnp.float32), pool_storage("float32"))
+        pv, _ = encode_kv(jnp.asarray(rng.standard_normal((1, P, ps, KV, hd)),
+                                      jnp.float32), pool_storage("float32"))
         tbl = jnp.asarray(rng.permutation(P)[:B * pps].reshape(B, pps),
                           jnp.int32)
         for ctx in PA_CTXS:
             last = jnp.full((B,), ctx - 1, jnp.int32)
             g_us = _time(jax.jit(gather_attn), q, pk, pv, tbl, last)
-            f_us = _time(jax.jit(lambda *a: ops.paged_decode_attention(*a)),
-                         q, pk, pv, tbl, last)
+            f_us = _time(
+                jax.jit(lambda *a: ops.paged_decode_attention(*a, 0)),
+                q, pk, pv, tbl, last)
             tok = lambda us: f"tok_per_s={B / us * 1e6:.0f}"
             emit(f"kernels.paged_attn.gather.ps{ps}.ctx{ctx}", g_us,
                  f"{tok(g_us)};max_ctx={PA_MAX_CTX}")
@@ -351,10 +356,10 @@ def bench_paged_attention(do_tune: bool = False):
                 pp = max(1, b["bk"] // ps)
                 if on_tpu:
                     return lambda: pa.paged_decode_attention(
-                        q, pk, pv, tbl, last_t, pp=pp, bkv=b["bn"],
+                        q, pk, pv, tbl, last_t, 0, pp=pp, bkv=b["bn"],
                         interpret=False)
                 return lambda: pa.paged_decode_attention_xla(
-                    q, pk, pv, tbl, last_t, pp=pp)
+                    q, pk, pv, tbl, last_t, 0, pp=pp)
 
             blocks, us = autotune.tune(
                 "attn.paged_decode", make_call, B, PA_MAX_CTX, H * hd,

@@ -62,7 +62,7 @@ from repro.observability import global_registry  # noqa: E402
 from repro.serving.api import TraceItem, run_trace  # noqa: E402
 from repro.serving.engine import InferenceEngine, build_params  # noqa: E402
 from repro.serving.kv_pages import (  # noqa: E402
-    init_paged_caches, paged_read, with_block_tables)
+    encode_kv, init_paged_caches, paged_read, pool_storage, with_block_tables)
 from repro.serving.scheduler import OK  # noqa: E402
 
 #: Kernel path vs pure-XLA path, end to end: relative RMS error of the
@@ -299,22 +299,26 @@ def phase_check_kernels(sc: SmokeConfig) -> Dict[str, float]:
         ops.w4a16_matmul(x, w, w_scale, D),
         ref.w4a16_matmul_ref(x, w, w_scale, D))
 
-    pool = (B * pps, ps, KV, hd)
-    k_pool = jax.random.normal(k[3], pool, jnp.bfloat16)
-    v_pool = jax.random.normal(k[4], pool, jnp.bfloat16)
+    # a two-layer stack as the served cache holds it, read at layer 1
+    pool = (2, B * pps, ps, KV, hd)
+    k_pool, _ = encode_kv(jax.random.normal(k[3], pool, jnp.bfloat16),
+                          pool_storage("bfloat16"))
+    v_pool, _ = encode_kv(jax.random.normal(k[4], pool, jnp.bfloat16),
+                          pool_storage("bfloat16"))
     tbl = jax.random.permutation(k[5], B * pps).reshape(B, pps)
     last = jnp.linspace(0, sc.max_ctx - 1, B).astype(jnp.int32)
     q = jax.random.normal(k[6], (B, H, hd), jnp.bfloat16)
-    kf, vf, kpos = paged_read({"k": k_pool, "v": v_pool, "tbl": tbl}, last)
+    kf, vf, kpos = paged_read(
+        {"k": k_pool, "v": v_pool, "tbl": tbl, "layer": 1}, last, KV)
     gathered = attention_core(q[:, None], kf, vf, q_positions=last[:, None],
                               k_positions=kpos, window=0, impl="full",
                               chunk_q=sc.max_ctx)[:, 0]
     out["paged_decode_attention"] = _rel_rms(
-        ops.paged_decode_attention(q, k_pool, v_pool, tbl, last), gathered)
+        ops.paged_decode_attention(q, k_pool, v_pool, tbl, last, 1), gathered)
     slots = jnp.arange(B, dtype=jnp.int32)
     out["ragged_paged_attention"] = _rel_rms(
-        ops.ragged_paged_attention(q, k_pool, v_pool, tbl, slots, last),
-        ragged_attention_xla(q, k_pool, v_pool, tbl, slots, last))
+        ops.ragged_paged_attention(q, k_pool, v_pool, tbl, slots, last, 1),
+        ragged_attention_xla(q, k_pool, v_pool, tbl, slots, last, 1))
 
     S = sc.check_len
     qkv = jax.random.normal(k[7], (3, 1, S, H, hd), jnp.bfloat16)

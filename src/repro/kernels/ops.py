@@ -239,63 +239,72 @@ def w4a16_matmul_kmajor(x, w_kmajor, w_scale, group_size: int,
                          interpret=m == _INTERPRET, **b)
 
 
-def paged_decode_attention(q, k_pool, v_pool, tbl, last_pos,
+def _pool_dtype_name(pool) -> str:
+    """A pool's autotune dtype: its values' (a float pool holds bits)."""
+    return paged_attention.FLOAT_OF_BITS.get(pool.dtype, pool.dtype).name
+
+
+def paged_decode_attention(q, k_pool, v_pool, tbl, last_pos, layer,
                            k_scale=None, v_scale=None, *, window: int = 0,
                            interpret: Optional[bool] = None, tag: str = ""):
     """Fused decode attention over the KV page pool (no gather, no dense
     [B, max_ctx] KV materialization).
 
-    q [B, H, hd]; pools [P, ps, KV, hd(/2)] (+ per-token scales when the
-    cache is int8/int4); tbl [B, pages_per_seq]; last_pos [B] (-1 = inactive
-    row, masked to a zero output).  Tiles resolve through ``kernels.autotune``
-    op ``attn.paged_decode`` — page size rides in the key's group_size slot,
-    ``bk`` is kv tokens per program, ``bn`` the KV-head tile.
+    q [B, H, hd]; layer-stacked lane-dense pools [L, P, ps, KV·hd] (uint8:
+    KV·hd/2; + per-token scales [L, P, ps, KV] when the cache is
+    int8/int4), read at `layer` (int32 scalar); tbl [B, pages_per_seq];
+    last_pos [B] (-1 = inactive row, masked to a zero output).  Tiles
+    resolve through ``kernels.autotune`` op ``attn.paged_decode`` — page
+    size rides in the key's group_size slot, ``bk`` is kv tokens per
+    program, ``bn`` the KV-head tile.
     """
     m = _mode(interpret)
     _count_dispatch("paged_decode_attention", m)
     B, H, hd = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     max_ctx = tbl.shape[1] * ps
     b = autotune.get_blocks("attn.paged_decode", B, max_ctx, H * hd,
-                            jnp.dtype(k_pool.dtype).name, group_size=ps,
+                            _pool_dtype_name(k_pool), group_size=ps,
                             tag=tag)
     pp = max(1, b["bk"] // ps)
     if m == _XLA:
         return paged_attention.paged_decode_attention_xla(
-            q, k_pool, v_pool, tbl, last_pos, k_scale, v_scale,
+            q, k_pool, v_pool, tbl, last_pos, layer, k_scale, v_scale,
             window=window, pp=pp)
     return paged_attention.paged_decode_attention(
-        q, k_pool, v_pool, tbl, last_pos, k_scale, v_scale,
+        q, k_pool, v_pool, tbl, last_pos, layer, k_scale, v_scale,
         window=window, pp=pp, bkv=b["bn"], interpret=m == _INTERPRET)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, tbl, token_slot, token_pos,
-                           k_scale=None, v_scale=None, *, window: int = 0,
+                           layer, k_scale=None, v_scale=None, *,
+                           window: int = 0,
                            interpret: Optional[bool] = None, tag: str = ""):
     """Ragged token-major attention over the KV page pool: one launch for a
     flat ``[T, H, hd]`` pack of mixed prefill-chunk and decode rows.
 
-    q [T, H, hd]; pools [P, ps, KV, hd(/2)] (+ per-token scales when the
-    cache is int8/int4); tbl [max_batch, pages_per_seq]; token_slot /
+    q [T, H, hd]; layer-stacked pools as for ``paged_decode_attention``,
+    read at `layer`; tbl [max_batch, pages_per_seq]; token_slot /
     token_pos [T] (-1 = padding row, masked to a zero output).  Tiles
     resolve through ``kernels.autotune`` op ``attn.ragged`` with the same
     entry semantics as ``attn.paged_decode``."""
     m = _mode(interpret)
     _count_dispatch("ragged_paged_attention", m)
     T, H, hd = q.shape
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     max_ctx = tbl.shape[1] * ps
     b = autotune.get_blocks("attn.ragged", T, max_ctx, H * hd,
-                            jnp.dtype(k_pool.dtype).name, group_size=ps,
+                            _pool_dtype_name(k_pool), group_size=ps,
                             tag=tag)
     pp = max(1, b["bk"] // ps)
     if m == _XLA:
         return ragged_attention.ragged_attention_xla(
-            q, k_pool, v_pool, tbl, token_slot, token_pos, k_scale, v_scale,
-            window=window, pp=pp)
+            q, k_pool, v_pool, tbl, token_slot, token_pos, layer, k_scale,
+            v_scale, window=window, pp=pp)
     return ragged_attention.ragged_decode_attention(
-        q, k_pool, v_pool, tbl, token_slot, token_pos, k_scale, v_scale,
-        window=window, pp=pp, bkv=b["bn"], interpret=m == _INTERPRET)
+        q, k_pool, v_pool, tbl, token_slot, token_pos, layer, k_scale,
+        v_scale, window=window, pp=pp, bkv=b["bn"],
+        interpret=m == _INTERPRET)
 
 
 def flash_prefill(q, k, v, q_positions, k_positions, *, window: int = 0,

@@ -24,6 +24,13 @@ data movement is gone, so attention must consume the pages where they live.
     int8/int4 pools dequantize per fetched page with the same
     ``q * scale -> bf16`` rounding as ``serving.kv_pages`` gather path.
 
+    The pool is the serving cache's own: stacked over the layers and
+    lane-dense, ``[L, P, ps, KV·hd]`` (``serving.kv_pages``).  The kernel
+    takes the whole stack and a layer index and fetches each page at
+    (layer, page) where it lives, so no layer's slice of the pool is copied
+    out for it; a page arrives as one [ps, KV·hd] tile, which a lane-dense
+    layout keeps row-major in HBM whatever the head size.
+
 ``flash_prefill``
     Tiled causal attention over the in-flight prompt: grid over
     (batch, head tile, q tile, kv tile) with online-softmax scratch carried
@@ -61,6 +68,22 @@ from .dispatch import default_interpret
 from .packing import unpack_nibbles
 
 NEG_INF = -1e30
+
+#: A float pool holds its values' bits, each as the unsigned integer of its
+#: width: a backend without a bfloat16 scatter (the CPU) would otherwise
+#: widen the whole layer-stacked pool to float32 and back around every
+#: write into it.  Readers reinterpret the pages they fetch (`pool_values`).
+FLOAT_OF_BITS = {jnp.dtype(jnp.uint16): jnp.dtype(jnp.bfloat16),
+                 jnp.dtype(jnp.uint32): jnp.dtype(jnp.float32)}
+BITS_OF_FLOAT = {f: b for b, f in FLOAT_OF_BITS.items()}
+
+
+def pool_values(x, quant: bool):
+    """Pages of a float pool (stored as bits) as their values; a quantized
+    pool's int8 values or packed int4 pairs as they are."""
+    if quant:
+        return x
+    return jax.lax.bitcast_convert_type(x, FLOAT_OF_BITS[x.dtype])
 
 
 def _largest_divisor(n: int, bound: int) -> int:
@@ -116,26 +139,31 @@ def live_page(j, u, last, *, pp: int, ps: int, window: int):
 def _attend_page(q, kb, vb, ks, vs, start, last, state, *, hd: int,
                  window: int, scale: float):
     """One page of a query row's online softmax: q the G query heads of
-    each KV head ([G, hd] each), kb/vb the page [ps, bkv, hd(/2)] (ks/vs
-    its scales, or None), `start` its first position; `state` the
-    per-head (m, l, acc), returned updated.  Jitted, so that a kernel
-    traces it once for all the pages it unrolls: traced inline, the
+    each KV head ([G, hd] each), kb/vb the lane-dense page [ps, bkv·hd]
+    (uint8: [ps, bkv·hd/2]) whose KV head h owns lanes [h·w, (h+1)·w)
+    (ks/vs its scales [ps, bkv], or None), `start` its first position;
+    `state` the per-head (m, l, acc), returned updated.  Jitted, so that a
+    kernel traces it once for all the pages it unrolls: traced inline, the
     unrolled pages made tracing the decode step's most expensive part of
     the engine's warmup."""
     cd = q[0].dtype
-    if ks is not None:
-        kb = _dequant_slab(kb, ks, hd)
-        vb = _dequant_slab(vb, vs, hd)
-    kb = kb.transpose(1, 0, 2).astype(cd)          # [bkv, ps, hd]
-    vb = vb.transpose(1, 0, 2).astype(jnp.float32)
-    pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, kb.shape[1]), 1)
+    w = kb.shape[-1] // len(q)
+
+    def head(page, scales, h, dtype):              # KV head h: [ps, hd]
+        slab = pool_values(page[:, h * w:(h + 1) * w], scales is not None)
+        if scales is not None:
+            slab = _dequant_slab(slab, scales[:, h:h + 1], hd)
+        return slab.astype(dtype)
+
+    pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, kb.shape[0]), 1)
     mask = pos <= last
     if window:
         mask &= (last - pos) < window
     new = []
     for h, (m_prev, l_prev, acc_prev) in enumerate(zip(*state)):
         # scores [G, ps]: contract hd
-        s = jax.lax.dot_general(q[h], kb[h], (((1,), (1,)), ((), ())),
+        s = jax.lax.dot_general(q[h], head(kb, ks, h, cd),
+                                (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = jnp.where(mask, _round_scores(s, cd) * scale, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -146,7 +174,8 @@ def _attend_page(q, kb, vb, ks, vs, start, last, state, *, hd: int,
         new.append((m_new,
                     l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
                     acc_prev * alpha + jax.lax.dot_general(
-                        p, vb[h], (((1,), (0,)), ((), ())),
+                        p, head(vb, vs, h, jnp.float32),
+                        (((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)))    # [G, hd]
     return tuple(zip(*new))
 
@@ -165,7 +194,8 @@ def _walk_pages(last, j, q_ref, refs, *, pp: int, ps: int, nj: int,
     h are the leading-index slice ``q_ref[0, h]`` ([G, hd]), never a reshape
     of the [H, hd] row — Mosaic cannot re-tile that reshape when G or hd is
     not a multiple of the (sublane, lane) tiling (G = 7, hd = 64 for
-    qwen2-0.5b)."""
+    qwen2-0.5b) — and a page arrives as one lane-dense [ps, bkv·hd] tile
+    whose heads are static lane slices."""
     k_refs = refs[:pp]
     v_refs = refs[pp:2 * pp]
     i = 2 * pp
@@ -193,9 +223,9 @@ def _walk_pages(last, j, q_ref, refs, *, pp: int, ps: int, nj: int,
                       for r in (m_ref, l_ref, acc_ref))
         for u in range(pp):                        # static unroll: pages
             state = _attend_page(
-                q, k_refs[u][0], v_refs[u][0],
-                ks_refs[u][0] if quant else None,
-                vs_refs[u][0] if quant else None,
+                q, k_refs[u][0, 0], v_refs[u][0, 0],
+                ks_refs[u][0, 0] if quant else None,
+                vs_refs[u][0, 0] if quant else None,
                 start + u * ps, last, state, hd=hd, window=window,
                 scale=scale)
         for r, values in zip((m_ref, l_ref, acc_ref), state):
@@ -209,40 +239,75 @@ def _walk_pages(last, j, q_ref, refs, *, pp: int, ps: int, nj: int,
         o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _decode_kernel(tbl_ref, lp_ref, q_ref, *refs, **kw):
-    del tbl_ref                    # consumed by the BlockSpec index_maps
+def _decode_kernel(tbl_ref, lp_ref, layer_ref, q_ref, *refs, **kw):
+    del tbl_ref, layer_ref         # consumed by the BlockSpec index_maps
     _walk_pages(lp_ref[pl.program_id(0)], pl.program_id(2), q_ref, refs, **kw)
 
 
-def page_walk_call(kernel, scalars, q, k_pool, v_pool, k_scale, v_scale, *,
-                   page_index, pp: int, bkv: int, nj: int, window: int,
-                   interpret: bool, name: str) -> jnp.ndarray:
+def pool_heads(pool, hd: int) -> int:
+    """KV heads of a lane-dense pool [L, P, ps, KV·hd] (uint8 packs two
+    int4 values a byte: [L, P, ps, KV·hd/2])."""
+    return pool.shape[-1] // (hd // 2 if pool.dtype == jnp.uint8 else hd)
+
+
+def head_tile(KV: int, width: int, bkv: int, quant: bool) -> int:
+    """The KV heads a program attends: a divisor of KV, all of them when
+    asked for none (0), and all of them where a tile of `bkv` heads of
+    `width` lanes each would not be a whole number of 128-lane tiles, or
+    where the pool is quantized (a page's scales arrive as one [ps, KV]
+    tile)."""
+    bkv = _largest_divisor(KV, bkv if bkv > 0 else KV)
+    if quant or (bkv * width) % 128:
+        return KV
+    return bkv
+
+
+def page_walk_call(kernel, scalars, q, k_pool, v_pool, layer, k_scale,
+                   v_scale, *, page_index, pp: int, bkv: int, nj: int,
+                   window: int, interpret: bool, name: str) -> jnp.ndarray:
     """The pallas_call both page-walking kernels share: grid (query row,
-    KV-head tile, page block) over q [R, H, hd], with `scalars` riding in
-    as scalar-prefetch operands that `page_index(u)` — an index_map for the
-    u-th page of a program — reads to find a physical pool page.
+    KV-head tile, page block) over q [R, H, hd], with `scalars` and then
+    the layer index riding in as scalar-prefetch operands.  `page_index(u)`
+    reads the scalars to find the u-th page of a program in the pool:
+    ``(r, j, *scalar_refs) -> physical page``.
+
+    The pools are the layer-stacked, lane-dense [L, P, ps, KV·hd]: each
+    page spec fetches the (1, 1, ps, bkv·hd) block at (layer, page, 0,
+    head tile), so a layer's pages are read where they live in the stack
+    and no slice of the pool is ever copied.  The layer is a traced
+    operand, so one compiled kernel serves every layer.
 
     q enters as [R, KV, G, hd] (a free reshape here, outside the kernel),
     so a program's query heads arrive grouped by KV head.  `name` names
     the kernel in the compiled program and so in the device trace."""
     R, H, hd = q.shape
-    ps, KV = k_pool.shape[1:3]
+    ps = k_pool.shape[2]
+    KV = pool_heads(k_pool, hd)
     assert H % KV == 0, (H, KV)           # query heads tile evenly over KV heads
-    assert KV % bkv == 0, (KV, bkv)       # whole KV heads per program
     G = H // KV
     quant = k_scale is not None
+    width = k_pool.shape[-1] // KV                 # hd, or hd//2 packed
+    bkv = head_tile(KV, width, bkv, quant)
 
     def row_index(r, h, j, *_):
         return (r, h, 0, 0)
 
-    kv_block = k_pool.shape[-1]                    # hd, or hd//2 packed
-    pool_specs = [pl.BlockSpec((1, ps, bkv, kv_block), page_index(u))
+    def pool_index(u):
+        page = page_index(u)
+
+        def index(r, h, j, *refs):
+            return (refs[-1][0], page(r, j, *refs[:-1]), 0, h)
+        return index
+
+    pool_specs = [pl.BlockSpec((1, 1, ps, bkv * width), pool_index(u))
                   for u in range(pp)]
     in_specs = [pl.BlockSpec((1, bkv, G, hd), row_index),
                 *pool_specs, *pool_specs]
     args = [q.reshape(R, KV, G, hd), *([k_pool] * pp), *([v_pool] * pp)]
     if quant:
-        scale_specs = [pl.BlockSpec((1, ps, bkv, 1), page_index(u))
+        # bkv == KV: the head-tile index is always 0, so the pool's index
+        # maps address the [L, P, ps, KV] scales too
+        scale_specs = [pl.BlockSpec((1, 1, ps, KV), pool_index(u))
                        for u in range(pp)]
         in_specs += [*scale_specs, *scale_specs]
         args += [*([k_scale] * pp), *([v_scale] * pp)]
@@ -250,10 +315,11 @@ def page_walk_call(kernel, scalars, q, k_pool, v_pool, k_scale, v_scale, *,
     body = functools.partial(
         kernel, pp=pp, ps=ps, nj=nj, bkv=bkv, hd=hd, window=window,
         quant=quant, scale=1.0 / math.sqrt(hd))
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     out = pl.pallas_call(
         body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(scalars),
+            num_scalar_prefetch=len(scalars) + 1,
             grid=(R, KV // bkv, nj),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, bkv, G, hd), row_index),
@@ -266,7 +332,7 @@ def page_walk_call(kernel, scalars, q, k_pool, v_pool, k_scale, v_scale, *,
         out_shape=jax.ShapeDtypeStruct((R, KV, G, hd), q.dtype),
         interpret=interpret,
         name=name,
-    )(*scalars, *args)
+    )(*scalars, layer, *args)
     return out.reshape(R, H, hd)
 
 
@@ -274,20 +340,20 @@ def page_walk_call(kernel, scalars, q, k_pool, v_pool, k_scale, v_scale, *,
     jax.jit, static_argnames=("window", "pp", "bkv", "interpret"))
 def paged_decode_attention(
     q: jnp.ndarray,            # [B, H, hd]
-    k_pool: jnp.ndarray,       # [P, ps, KV, hd]  (uint8: [..., hd//2])
+    k_pool: jnp.ndarray,       # [L, P, ps, KV·hd]  (uint8: KV·hd/2)
     v_pool: jnp.ndarray,
     tbl: jnp.ndarray,          # [B, pages_per_seq] int32
     last_pos: jnp.ndarray,     # [B] int32, newest valid position (-1 = idle)
-    k_scale: jnp.ndarray = None,   # [P, ps, KV, 1] f32 when quantized
+    layer: jnp.ndarray,        # int32 scalar: the layer of the stack read
+    k_scale: jnp.ndarray = None,   # [L, P, ps, KV] f32 when quantized
     v_scale: jnp.ndarray = None,
     window: int = 0,
     pp: int = 4,               # pages per program (autotuned: attn.paged_decode)
     bkv: int = 0,              # KV-head tile, 0 = all heads
     interpret: bool = None,
 ) -> jnp.ndarray:
-    P, ps, KV = k_pool.shape[:3]
+    P, ps = k_pool.shape[1:3]
     pps = tbl.shape[1]
-    bkv = _largest_divisor(KV, bkv if bkv > 0 else KV)
     pp = max(1, min(pp, pps))
 
     def page_index(u):
@@ -298,21 +364,21 @@ def paged_decode_attention(
         # so dead pages are never copied.  An idle row's table slot may
         # carry the out-of-bounds sentinel (== P); clamp so the DMA stays
         # in bounds — the kernel skips that page anyway.
-        def index(b, h, j, tbl_ref, lp_ref):
+        def index(b, j, tbl_ref, lp_ref):
             logical = live_page(j, u, lp_ref[b], pp=pp, ps=ps, window=window)
-            return (jnp.minimum(tbl_ref[b, logical], P - 1), 0, h, 0)
+            return jnp.minimum(tbl_ref[b, logical], P - 1)
         return index
 
     return page_walk_call(
         _decode_kernel, (tbl.astype(jnp.int32), last_pos.astype(jnp.int32)),
-        q, k_pool, v_pool, k_scale, v_scale, page_index=page_index, pp=pp,
-        bkv=bkv, nj=-(-pps // pp), window=window,
+        q, k_pool, v_pool, layer, k_scale, v_scale, page_index=page_index,
+        pp=pp, bkv=bkv, nj=-(-pps // pp), window=window,
         interpret=default_interpret(interpret), name="paged_decode_attention")
 
 
 @functools.partial(jax.jit, static_argnames=("window", "pp"))
 def paged_decode_attention_xla(
-    q, k_pool, v_pool, tbl, last_pos, k_scale=None, v_scale=None,
+    q, k_pool, v_pool, tbl, last_pos, layer, k_scale=None, v_scale=None,
     window: int = 0, pp: int = 4,
 ) -> jnp.ndarray:
     """Pure-XLA twin, *bit-identical to the gather reference* by
@@ -326,11 +392,13 @@ def paged_decode_attention_xla(
 
     Both loops stop at the last *active* page in the batch, so per-step
     work scales with the actual context, not the pool bound, and the dense
-    [B, max_ctx, KV, hd] K/V buffers never exist.  This is what keeps the
-    `--layout compare` harness token-identical across contiguous,
+    [B, max_ctx, KV, hd] K/V buffers never exist.  Each block gathers its
+    pages of `layer` straight out of the layer-stacked pool.  This is what
+    keeps the `--layout compare` harness token-identical across contiguous,
     paged-gather, and paged-fused on CPU hosts."""
     B, H, hd = q.shape
-    P, ps, KV = k_pool.shape[:3]
+    P, ps = k_pool.shape[1:3]
+    KV = pool_heads(k_pool, hd)
     pps = tbl.shape[1]
     G = H // KV
     scale = 1.0 / math.sqrt(hd)
@@ -351,13 +419,18 @@ def paged_decode_attention_xla(
     q4 = q.reshape(B, KV, G, hd)
     steps = jnp.clip((jnp.max(last_pos) + tokens) // tokens, 1, nj)
 
+    def block(pool, scales, j):
+        """Block j's pages of every row: [B, tokens, KV, hd]."""
+        cols = jax.lax.dynamic_slice_in_dim(tbl_p, j * pp, pp, 1)  # [B, pp]
+        kb = pool_values(pool[layer, cols], quant).reshape(B, tokens, KV, -1)
+        if quant:
+            kb = _dequant_slab(
+                kb, scales[layer, cols].reshape(B, tokens, KV, 1), hd)
+        return kb
+
     def qk_body(carry):
         j, sbuf = carry
-        cols = jax.lax.dynamic_slice_in_dim(tbl_p, j * pp, pp, 1)  # [B, pp]
-        kb = k_pool[cols]                          # [B, pp, ps, KV, hd(/2)]
-        if quant:
-            kb = _dequant_slab(kb, k_scale[cols], hd)
-        kb = kb.reshape(B, tokens, KV, hd)
+        kb = block(k_pool, k_scale, j)
         s = jnp.einsum("bkgh,btkh->bkgt", q4, kb.astype(cd))
         s = _round_scores(s, cd) * scale           # [B, KV, G, tokens]
         return j + 1, jax.lax.dynamic_update_slice(
@@ -374,21 +447,17 @@ def paged_decode_attention_xla(
         mask &= (last_pos[:, None] - pos[None, :]) < window
     sbuf = jnp.where(mask[:, None, None, :], sbuf, NEG_INF)
     probs = jax.nn.softmax(sbuf, axis=-1).astype(
-        jnp.bfloat16 if quant else v_pool.dtype)
+        jnp.bfloat16 if quant else FLOAT_OF_BITS[v_pool.dtype])
 
     def pv_body(carry):
         j, acc = carry
-        cols = jax.lax.dynamic_slice_in_dim(tbl_p, j * pp, pp, 1)
-        vb = v_pool[cols]
-        if quant:
-            vb = _dequant_slab(vb, v_scale[cols], hd)
-        vb = vb.reshape(B, tokens, KV, hd)
         # dead table slots hold the out-of-bounds sentinel (== P); the
         # gather clamps them to the last physical page, whose masked
         # positions contribute exactly 0 via zero probs.  (Finite-garbage
         # safe, like the pre-sentinel code; the NaN-proof zero-fill lives
         # in paged_read — zeroing V per block here costs 10-25% of the
         # decode step for a hazard only a NaN-poisoned pool can hit.)
+        vb = block(v_pool, v_scale, j)
         p = jax.lax.dynamic_slice_in_dim(probs, j * tokens, tokens, 3)
         pv = jnp.einsum("bkgt,btkh->bkgh", p, vb,
                         preferred_element_type=jnp.float32)

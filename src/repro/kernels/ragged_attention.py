@@ -49,7 +49,6 @@ from jax.experimental import pallas as pl
 
 from .dispatch import default_interpret
 from .paged_attention import (
-    _largest_divisor,
     _walk_pages,
     live_page,
     page_walk_call,
@@ -57,13 +56,14 @@ from .paged_attention import (
 )
 
 
-def _ragged_kernel(slot_ref, pos_ref, tbl_ref, q_ref, *refs, **kw):
-    # slot_ref/tbl_ref are consumed by the BlockSpec index_maps; the body
-    # only needs the token's own position for masking.  pos <= token_pos is
-    # causal for prefill rows (the chunk's K/V is already in the pool) and
-    # last-token for decode rows; padding rows (token_pos == -1) skip
-    # every page and emit zeros.
-    del slot_ref, tbl_ref
+def _ragged_kernel(slot_ref, pos_ref, tbl_ref, layer_ref, q_ref, *refs,
+                   **kw):
+    # slot_ref/tbl_ref/layer_ref are consumed by the BlockSpec index_maps;
+    # the body only needs the token's own position for masking.  pos <=
+    # token_pos is causal for prefill rows (the chunk's K/V is already in
+    # the pool) and last-token for decode rows; padding rows (token_pos ==
+    # -1) skip every page and emit zeros.
+    del slot_ref, tbl_ref, layer_ref
     _walk_pages(pos_ref[pl.program_id(0)], pl.program_id(2), q_ref, refs,
                 **kw)
 
@@ -72,21 +72,21 @@ def _ragged_kernel(slot_ref, pos_ref, tbl_ref, q_ref, *refs, **kw):
     jax.jit, static_argnames=("window", "pp", "bkv", "interpret"))
 def ragged_decode_attention(
     q: jnp.ndarray,            # [T, H, hd] packed token rows
-    k_pool: jnp.ndarray,       # [P, ps, KV, hd]  (uint8: [..., hd//2])
+    k_pool: jnp.ndarray,       # [L, P, ps, KV·hd]  (uint8: KV·hd/2)
     v_pool: jnp.ndarray,
     tbl: jnp.ndarray,          # [max_batch, pages_per_seq] int32
     token_slot: jnp.ndarray,   # [T] int32 table row per token (-1 = pad)
     token_pos: jnp.ndarray,    # [T] int32 absolute position (-1 = pad)
-    k_scale: jnp.ndarray = None,   # [P, ps, KV, 1] f32 when quantized
+    layer: jnp.ndarray,        # int32 scalar: the layer of the stack read
+    k_scale: jnp.ndarray = None,   # [L, P, ps, KV] f32 when quantized
     v_scale: jnp.ndarray = None,
     window: int = 0,
     pp: int = 4,               # pages per program (autotuned: attn.ragged)
     bkv: int = 0,              # KV-head tile, 0 = all heads
     interpret: bool = None,
 ) -> jnp.ndarray:
-    P, ps, KV = k_pool.shape[:3]
+    P, ps = k_pool.shape[1:3]
     pps = tbl.shape[1]
-    bkv = _largest_divisor(KV, bkv if bkv > 0 else KV)
     pp = max(1, min(pp, pps))
 
     def page_index(u):
@@ -96,17 +96,17 @@ def ragged_decode_attention(
         # copied).  Padding rows (slot -1) clamp to row 0, whose slot may
         # carry the out-of-bounds sentinel (== P); both clamp into bounds
         # and the kernel body skips them.
-        def index(t, h, j, slot_ref, pos_ref, tbl_ref):
+        def index(t, j, slot_ref, pos_ref, tbl_ref):
             row = jnp.maximum(slot_ref[t], 0)
             logical = live_page(j, u, pos_ref[t], pp=pp, ps=ps,
                                 window=window)
-            return (jnp.minimum(tbl_ref[row, logical], P - 1), 0, h, 0)
+            return jnp.minimum(tbl_ref[row, logical], P - 1)
         return index
 
     scalars = (token_slot.astype(jnp.int32), token_pos.astype(jnp.int32),
                tbl.astype(jnp.int32))
     return page_walk_call(
-        _ragged_kernel, scalars, q, k_pool, v_pool, k_scale, v_scale,
+        _ragged_kernel, scalars, q, k_pool, v_pool, layer, k_scale, v_scale,
         page_index=page_index, pp=pp, bkv=bkv, nj=-(-pps // pp),
         window=window, interpret=default_interpret(interpret),
         name="ragged_decode_attention")
@@ -114,7 +114,7 @@ def ragged_decode_attention(
 
 @functools.partial(jax.jit, static_argnames=("window", "pp"))
 def ragged_attention_xla(
-    q, k_pool, v_pool, tbl, token_slot, token_pos,
+    q, k_pool, v_pool, tbl, token_slot, token_pos, layer,
     k_scale=None, v_scale=None, window: int = 0, pp: int = 4,
 ) -> jnp.ndarray:
     """Pure-XLA twin: gather each token's block-table row (padding rows
@@ -123,7 +123,7 @@ def ragged_attention_xla(
     Per-token rows are independent in that twin, so decode tokens here are
     bit-identical to what the bucketed decode step produced for the same
     (pool, table, position) — regardless of how many rows share a step."""
-    P = k_pool.shape[0]
+    P = k_pool.shape[1]
     maxB = tbl.shape[0]
     slot = token_slot.astype(jnp.int32)
     tbl_pt = jnp.where(
@@ -131,5 +131,5 @@ def ragged_attention_xla(
         jnp.take(tbl.astype(jnp.int32), jnp.clip(slot, 0, maxB - 1), axis=0),
         P)                                          # [T, pages_per_seq]
     return paged_decode_attention_xla(
-        q, k_pool, v_pool, tbl_pt, token_pos.astype(jnp.int32),
+        q, k_pool, v_pool, tbl_pt, token_pos.astype(jnp.int32), layer,
         k_scale, v_scale, window=window, pp=pp)

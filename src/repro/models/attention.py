@@ -31,6 +31,10 @@ from .common import apply_mrope, apply_rope, normal_init, rms_norm
 
 NEG_INF = -1e30
 
+#: the leaves of a paged attention cache that hold the layer-stacked pool
+#: (serving.kv_pages); the layer loop passes them through whole
+POOL_LEAVES = ("k", "v", "k_scale", "v_scale")
+
 
 def _attn_strategy(n_units: int, seq: int) -> str:
     """How to use the TP axis inside the attention core:
@@ -317,13 +321,14 @@ def apply_attention(
         new_cache = ragged_paged_write(cache, k, v, tpos)
         out = ops.ragged_paged_attention(
             q[0], new_cache["k"], new_cache["v"], new_cache["tbl"],
-            cache["slots"], tpos[0],
+            cache["slots"], tpos[0], cache["layer"],
             new_cache.get("k_scale"), new_cache.get("v_scale"),
             window=cfg.local_window,
             tag=join_site(site, "attn.ragged"),
         )[None]
     elif cache is not None and "tbl" in cache:
-        # ---- paged KV (serving): pool + block table, see serving/kv_pages --
+        # ---- paged KV (serving): block table + the layer-stacked pool,
+        # read and written at cache["layer"] (serving/kv_pages) ------------
         from repro.serving.kv_pages import paged_read, paged_write
 
         if S == 1:
@@ -336,7 +341,7 @@ def apply_attention(
 
                 out = ops.paged_decode_attention(
                     q[:, 0], new_cache["k"], new_cache["v"],
-                    new_cache["tbl"], tpos[:, -1],
+                    new_cache["tbl"], tpos[:, -1], cache["layer"],
                     new_cache.get("k_scale"), new_cache.get("v_scale"),
                     window=cfg.local_window,
                     tag=join_site(site, "attn.paged_decode"),
@@ -344,7 +349,7 @@ def apply_attention(
             else:
                 # gather baseline (and TP fallback): reconstruct the dense
                 # layout, attend over it — the bit-exactness reference
-                kf, vf, kpos = paged_read(new_cache, tpos[:, -1])
+                kf, vf, kpos = paged_read(new_cache, tpos[:, -1], KV)
                 out = attention_core(
                     q, kf, vf,
                     q_positions=tpos, k_positions=kpos,
@@ -359,7 +364,7 @@ def apply_attention(
             # baseline reconstructs, with kpos masking the empty slots.
             new_cache = paged_write(cache, k, v, tpos) if update_cache \
                 else cache
-            kf, vf, kpos = paged_read(new_cache, tpos[:, -1])
+            kf, vf, kpos = paged_read(new_cache, tpos[:, -1], KV)
             out = attention_core(
                 q, kf, vf,
                 q_positions=tpos, k_positions=kpos,

@@ -25,7 +25,12 @@ from repro.core.quant_plan import (
     plan_repeat_uniform,
 )
 from repro.distributed.sharding import shard
-from .attention import apply_attention, init_attention, init_attn_cache
+from .attention import (
+    POOL_LEAVES,
+    apply_attention,
+    init_attention,
+    init_attn_cache,
+)
 from .common import normal_init, rms_norm, sinusoidal_pos_embed
 from .ffn import apply_ffn, init_ffn
 from .moe import apply_moe, init_moe
@@ -216,10 +221,11 @@ def forward(
     per_repeat = layers_per_repeat(params)
     use_scan = (rt.scan_layers and not per_repeat
                 and plan_repeat_uniform(active_plan(cfg, rt), cfg))
-    # The stacked caches are read and written back one unit at a time in
-    # the buffer they arrived in (`_take_unit`/`_put_unit`), never scanned
-    # as xs/ys: an output stack beside the input stack is a second copy of
-    # the KV pool for the whole step, however the step donates its caches.
+    # The stacked caches ride through the loop whole (`_take_unit`/
+    # `_put_unit`), never scanned as xs/ys: an output stack beside the input
+    # stack is a second copy of the KV pool for the whole step, however the
+    # step donates its caches.  A paged pool is never even sliced: each
+    # layer reads and writes it in place at its layer index.
     if use_scan:
         body = make_body([f"block[{j}]" for j in range(P)])
         if rep_caches is None:
@@ -244,7 +250,7 @@ def forward(
         for r in range(cfg.n_repeats):
             unit_p = (params["layers"][f"r{r}"] if per_repeat
                       else jax.tree.map(lambda a: a[r], params["layers"]))
-            unit_c = (_take_unit(new_rep, r)
+            unit_c = (_take_unit(new_rep, jnp.int32(r))
                       if rep_caches is not None else None)
             body = make_body([f"block[{r * P + j}]" for j in range(P)])
             carry, nc = body(carry, (unit_p, unit_c))
@@ -256,13 +262,17 @@ def forward(
     if caches is not None:
         new_caches = {"rep": new_rep, "tail": {}}
     for t, bt in enumerate(cfg.tail):
-        tc = caches["tail"][f"tail{t}"] if caches is not None else None
+        tc = (_bind_layer(caches["tail"][f"tail{t}"], jnp.int32(0),
+                          lambda a: a)
+              if caches is not None else None)
         x, nc, aux = apply_block(bt, params[f"tail{t}"], x, cfg, rt,
                                  positions, tc, update_cache,
                                  site=f"block[{cfg.n_repeats * P + t}]")
         aux_sum = aux_sum + aux
         if new_caches is not None:
-            new_caches["tail"][f"tail{t}"] = nc if nc is not None else tc
+            new_caches["tail"][f"tail{t}"] = _unbind_layer(
+                caches["tail"][f"tail{t}"], nc if nc is not None else tc,
+                lambda a, u: u)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     aux_mean = aux_sum / max(cfg.n_layers, 1)
@@ -273,16 +283,40 @@ def forward(
     return logits, new_caches, aux_mean
 
 
+def _bind_layer(node, layer, take):
+    """A layer's view of a cache tree: `take` applied to every leaf, except
+    that a paged attention cache (a dict with a block table) keeps its
+    layer-stacked pool leaves whole and gains its ``"layer"`` index."""
+    if "tbl" in node:
+        out = {k: v if k in POOL_LEAVES else take(v) for k, v in node.items()}
+        out["layer"] = layer
+        return out
+    return {k: _bind_layer(v, layer, take) if isinstance(v, dict) else take(v)
+            for k, v in node.items()}
+
+
+def _unbind_layer(node, new, put):
+    """Inverse of `_bind_layer`: `put(old, new)` for every leaf, except that
+    a paged attention cache takes its written pool leaves from `new` as
+    they are and keeps its own block table (a layer never writes it)."""
+    if "tbl" in node:
+        return {k: new[k] if k in POOL_LEAVES else v for k, v in node.items()}
+    return {k: (_unbind_layer(v, new[k], put) if isinstance(v, dict)
+                else put(v, new[k]))
+            for k, v in node.items()}
+
+
 def _take_unit(rep, r):
-    """Repeat unit `r`'s caches out of the stacked tree."""
-    return jax.tree.map(lambda a: a[r], rep)
+    """Repeat unit `r`'s caches out of the stacked tree: per-layer leaves
+    sliced at `r`, paged pools whole and addressed at layer `r`."""
+    return _bind_layer(rep, r, lambda a: a[r])
 
 
 def _put_unit(rep, unit, r):
     """Write unit `r`'s caches back into the stacked tree, in place."""
-    return jax.tree.map(
-        lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, r, 0),
-        rep, unit)
+    return _unbind_layer(
+        rep, unit,
+        lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, r, 0))
 
 
 def _logits(params, x, cfg: ArchConfig, rt: Runtime):

@@ -1054,9 +1054,12 @@ class InferenceEngine:
         step_us = _best_us(step)
 
         from repro.models.attention import _cache_read, attention_core
-        layer = jax.tree.map(lambda l: l[0], self.caches["rep"])
-        attn = next((blk["attn"] for blk in layer.values() if "attn" in blk),
-                    None)
+        # the first layer's attention cache: a paged pool stays stacked and
+        # is read at layer 0, a contiguous cache is sliced
+        attn = next((blk["attn"] for blk in self.caches["rep"].values()
+                     if "attn" in blk), None)
+        if attn is not None and sv.layout != "paged":
+            attn = jax.tree.map(lambda l: l[0], attn)
         if attn is None:
             # SSM/LRU stack: no attention blocks to attribute — the whole
             # step is GEMM + recurrence
@@ -1086,17 +1089,18 @@ class InferenceEngine:
                 def attn_op():
                     return ops.ragged_paged_attention(
                         qT, attn["k"], attn["v"], self._tbl, tslots, tpos,
-                        attn.get("k_scale"), attn.get("v_scale"),
+                        0, attn.get("k_scale"), attn.get("v_scale"),
                         window=cfg.local_window)
             elif self.rt.paged_attn == "fused":
                 def attn_op():
                     return ops.paged_decode_attention(
-                        q, attn["k"], attn["v"], tbl, last,
+                        q, attn["k"], attn["v"], tbl, last, 0,
                         attn.get("k_scale"), attn.get("v_scale"),
                         window=cfg.local_window)
             else:
                 def attn_op():
-                    kf, vf, kpos = paged_read(dict(attn, tbl=tbl), last)
+                    kf, vf, kpos = paged_read(dict(attn, tbl=tbl, layer=0),
+                                              last, cfg.n_kv_heads)
                     return attention_core(
                         q[:, None], kf, vf, q_positions=last[:, None],
                         k_positions=kpos, window=cfg.local_window,

@@ -1,19 +1,27 @@
 """Paged KV cache: fixed-size pages from a preallocated pool + block tables.
 
-Device side, each attention layer's cache is a plain dict (scan/vmap-friendly
-pytree):
+Device side, the attention layers that share block tables share one cache
+dict (a scan-friendly pytree) whose pool leaves are stacked over those
+layers, each page lane-dense (a token's KV heads side by side):
 
-    {"tbl": [B, pages_per_seq] int32,        # logical page -> physical page
-     "k":   [num_pages, page_size, KV, hd],  # shared pool
-     "v":   [num_pages, page_size, KV, hd],
-     (+ "k_scale"/"v_scale" [num_pages, page_size, KV, 1] when quantized)}
+    {"tbl": [B, pages_per_seq] int32,            # logical -> physical page
+     "k":   [L, num_pages, page_size, KV·hd],    # layer-stacked pool
+     "v":   [L, num_pages, page_size, KV·hd],
+     (int4: [..., KV·hd/2] packed nibble pairs;
+      + "k_scale"/"v_scale" [L, num_pages, page_size, KV] when quantized)}
 
-The attention module dispatches on the ``"tbl"`` key, so the same model code
-consumes the contiguous ring cache and the paged pool.  Logical slot ``j`` of
-a sequence lives at flat pool index ``tbl[j // page_size] * page_size +
-j % page_size``; a gather along that index vector reconstructs exactly the
-[B, max_ctx, KV, hd] layout of the contiguous cache, which is what makes
-paged and contiguous decode bit-identical.
+A float pool holds its values' bits (uint16 for bfloat16, see
+``pool_storage``), so that every backend scatters a write into the stack in
+place.  A layer's call adds its index, ``"layer"`` (an int32 scalar), to
+the dict it hands the attention module (``models.transformer.forward``):
+the writes scatter into the stack at that index and the decode kernels
+read the stack there, so no layer's slice of the pool is ever copied out
+or back.  The attention module dispatches on the ``"tbl"`` key, so the
+same model code consumes the contiguous ring cache and the paged pool.
+Logical slot ``j`` of a sequence lives in page ``tbl[j // page_size]`` at
+slot ``j % page_size``; a gather of a layer's pages reconstructs exactly
+the [B, max_ctx, KV, hd] layout of the contiguous cache, which is what
+makes paged and contiguous decode bit-identical.
 
 Host side, ``PagedKVCacheManager`` owns the page pool and per-request page
 lists; ``ContinuousKVCache`` wraps the static-slot layout behind the same
@@ -67,83 +75,113 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig, Runtime, ServingConfig
+from repro.kernels.paged_attention import (
+    BITS_OF_FLOAT,
+    FLOAT_OF_BITS,
+    pool_values,
+)
 from repro.models.attention import dequantize_kv, quantize_kv
 from repro.observability.metrics import NULL_REGISTRY
 
 
 # ------------------------------------------------------- device-side cache --
+def pool_storage(cache_dtype: str):
+    """The dtype of the pool leaves for a ``Runtime.cache_dtype``: int8, or
+    packed int4 pairs in uint8, beside float32 scales; a float cache holds
+    its values' bits (``kernels.paged_attention.FLOAT_OF_BITS``)."""
+    if cache_dtype == "int4":
+        return jnp.dtype(jnp.uint8)
+    if cache_dtype == "int8":
+        return jnp.dtype(jnp.int8)
+    return BITS_OF_FLOAT[jnp.dtype(jnp.bfloat16 if cache_dtype == "bfloat16"
+                                   else jnp.float32)]
+
+
+def encode_kv(val, storage) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
+    """K or V values [..., KV, hd] as a pool of dtype `storage` holds them:
+    lane-dense [..., KV·hd] (packed int4: KV·hd/2) and, when quantized,
+    the per-(token, head) scales [..., KV] (else None)."""
+    def dense(x):
+        return x.reshape(*x.shape[:-2], -1)
+
+    if storage in (jnp.int8, jnp.uint8):
+        q, scale = quantize_kv(val, storage == jnp.uint8)
+        return dense(q), dense(scale)
+    return dense(jax.lax.bitcast_convert_type(
+        val.astype(FLOAT_OF_BITS[storage]), storage)), None
+
+
 def init_paged_attn_cache(cfg: ArchConfig, rt: Runtime, batch: int,
-                          sv: ServingConfig) -> Dict:
-    """One attention layer's paged cache (pool + block table)."""
+                          sv: ServingConfig, layers: int = 1) -> Dict:
+    """The paged cache of `layers` attention layers: one block table
+    [batch, pages_per_seq] and the pool stacked over the layers."""
     kv, hd = cfg.n_kv_heads, cfg.hd
-    P, ps = sv.num_pages, sv.page_size
+    page = (layers, sv.num_pages, sv.page_size)
+    storage = pool_storage(rt.cache_dtype)
+    width = kv * hd // 2 if storage == jnp.uint8 else kv * hd
     cache = {"tbl": jnp.zeros((batch, sv.pages_per_seq), jnp.int32)}
-    if rt.cache_dtype == "int8":
-        z = jnp.zeros((P, ps, kv, hd), jnp.int8)
-        s = jnp.zeros((P, ps, kv, 1), jnp.float32)
-        cache.update({"k": z, "v": z, "k_scale": s, "v_scale": s})
-    elif rt.cache_dtype == "int4":
-        z = jnp.zeros((P, ps, kv, hd // 2), jnp.uint8)
-        s = jnp.zeros((P, ps, kv, 1), jnp.float32)
-        cache.update({"k": z, "v": z, "k_scale": s, "v_scale": s})
-    else:
-        dt = jnp.bfloat16 if rt.cache_dtype == "bfloat16" else jnp.float32
-        z = jnp.zeros((P, ps, kv, hd), dt)
-        cache.update({"k": z, "v": z})
+    for name in ("k", "v"):
+        cache[name] = jnp.zeros(page + (width,), storage)
+        if rt.cache_dtype in ("int8", "int4"):
+            cache[name + "_scale"] = jnp.zeros(page + (kv,), jnp.float32)
     return cache
 
 
 def init_paged_caches(cfg: ArchConfig, rt: Runtime, batch: int,
                       sv: ServingConfig) -> Dict:
     """Full-model paged caches, mirroring transformer.init_caches' structure
-    ({"rep": stacked-over-repeats, "tail": per-layer}).  Paged serving only
-    supports pure-attention stacks (SSM/LRU states are O(1) and don't page).
+    ({"rep": stacked-over-repeats, "tail": per-layer}): each block of the
+    repeat unit holds one pool stacked over the repeats (its block table
+    stacked likewise, [n_repeats, B, pages_per_seq]), each tail block a
+    pool of one layer.  Paged serving only supports pure-attention stacks
+    (SSM/LRU states are O(1) and don't page).
     """
     blocks = tuple(cfg.pattern) + tuple(cfg.tail)
     assert all(bt == "A" for bt in blocks), (
         f"paged KV serving requires an all-attention arch, got {blocks}")
+    R = cfg.n_repeats
 
-    def unit(_):
-        return {f"u{j}": {"attn": init_paged_attn_cache(cfg, rt, batch, sv)}
-                for j in range(len(cfg.pattern))}
+    def stacked():
+        cache = init_paged_attn_cache(cfg, rt, batch, sv, layers=R)
+        cache["tbl"] = jnp.zeros((R,) + cache["tbl"].shape, jnp.int32)
+        return {"attn": cache}
 
-    stacked = jax.vmap(unit)(jnp.arange(cfg.n_repeats))
-    tail = {f"tail{t}": {"attn": init_paged_attn_cache(cfg, rt, batch, sv)}
-            for t in range(len(cfg.tail))}
-    return {"rep": stacked, "tail": tail}
+    return {"rep": {f"u{j}": stacked() for j in range(len(cfg.pattern))},
+            "tail": {f"tail{t}": {"attn": init_paged_attn_cache(cfg, rt,
+                                                                batch, sv)}
+                     for t in range(len(cfg.tail))}}
+
+
+def _write_stack(cache: Dict, k, v, page, slot) -> Dict:
+    """Scatter each token's K and V [..., KV, hd], encoded as the pool holds
+    them (`encode_kv`), into the stacked pool at (cache["layer"], page,
+    slot), index arrays shaped like the tokens.  A page of num_pages is out
+    of bounds and the write drops."""
+    out = dict(cache)
+    for name, val in (("k", k), ("v", v)):
+        for leaf, enc in zip((name, name + "_scale"),
+                             encode_kv(val, cache[name].dtype)):
+            if enc is not None:
+                out[leaf] = cache[leaf].at[cache["layer"], page, slot].set(
+                    enc, mode="drop")
+    return out
 
 
 def paged_write(cache: Dict, k, v, abs_pos) -> Dict:
     """Write k/v [B, n, KV, hd] at absolute positions abs_pos [B, n] through
-    the block table.  Negative positions (left-pad / inactive rows) are routed
-    to an out-of-bounds page index and dropped.
+    the block table into layer ``cache["layer"]`` of the stacked pool.
+    Negative positions (left-pad / inactive rows) are routed to an
+    out-of-bounds page index and dropped.
 
-    One batched 2D scatter per pool leaf — no per-row host loop and no flat
-    reshape round-trip.  The layer loop (``models.transformer.forward``)
-    hands this one layer's slice of the stacked pool and writes the result
-    back into the donated stack in place, so a step holds a layer's slice
-    beside the pool, never a second pool."""
-    P, ps = cache["k"].shape[:2]
+    One batched scatter per pool leaf straight into the donated stack — no
+    per-row host loop, no flat reshape round-trip and no copy of the
+    layer's slice — so a step holds the pool once."""
+    P, ps = cache["k"].shape[1:3]
     tbl = cache["tbl"]
     logical = jnp.clip(abs_pos // ps, 0, tbl.shape[1] - 1)       # [B, n]
     phys = jnp.take_along_axis(tbl, logical, axis=1)
     page = jnp.where(abs_pos >= 0, phys, P)                      # P => dropped
-    slot = abs_pos % ps                                          # in [0, ps)
-
-    def write(pool, val):
-        return pool.at[page, slot].set(val.astype(pool.dtype), mode="drop")
-
-    out = dict(cache)
-    if "k_scale" in cache:
-        int4 = cache["k"].dtype == jnp.uint8
-        for name, val in (("k", k), ("v", v)):
-            q, scale = quantize_kv(val, int4)
-            out[name] = write(cache[name], q)
-            out[name + "_scale"] = write(cache[name + "_scale"], scale)
-    else:
-        out["k"] = write(cache["k"], k)
-        out["v"] = write(cache["v"], v)
-    return out
+    return _write_stack(cache, k, v, page, abs_pos % ps)
 
 
 def ragged_paged_write(cache: Dict, k, v, abs_pos) -> Dict:
@@ -155,58 +193,42 @@ def ragged_paged_write(cache: Dict, k, v, abs_pos) -> Dict:
     the identical ``quantize_kv`` math to the bucketed writes, so a pool
     filled by chunked ragged steps is bit-identical to one filled by
     bucketed prefill + decode."""
-    P, ps = cache["k"].shape[:2]
+    P, ps = cache["k"].shape[1:3]
     tbl, slots = cache["tbl"], cache["slots"]       # [max_batch, pps], [T]
     pos = abs_pos.reshape(-1)                       # [T]
     logical = jnp.clip(pos // ps, 0, tbl.shape[1] - 1)
     phys = tbl[jnp.clip(slots, 0, tbl.shape[0] - 1), logical]
     page = jnp.where((pos >= 0) & (slots >= 0), phys, P)   # P => dropped
-    slot_in_page = pos % ps
-
-    def write(pool, val):
-        return pool.at[page, slot_in_page].set(val.astype(pool.dtype),
-                                               mode="drop")
-
-    out = dict(cache)
-    if "k_scale" in cache:
-        int4 = cache["k"].dtype == jnp.uint8
-        for name, val in (("k", k), ("v", v)):
-            q, scale = quantize_kv(val, int4)
-            out[name] = write(cache[name], q[0])
-            out[name + "_scale"] = write(cache[name + "_scale"], scale[0])
-    else:
-        out["k"] = write(cache["k"], k[0])
-        out["v"] = write(cache["v"], v[0])
-    return out
+    return _write_stack(cache, k[0], v[0], page, pos % ps)
 
 
-def paged_read(cache: Dict, last_pos):
-    """Gather each row's pages back into the contiguous [B, max_ctx, KV, hd]
-    layout.  last_pos [B] is the newest valid absolute position per row (-1 =
-    inactive row); returns (k, v, kpos) with kpos[b, j] = j for valid slots,
-    -1 otherwise — the same masking contract as the contiguous cache.
+def paged_read(cache: Dict, last_pos, kv_heads: int):
+    """Gather each row's pages of layer ``cache["layer"]`` back into the
+    contiguous [B, max_ctx, KV, hd] layout (`kv_heads` KV heads a token).
+    last_pos [B] is the newest valid absolute position per row (-1 =
+    inactive row); returns (k, v, kpos) with kpos[b, j] = j for valid
+    slots, -1 otherwise — the same masking contract as the contiguous
+    cache.
 
     Table slots holding the out-of-bounds sentinel (page index == num_pages,
     the unallocated-slot marker `table_row` writes) gather exact zeros via
     fill-mode indexing — stale pool data behind a dead table entry can never
     leak into the dense layout (attention masks those slots, but a NaN in a
     recycled page would still poison `0 * NaN` in the PV contraction)."""
-    P, ps = cache["k"].shape[:2]
+    ps = cache["k"].shape[2]
     tbl = cache["tbl"]
     B, pps = tbl.shape
     max_ctx = pps * ps
-    idx = (tbl[:, :, None] * ps
-           + jnp.arange(ps, dtype=jnp.int32)[None, None, :]).reshape(B, max_ctx)
 
-    def gather(pool):
-        flat = pool.reshape(P * ps, *pool.shape[2:])
-        return flat.at[idx].get(mode="fill", fill_value=0)
+    def gather(pool):                       # [B, max_ctx, KV, w]
+        pages = pool.at[cache["layer"], tbl].get(mode="fill", fill_value=0)
+        return pages.reshape(B, max_ctx, kv_heads, -1)
 
     if "k_scale" in cache:
         k = dequantize_kv(gather(cache["k"]), gather(cache["k_scale"]))
         v = dequantize_kv(gather(cache["v"]), gather(cache["v_scale"]))
-    else:
-        k, v = gather(cache["k"]), gather(cache["v"])
+    else:                                   # bits -> values (zero -> 0.0)
+        k, v = (pool_values(gather(cache[name]), False) for name in "kv")
     j = jnp.arange(max_ctx, dtype=jnp.int32)[None, :]
     valid = (j <= last_pos[:, None]) & (last_pos[:, None] >= 0)
     return k, v, jnp.where(valid, j, -1)

@@ -13,31 +13,43 @@ import pytest
 from repro.configs import Runtime, ServingConfig, get_config
 from repro.kernels import autotune, ops
 from repro.kernels import paged_attention as pa
-from repro.kernels.ragged_attention import ragged_decode_attention
-from repro.models.attention import attention_core, quantize_kv
+from repro.kernels.ragged_attention import (
+    ragged_attention_xla,
+    ragged_decode_attention,
+)
+from repro.models.attention import attention_core, dequantize_kv
 from repro.serving.api import poisson_trace, run_trace
 from repro.serving.engine import InferenceEngine, build_params
-from repro.serving.kv_pages import paged_read
+from repro.serving.kv_pages import encode_kv, paged_read, pool_storage
+
+#: every pool here is a layer-stacked one of LAYERS layers, read at LAYER
+LAYERS, LAYER = 3, 1
 
 
-def _pool_setup(rng, B, KV, hd, ps, pps, cache_dtype="bfloat16"):
-    """Random pool + permuted block tables (pages deliberately scattered)."""
+def _pool_setup(rng, B, KV, hd, ps, pps, cache_dtype="bfloat16",
+                layers=LAYERS, layer=LAYER):
+    """Random layer-stacked, lane-dense pool (as the serving cache holds
+    it) + permuted block tables (pages deliberately scattered), read at
+    `layer`."""
     P = B * pps + 4
-    k32 = jnp.asarray(rng.standard_normal((P, ps, KV, hd)), jnp.float32)
-    v32 = jnp.asarray(rng.standard_normal((P, ps, KV, hd)), jnp.float32)
+    k32 = jnp.asarray(rng.standard_normal((layers, P, ps, KV, hd)),
+                      jnp.float32)
+    v32 = jnp.asarray(rng.standard_normal((layers, P, ps, KV, hd)),
+                      jnp.float32)
     tbl = jnp.asarray(rng.permutation(P)[: B * pps].reshape(B, pps),
                       jnp.int32)
-    if cache_dtype in ("int8", "int4"):
-        kq, ks = quantize_kv(k32, cache_dtype == "int4")
-        vq, vs = quantize_kv(v32, cache_dtype == "int4")
-        return {"tbl": tbl, "k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-    dt = jnp.bfloat16 if cache_dtype == "bfloat16" else jnp.float32
-    return {"tbl": tbl, "k": k32.astype(dt), "v": v32.astype(dt)}
+    cache = {"tbl": tbl, "layer": jnp.int32(layer)}
+    for name, val in (("k", k32), ("v", v32)):
+        cache[name], scale = encode_kv(val, pool_storage(cache_dtype))
+        if scale is not None:
+            cache[name + "_scale"] = scale
+    return cache
 
 
 def _reference(q, cache, last, window=0):
     """The gather path: paged_read + dense attention (masked softmax)."""
-    kf, vf, kpos = paged_read(cache, last)
+    kv_heads = pa.pool_heads(cache["k"], q.shape[-1])
+    kf, vf, kpos = paged_read(cache, last, kv_heads)
     return attention_core(
         q[:, None], kf, vf, q_positions=last[:, None], k_positions=kpos,
         window=window, impl="full", chunk_q=64)[:, 0]
@@ -58,7 +70,8 @@ def test_decode_xla_twin_bit_identical_across_page_sizes(ps, pps):
                        jnp.int32)
     ref = _reference(q, cache, last)
     out = pa.paged_decode_attention_xla(q, cache["k"], cache["v"],
-                                        cache["tbl"], last, pp=3)
+                                        cache["tbl"], last, cache["layer"],
+                                        pp=3)
     act = np.asarray(last) >= 0
     np.testing.assert_array_equal(np.float32(out)[act], np.float32(ref)[act])
     # inactive rows are masked to zero (finite, never NaN)
@@ -75,7 +88,7 @@ def test_decode_xla_twin_quantized_pools(cache_dtype):
     last = jnp.asarray([ps * pps - 1, 5, 0], jnp.int32)
     ref = _reference(q, cache, last)
     out = pa.paged_decode_attention_xla(
-        q, cache["k"], cache["v"], cache["tbl"], last,
+        q, cache["k"], cache["v"], cache["tbl"], last, cache["layer"],
         cache.get("k_scale"), cache.get("v_scale"), pp=2)
     np.testing.assert_array_equal(np.float32(out), np.float32(ref))
 
@@ -92,7 +105,7 @@ def test_decode_pallas_kernel_matches_reference(cache_dtype):
     ref = _reference(q, cache, last)
     for pp, bkv in [(1, 0), (4, 2)]:
         out = pa.paged_decode_attention(
-            q, cache["k"], cache["v"], cache["tbl"], last,
+            q, cache["k"], cache["v"], cache["tbl"], last, cache["layer"],
             cache.get("k_scale"), cache.get("v_scale"),
             pp=pp, bkv=bkv, interpret=True)
         act = np.asarray(last) >= 0
@@ -115,13 +128,13 @@ def _walk(kernel, q, cache, last, window=0, pp=4):
     scales = cache.get("k_scale"), cache.get("v_scale")
     if kernel == "paged":
         return pa.paged_decode_attention(
-            q, cache["k"], cache["v"], cache["tbl"], last, *scales,
-            window=window, pp=pp, interpret=True)
+            q, cache["k"], cache["v"], cache["tbl"], last, cache["layer"],
+            *scales, window=window, pp=pp, interpret=True)
     slot = jnp.where(last >= 0, jnp.arange(last.shape[0], dtype=jnp.int32),
                      -1)
     return ragged_decode_attention(
-        q, cache["k"], cache["v"], cache["tbl"], slot, last, *scales,
-        window=window, pp=pp, interpret=True)
+        q, cache["k"], cache["v"], cache["tbl"], slot, last, cache["layer"],
+        *scales, window=window, pp=pp, interpret=True)
 
 
 @pytest.mark.parametrize("window", [0, 6])
@@ -176,13 +189,22 @@ def test_live_page_fetches_each_live_page_once(pp, window):
                 0, pp - end - 1), last
 
 
+def _nan(leaf):
+    """A NaN as `leaf` holds it (a float pool holds its values' bits)."""
+    if leaf.dtype in pa.FLOAT_OF_BITS:
+        return jax.lax.bitcast_convert_type(
+            jnp.asarray(jnp.nan, pa.FLOAT_OF_BITS[leaf.dtype]), leaf.dtype)
+    return jnp.nan
+
+
 def _poisoned_pool(rng, B, KV, hd, ends, cache_dtype):
-    """A pool in which every page that holds no live position of any row
-    is NaN (float pools) or has NaN scales (int8), with the clean pool the
-    reference reads.  Rows own distinct pages drawn from all but the last
-    physical page; past its last live page a row's table holds the
-    out-of-bounds sentinel (even rows, clamped to the last page) or other
-    dead pages (odd rows)."""
+    """A pool in which every page of the read layer that holds no live
+    position of any row, and every page of the other layers, is NaN (float
+    pools) or has NaN scales (int8), with the clean pool the reference
+    reads.  Rows own distinct pages drawn from all but the last physical
+    page; past its last live page a row's table holds the out-of-bounds
+    sentinel (even rows, clamped to the last page) or other dead pages
+    (odd rows)."""
     P = B * _PPS + 4
     clean = _pool_setup(rng, B, KV, hd, _PS, _PPS, cache_dtype)
     n_live = [e // _PS + 1 if e >= 0 else 0 for e in ends]
@@ -200,8 +222,11 @@ def _poisoned_pool(rng, B, KV, hd, ends, cache_dtype):
     clean["tbl"] = jnp.asarray(tbl)
     poisoned = dict(clean)
     names = ("k_scale", "v_scale") if "k_scale" in clean else ("k", "v")
+    others = np.asarray([r for r in range(LAYERS) if r != LAYER])
     for name in names:
-        poisoned[name] = clean[name].at[dead].set(jnp.nan)
+        nan = _nan(clean[name])
+        poisoned[name] = clean[name].at[LAYER, dead].set(nan).at[
+            others].set(nan)
     return clean, poisoned
 
 
@@ -231,6 +256,88 @@ def test_decode_kernels_never_read_dead_pages(kernel, cache_dtype, pp):
     assert (np.float32(_walk(kernel, q, poisoned, idle, pp=pp)) == 0).all()
 
 
+def _twin(kernel, q, cache, last):
+    """The XLA twin of one of the two page walks, called as `_walk` calls
+    the kernel."""
+    scales = cache.get("k_scale"), cache.get("v_scale")
+    if kernel == "paged":
+        return pa.paged_decode_attention_xla(
+            q, cache["k"], cache["v"], cache["tbl"], last, cache["layer"],
+            *scales)
+    slot = jnp.where(last >= 0, jnp.arange(last.shape[0], dtype=jnp.int32),
+                     -1)
+    return ragged_attention_xla(
+        q, cache["k"], cache["v"], cache["tbl"], slot, last, cache["layer"],
+        *scales)
+
+
+def _slice_reference(q, cache, r, last):
+    """Dense attention over layer r's slice of the stack viewed [P, ps, KV,
+    hd], the layout a pool had before it was stacked: its values decoded,
+    each row's pages gathered through its table (sentinel pages zero) —
+    no layer index and no paged_read."""
+    B, H, hd = q.shape
+    KV = pa.pool_heads(cache["k"], hd)
+    P, ps = cache["k"].shape[1:3]
+    tbl = np.asarray(cache["tbl"])
+    ctx = tbl.shape[1] * ps
+
+    def dense(name):
+        pages = cache[name][r].reshape(P, ps, KV, -1)[np.minimum(tbl, P - 1)]
+        if "k_scale" in cache:
+            scale = cache[name + "_scale"][r][np.minimum(tbl, P - 1)]
+            pages = dequantize_kv(pages, scale[..., None])
+        else:
+            pages = pa.pool_values(pages, False)
+        pages = jnp.where((tbl < P)[..., None, None, None], pages, 0)
+        return pages.reshape(B, ctx, KV, hd)
+
+    j = jnp.arange(ctx, dtype=jnp.int32)[None]
+    kpos = jnp.where((j <= last[:, None]) & (last[:, None] >= 0), j, -1)
+    return attention_core(
+        q[:, None], dense("k"), dense("v"), q_positions=last[:, None],
+        k_positions=kpos, window=0, impl="full", chunk_q=64)[:, 0]
+
+
+@pytest.mark.parametrize("KV,G,hd", [(2, 7, 64), (8, 2, 128)],
+                         ids=["hd64", "hd128"])
+@pytest.mark.parametrize("cache_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("kernel", ["paged", "ragged"])
+def test_each_layer_of_the_stack_reads_as_its_slice(kernel, cache_dtype, KV,
+                                                    G, hd):
+    """Over a layer-stacked, lane-dense pool, the page walk at each layer
+    index gives bit-identical output to the same walk over that layer's
+    slice alone, and its XLA twin gives bit-identical output to dense
+    attention over the slice viewed [P, ps, KV, hd].  The idle row, whose
+    table holds only the out-of-bounds sentinel, gives zeros."""
+    rng = np.random.default_rng(KV * hd + len(cache_dtype))
+    B, ps, pps = 4, 4, 4
+    cache = _pool_setup(rng, B, KV, hd, ps, pps, cache_dtype)
+    P = cache["k"].shape[1]
+    cache["tbl"] = cache["tbl"].at[3].set(P)
+    q = jnp.asarray(rng.standard_normal((B, KV * G, hd)), jnp.bfloat16)
+    last = jnp.asarray([ps * pps - 1, 6, 0, -1], jnp.int32)
+    act = np.asarray(last) >= 0
+    for r in range(LAYERS):
+        at_r = dict(cache, layer=jnp.int32(r))
+        alone = dict(cache, layer=jnp.int32(0),
+                     **{name: cache[name][r:r + 1] for name in cache
+                        if name in ("k", "v", "k_scale", "v_scale")})
+        ref = np.float32(_slice_reference(q, cache, r, last))
+        twin = np.float32(_twin(kernel, q, at_r, last))
+        np.testing.assert_array_equal(twin[act], ref[act])
+        assert (twin[~act] == 0).all()
+        out = np.float32(_walk(kernel, q, at_r, last))
+        np.testing.assert_array_equal(out,
+                                      np.float32(_walk(kernel, q, alone,
+                                                       last)))
+        # the kernel's single-pass online softmax is held to the dense
+        # reference within bf16 tolerance, as in every test above
+        np.testing.assert_allclose(  # repro: ignore[tolerance-claim-mismatch]
+            out[act], ref[act], atol=2e-2)
+        assert (out[~act] == 0).all()
+
+
 def test_decode_window_masking():
     rng = np.random.default_rng(13)
     B, KV, G, hd, ps, pps = 2, 2, 2, 16, 4, 8
@@ -240,11 +347,11 @@ def test_decode_window_masking():
     for window in (5, 16):
         ref = _reference(q, cache, last, window=window)
         tw = pa.paged_decode_attention_xla(
-            q, cache["k"], cache["v"], cache["tbl"], last,
+            q, cache["k"], cache["v"], cache["tbl"], last, cache["layer"],
             window=window, pp=2)
         np.testing.assert_array_equal(np.float32(tw), np.float32(ref))
         kr = pa.paged_decode_attention(
-            q, cache["k"], cache["v"], cache["tbl"], last,
+            q, cache["k"], cache["v"], cache["tbl"], last, cache["layer"],
             window=window, pp=2, interpret=True)
         np.testing.assert_allclose(np.float32(kr), np.float32(tw), atol=2e-2)
 
@@ -258,11 +365,11 @@ def test_ops_dispatch_routes_xla_twin_off_tpu():
     q = jnp.asarray(rng.standard_normal((B, KV * G, hd)), jnp.bfloat16)
     last = jnp.asarray([7, 14], jnp.int32)
     via_ops = ops.paged_decode_attention(q, cache["k"], cache["v"],
-                                         cache["tbl"], last)
+                                         cache["tbl"], last, cache["layer"])
     blocks = autotune.get_blocks("attn.paged_decode", B, ps * pps,
                                  KV * G * hd, "bfloat16", group_size=ps)
     direct = pa.paged_decode_attention_xla(
-        q, cache["k"], cache["v"], cache["tbl"], last,
+        q, cache["k"], cache["v"], cache["tbl"], last, cache["layer"],
         pp=max(1, blocks["bk"] // ps))
     np.testing.assert_array_equal(np.float32(via_ops), np.float32(direct))
 

@@ -14,6 +14,7 @@ from repro.serving.engine import InferenceEngine
 from repro.serving.kv_pages import (
     ContinuousKVCache,
     PagedKVCacheManager,
+    encode_kv,
     init_paged_attn_cache,
     paged_read,
     paged_write,
@@ -158,7 +159,8 @@ def test_poisoned_page0_cannot_leak_through_dead_table_slots():
     kv.ensure(0, 8)                     # rid 0 owns pages 0..1
     kv.ensure(1, 8)                     # rid 1 owns pages 2..3
     cache = init_paged_attn_cache(cfg, rt, 1, SV)
-    cache = dict(cache, tbl=jnp.asarray(kv.table_row(1))[None])
+    cache = dict(cache, tbl=jnp.asarray(kv.table_row(1))[None],
+                 layer=jnp.int32(0))
 
     n = 6
     rng = np.random.default_rng(0)
@@ -171,14 +173,16 @@ def test_poisoned_page0_cannot_leak_through_dead_table_slots():
     last = jnp.asarray([n - 1], jnp.int32)
 
     def decode_out(c):
-        kf, vf, kpos = paged_read(c, last)
+        kf, vf, kpos = paged_read(c, last, cfg.n_kv_heads)
         return np.asarray(attention_core(
             q, kf, vf, q_positions=last[:, None], k_positions=kpos,
             window=0, impl="full", chunk_q=512), np.float32)
 
     clean = decode_out(cache)
-    poisoned = dict(cache, k=cache["k"].at[0].set(jnp.nan),
-                    v=cache["v"].at[0].set(jnp.nan))
+    nan = encode_kv(jnp.full((1, cfg.n_kv_heads, cfg.hd), jnp.nan),
+                    cache["k"].dtype)[0][0]        # a NaN as the pool holds it
+    poisoned = dict(cache, k=cache["k"].at[0, 0].set(nan),
+                    v=cache["v"].at[0, 0].set(nan))
     out = decode_out(poisoned)
     assert np.isfinite(out).all()
     np.testing.assert_array_equal(clean, out)

@@ -16,20 +16,26 @@ from repro.kernels.ragged_attention import (
     ragged_attention_xla,
     ragged_decode_attention,
 )
-from repro.models.attention import quantize_kv
 from repro.serving.api import bursty_trace, mixed_trace, run_trace
 from repro.serving.engine import InferenceEngine
-from repro.serving.kv_pages import PagedKVCacheManager
+from repro.serving.kv_pages import (
+    PagedKVCacheManager,
+    encode_kv,
+    pool_storage,
+)
 from repro.serving.scheduler import Request, Scheduler
 
 
 # ------------------------------------------------- kernel vs XLA twin -----
+#: the pools here are layer-stacked over LAYERS layers, read at LAYER
+LAYERS, LAYER = 2, 1
+
+
 def _pool(rng, P, ps, KV, hd, dtype):
-    vals = rng.standard_normal((P, ps, KV, hd)).astype(np.float32)
-    if dtype == "bfloat16":
-        return jnp.asarray(vals, jnp.bfloat16), None
-    q, s = quantize_kv(jnp.asarray(vals), int4=(dtype == "int4"))
-    return q, s
+    """A random layer-stacked pool leaf as the serving cache holds it, and
+    its scales (None for a float pool)."""
+    vals = rng.standard_normal((LAYERS, P, ps, KV, hd)).astype(np.float32)
+    return encode_kv(jnp.asarray(vals), pool_storage(dtype))
 
 
 @pytest.mark.parametrize("ps", [1, 4, 16])
@@ -61,10 +67,11 @@ def test_ragged_kernel_matches_xla_twin(ps, cache_dtype):
     for pp in (1, 2):
         out_k = ragged_decode_attention(
             q, k_pool, v_pool, jnp.asarray(tbl), jnp.asarray(token_slot),
-            jnp.asarray(token_pos), k_scale, v_scale, pp=pp, interpret=True)
+            jnp.asarray(token_pos), LAYER, k_scale, v_scale, pp=pp,
+            interpret=True)
         out_x = ragged_attention_xla(
             q, k_pool, v_pool, jnp.asarray(tbl), jnp.asarray(token_slot),
-            jnp.asarray(token_pos), k_scale, v_scale, pp=pp)
+            jnp.asarray(token_pos), LAYER, k_scale, v_scale, pp=pp)
         a = np.asarray(out_k, np.float32)
         b = np.asarray(out_x, np.float32)
         assert np.max(np.abs(a - b)) < 2e-2, (ps, cache_dtype, pp)

@@ -137,10 +137,12 @@ def test_paged_read_bit_identical_to_contiguous(cache_dtype):
     kv_mgr.release(99)
     kv_mgr.ensure(1, n)
     tbl = jnp.asarray(np.stack([kv_mgr.table_row(0), kv_mgr.table_row(1)]))
-    paged = init_paged_attn_cache(cfg, rt, B, sv)
-    paged = dict(paged, tbl=tbl)
+    # the middle layer of a three-layer stack
+    paged = init_paged_attn_cache(cfg, rt, B, sv, layers=3)
+    paged = dict(paged, tbl=tbl, layer=jnp.int32(1))
     paged = paged_write(paged, k, v, pos)
-    kp, vp, kpos = paged_read(paged, jnp.full((B,), n - 1, jnp.int32))
+    kp, vp, kpos = paged_read(paged, jnp.full((B,), n - 1, jnp.int32),
+                              cfg.n_kv_heads)
 
     valid = np.asarray(kpos) >= 0
     assert valid[:, :n].all() and not valid[:, n:].any()
@@ -158,13 +160,15 @@ def test_negative_positions_are_dropped():
     rt = Runtime(cache_dtype="bfloat16", aligned_decode=False)
     sv = ServingConfig(layout="paged", max_batch=1, page_size=4,
                        num_pages=4, max_ctx=16)
-    paged = init_paged_attn_cache(cfg, rt, 1, sv)
-    paged = dict(paged, tbl=jnp.arange(4, dtype=jnp.int32)[None])
+    paged = init_paged_attn_cache(cfg, rt, 1, sv, layers=2)
+    paged = dict(paged, tbl=jnp.arange(4, dtype=jnp.int32)[None],
+                 layer=jnp.int32(1))
     k = jnp.ones((1, 3, cfg.n_kv_heads, cfg.hd), jnp.bfloat16)
     out = paged_write(paged, k, k, jnp.asarray([[-2, -1, 5]], jnp.int32))
-    pool = np.asarray(out["k"], np.float32)
-    assert pool.reshape(16, -1)[5].all()            # the valid slot landed
-    assert (pool.reshape(16, -1)[[0, 1, 2, 3, 4]] == 0).all()
+    pool = np.asarray(out["k"]).reshape(2, 16, -1)
+    assert pool[1, 5].all()                         # the valid slot landed
+    assert (pool[1, [0, 1, 2, 3, 4]] == 0).all()
+    assert (pool[0] == 0).all()                     # the other layer
 
 
 # ------------------------------------------------------------- engine e2e --

@@ -26,7 +26,11 @@ from repro.launch.steps import make_serving_steps
 from repro.models import init_model
 from repro.models.transformer import _logits, forward, prefill
 from repro.serving.kv_pages import (
+    encode_kv,
+    init_paged_attn_cache,
     init_paged_caches,
+    paged_write,
+    ragged_paged_write,
     with_block_tables,
 )
 
@@ -129,3 +133,79 @@ def test_prefill_then_paged_decode_matches_the_full_forward(shape):
     want = want[0, prompt - 1:prompt + n_dec - 1]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_decode_step_temp_bytes_under_one_layers_slice(shape):
+    """The decode step reads and writes the layer-stacked pool where it
+    lives: its temporaries stay under one layer's K and V slice, so no
+    layer's slice is ever copied out of the stack or back."""
+    cfg = _cfg(shape)
+    rt = Runtime(scan_layers=True, attn_impl="chunked", remat="none",
+                 loss_chunk=0, quant_backend="w4a16")
+    sv = _sv(num_pages=4096)
+    params = jax.eval_shape(lambda: init_model(jax.random.PRNGKey(0), cfg))
+    caches = jax.eval_shape(lambda: init_paged_caches(cfg, rt, 0, sv))
+    _, _, decode = make_serving_steps(cfg, rt, paged=True)
+    tbl = jax.ShapeDtypeStruct((MAX_BATCH, sv.pages_per_seq), jnp.int32)
+    rows = jax.ShapeDtypeStruct((MAX_BATCH, 1), jnp.int32)
+    mem = decode.lower(params, rows, caches, rows, tbl,
+                       jax.ShapeDtypeStruct((MAX_BATCH,), jnp.int32)
+                       ).compile().memory_analysis()
+    layer_slice = _nbytes(caches) / cfg.n_layers
+    assert mem.temp_size_in_bytes < layer_slice, (
+        mem.temp_size_in_bytes, layer_slice)
+
+
+def _random_bytes(rng, leaf):
+    """A pool leaf of random contents, so that an untouched byte tells."""
+    if leaf.dtype == jnp.float32:
+        return jnp.asarray(rng.standard_normal(leaf.shape), jnp.float32)
+    info = np.iinfo(leaf.dtype)
+    return jnp.asarray(rng.integers(info.min, info.max, leaf.shape,
+                                    endpoint=True), leaf.dtype)
+
+
+@pytest.mark.parametrize("cache_dtype", ["bfloat16", "int8", "int4"])
+@pytest.mark.parametrize("write", ["paged", "ragged"])
+def test_writes_change_only_their_layers_slots(write, cache_dtype):
+    """paged_write and ragged_paged_write at layer r put each token's K and
+    V, as the pool encodes them, in the (page, slot) its table addresses
+    in layer r; every other byte of the stack, in every layer, keeps its
+    value (negative positions, padding rows and sentinel pages drop)."""
+    cfg = _cfg("qwen2")
+    rt = Runtime(cache_dtype=cache_dtype)
+    ps, P, L, r = 4, 12, 3, 1
+    sv = ServingConfig(layout="paged", max_batch=2, page_size=ps,
+                       num_pages=P, max_ctx=16)
+    rng = np.random.default_rng(len(cache_dtype))
+    cache = {name: _random_bytes(rng, leaf) for name, leaf in
+             init_paged_attn_cache(cfg, rt, 2, sv, layers=L).items()}
+    cache["tbl"] = jnp.asarray([[5, 2, 9, P], [7, 0, 3, 11]], jnp.int32)
+    cache["layer"] = jnp.int32(r)
+    pos = np.asarray([[6, 7, 8, 13], [0, 15, -1, 3]], np.int32)
+    shape = pos.shape + (cfg.n_kv_heads, cfg.hd)
+    k = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    if write == "paged":
+        out = paged_write(cache, k, v, jnp.asarray(pos))
+    else:                          # the same tokens packed, one padding row
+        slots = np.repeat(np.arange(2, dtype=np.int32), 4)
+        slots[1] = -1
+        out = ragged_paged_write(
+            dict(cache, slots=jnp.asarray(slots)), k.reshape(1, 8, *shape[2:]),
+            v.reshape(1, 8, *shape[2:]), jnp.asarray(pos.reshape(1, 8)))
+    want = {name: np.asarray(cache[name]).copy()
+            for name in ("k", "v", "k_scale", "v_scale") if name in cache}
+    tbl = np.asarray(cache["tbl"])
+    for name, val in (("k", k), ("v", v)):
+        enc = encode_kv(val, cache[name].dtype)
+        for b, i in np.ndindex(pos.shape):
+            page = tbl[b, pos[b, i] // ps] if pos[b, i] >= 0 else P
+            if page == P or (write == "ragged" and b * 4 + i == 1):
+                continue
+            for leaf, e in zip((name, name + "_scale"), enc):
+                if e is not None:
+                    want[leaf][r, page, pos[b, i] % ps] = np.asarray(e)[b, i]
+    for name, arr in want.items():
+        np.testing.assert_array_equal(np.asarray(out[name]), arr, name)

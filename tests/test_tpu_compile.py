@@ -31,6 +31,11 @@ from repro.kernels.w4a16_matmul import w4a16_matmul
 H, KV, HD, D, F = 14, 2, 64, 896, 4864      # qwen2-0.5b widths
 B, PS, MAX_CTX, T, S = 8, 16, 2048, 64, 512  # decode batch, page, ctx, ...
 PAGES = B * MAX_CTX // PS
+LAYERS = 24                                  # the pool's layer stack
+#: the serving cache's pool leaves: layer-stacked and lane-dense, a float
+#: pool holding its values' bits
+STORAGE = {jnp.bfloat16: jnp.uint16, jnp.int8: jnp.int8}
+LAYER = ((), jnp.int32)                      # the layer-index operand
 
 
 @pytest.fixture(scope="module")
@@ -93,23 +98,23 @@ def _paged_blocks(op):
                          ids=["bf16", "int8"])
 def test_paged_decode_attention_compiles(one_chip, no_persistent_cache,
                                          cache_dtype):
-    pool = ((PAGES, PS, KV, HD), cache_dtype)
+    pool = ((LAYERS, PAGES, PS, KV * HD), STORAGE[cache_dtype])
     shapes = [((B, H, HD), jnp.bfloat16), pool, pool,
-              ((B, MAX_CTX // PS), jnp.int32), ((B,), jnp.int32)]
+              ((B, MAX_CTX // PS), jnp.int32), ((B,), jnp.int32), LAYER]
     if cache_dtype == jnp.int8:
-        shapes += [((PAGES, PS, KV, 1), jnp.float32)] * 2
+        shapes += [((LAYERS, PAGES, PS, KV), jnp.float32)] * 2
     _compile(functools.partial(paged_decode_attention, interpret=False,
                                **_paged_blocks("attn.paged_decode")),
              one_chip, *shapes)
 
 
 def test_ragged_decode_attention_compiles(one_chip, no_persistent_cache):
-    pool = ((PAGES, PS, KV, HD), jnp.bfloat16)
+    pool = ((LAYERS, PAGES, PS, KV * HD), jnp.uint16)
     _compile(functools.partial(ragged_decode_attention, interpret=False,
                                **_paged_blocks("attn.ragged")),
              one_chip, ((T, H, HD), jnp.bfloat16), pool, pool,
              ((B, MAX_CTX // PS), jnp.int32), ((T,), jnp.int32),
-             ((T,), jnp.int32))
+             ((T,), jnp.int32), LAYER)
 
 
 @pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8],
@@ -120,10 +125,10 @@ def test_page_walks_compile_at_chat_shapes(one_chip, no_persistent_cache,
                                            kernel, cache_dtype):
     """The page walks that stop at each row's last live page, at the chat
     deployment's shapes: a 64-row bucket, max_ctx 2560 in 16-token pages
-    over an 81,920-token pool."""
+    over an 81,920-token pool of 24 layers."""
     rows, max_ctx, pages = 64, 2560, 81920 // PS
-    pool = ((pages, PS, KV, HD), cache_dtype)
-    scales = ([((pages, PS, KV, 1), jnp.float32)] * 2
+    pool = ((LAYERS, pages, PS, KV * HD), STORAGE[cache_dtype])
+    scales = ([((LAYERS, pages, PS, KV), jnp.float32)] * 2
               if cache_dtype == jnp.int8 else [])
     tbl = ((rows, max_ctx // PS), jnp.int32)
     b = autotune.attn_default_blocks(
@@ -133,13 +138,58 @@ def test_page_walks_compile_at_chat_shapes(one_chip, no_persistent_cache,
     if kernel == "paged_decode_attention":
         call = functools.partial(paged_decode_attention, interpret=False,
                                  **blocks)
-        rest = [((rows,), jnp.int32)]
+        rest = [((rows,), jnp.int32), LAYER]
     else:
         call = functools.partial(ragged_decode_attention, interpret=False,
                                  **blocks)
-        rest = [((rows,), jnp.int32)] * 2
+        rest = [((rows,), jnp.int32)] * 2 + [LAYER]
     _compile(call, one_chip, ((rows, H, HD), jnp.bfloat16), pool, pool, tbl,
              *rest, *scales)
+
+
+#: (layers, pool tokens, query heads, KV heads, head size) of the chat
+#: cells' pools: qwen2-0.5b's 81,920 tokens, qwen3-4b's 57,344
+CHAT_POOLS = {"qwen2-0.5b.chat": (24, 81920, 14, 2, 64),
+              "qwen3-4b.chat": (36, 57344, 32, 8, 128)}
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode_attention",
+                                    "ragged_decode_attention"])
+@pytest.mark.parametrize("cell", sorted(CHAT_POOLS))
+def test_stacked_pool_walks_compile_at_chat_shapes(one_chip,
+                                                   no_persistent_cache,
+                                                   cell, kernel):
+    """The page walks over a chat cell's whole layer-stacked pool, at a
+    traced layer index, for a 64-row decode bucket: one compiled kernel
+    reads two layers of the stack where it lives, with no copy or
+    relayout of the pool (the compiled program's temporaries stay under
+    one layer's share of it)."""
+    L, tokens, heads, kv, hd = CHAT_POOLS[cell]
+    rows, max_ctx = 64, 2560
+    pool = ((L, tokens // PS, PS, kv * hd), jnp.uint16)
+    b = autotune.attn_default_blocks(
+        "attn.paged_decode" if kernel == "paged_decode_attention"
+        else "attn.ragged", rows, max_ctx, heads * hd, group_size=PS)
+    call = functools.partial(
+        paged_decode_attention if kernel == "paged_decode_attention"
+        else ragged_decode_attention, interpret=False, pp=b["bk"] // PS,
+        bkv=b["bn"])
+    rest = 1 if kernel == "paged_decode_attention" else 2
+
+    def two_layers(*operands, layer):
+        return call(*operands, layer) + call(*operands, layer + 1)
+
+    shapes = [((rows, heads, hd), jnp.bfloat16), pool, pool,
+              ((rows, max_ctx // PS), jnp.int32),
+              *[((rows,), jnp.int32)] * rest, LAYER]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(two_layers).lower(*args[:-1],
+                                         layer=args[-1]).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    pool_bytes = L * tokens * kv * hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / L
 
 
 def test_flash_prefill_compiles(one_chip, no_persistent_cache):
@@ -163,7 +213,7 @@ def _trace_names(kernel):
 def _serving_call(kernel):
     """(call, operand shapes) of a serving-path kernel at qwen2-0.5b
     widths."""
-    pool = ((PAGES, PS, KV, HD), jnp.bfloat16)
+    pool = ((LAYERS, PAGES, PS, KV * HD), jnp.uint16)
     if kernel == "w4a16_matmul":
         b = autotune.default_blocks(B, D, F, group_size=0)
         return (functools.partial(w4a16_matmul, group_size=0,
@@ -174,13 +224,13 @@ def _serving_call(kernel):
         return (functools.partial(paged_decode_attention, interpret=False,
                                   **_paged_blocks("attn.paged_decode")),
                 [((B, H, HD), jnp.bfloat16), pool, pool,
-                 ((B, MAX_CTX // PS), jnp.int32), ((B,), jnp.int32)])
+                 ((B, MAX_CTX // PS), jnp.int32), ((B,), jnp.int32), LAYER])
     if kernel == "ragged_decode_attention":
         return (functools.partial(ragged_decode_attention, interpret=False,
                                   **_paged_blocks("attn.ragged")),
                 [((T, H, HD), jnp.bfloat16), pool, pool,
                  ((B, MAX_CTX // PS), jnp.int32), ((T,), jnp.int32),
-                 ((T,), jnp.int32)])
+                 ((T,), jnp.int32), LAYER])
     b = autotune.attn_default_blocks("attn.prefill", S, S, H * HD)
     kv = ((1, S, KV, HD), jnp.bfloat16)
     return (functools.partial(flash_prefill, bq=b["bm"], bk=b["bk"],
