@@ -151,8 +151,9 @@ def _maybe_tune(do_tune: bool, on_tpu: bool):
     int8 a_q, the fused variant on its float x, w4a16 on bf16 x + G."""
     if not do_tune:
         return
-    from repro.core.quant import group_quantize, pack_int4
+    from repro.core.quant import group_quantize
     from repro.kernels import autotune, ops
+    from repro.kernels.packing import pack_kmajor
 
     rng = np.random.default_rng(7)
     interp = None if on_tpu else True
@@ -161,12 +162,12 @@ def _maybe_tune(do_tune: bool, on_tpu: bool):
         a_s = jnp.ones((M, 1), jnp.float32)
         x = jnp.asarray(rng.standard_normal((M, K)).astype(np.float32))
         xb = x.astype(jnp.bfloat16)
-        wp = pack_int4(
-            jnp.asarray(rng.integers(-8, 8, size=(K, N), dtype=np.int8)), -1)
+        wp = pack_kmajor(
+            jnp.asarray(rng.integers(-8, 8, size=(K, N), dtype=np.int8)))
         ws = jnp.ones((1, N), jnp.float32)
         qg, sg = group_quantize(
             jnp.asarray(rng.standard_normal((K, N)).astype(np.float32)), 128)
-        wpg = pack_int4(qg, -1)
+        wpg = pack_kmajor(qg, row_mult=2 * 128)
 
         specs = [
             ("int4_matmul", "int8", 0, lambda b:
@@ -194,8 +195,9 @@ def _maybe_tune(do_tune: bool, on_tpu: bool):
 
 
 def bench_kernels(do_tune: bool = False):
-    from repro.core.quant import group_quantize, pack_int4
-    from repro.kernels import ops, packing, ref
+    from repro.core.quant import group_quantize
+    from repro.kernels import ops, ref
+    from repro.kernels.packing import pack_kmajor
 
     rng = np.random.default_rng(0)
     # elementwise LUT multiplier array, 1M elements.  The Pallas LUT kernel
@@ -236,36 +238,31 @@ def bench_kernels(do_tune: bool = False):
         xb = x.astype(jnp.bfloat16)
         wq = jnp.asarray(rng.integers(-8, 8, size=(K, N), dtype=np.int8))
         w_s = jnp.asarray(rng.random((1, N), dtype=np.float32) + 0.05)
-        wp = pack_int4(wq, -1)
+        wp = pack_kmajor(wq)
         wf = jnp.asarray(rng.standard_normal((K, N)).astype(np.float32)) * 0.05
         qg, sg = group_quantize(wf, 128)
-        wpg = pack_int4(qg, -1)
+        wpg = pack_kmajor(qg, row_mult=2 * 128)
 
         # arrays are passed as jit *arguments* so XLA can't constant-fold
-        # the contraction away, and weights are prepacked to the planar
-        # K-major layout *outside* the timed call — that is what the
-        # serving path executes (build_params/prepack_tree twins); passing
-        # the interleaved weight through jit would time a per-call relayout
-        # the real models never pay
-        w_km = packing.prepack_kmajor(wp)
-        w_kmg = packing.prepack_kmajor(wpg, row_mult=2 * 128)
+        # the contraction away; weights are packed once, outside the timed
+        # call, in the layout the serving packer writes
         rows = {
             f"int4_matmul.{shape_name}": (
                 jax.jit(lambda a1, a2, a3, a4:
-                        ops.int4_matmul_kmajor(a1, a2, a3, a4)),
-                (aq, a_s, w_km, w_s)),
+                        ops.int4_matmul(a1, a2, a3, a4)),
+                (aq, a_s, wp, w_s)),
             f"int4_matmul_fused.{shape_name}": (
                 jax.jit(lambda a1, a2, a3:
-                        ops.int4_matmul_fused_kmajor(a1, a2, a3)),
-                (x, w_km, w_s)),
+                        ops.int4_matmul_fused(a1, a2, a3)),
+                (x, wp, w_s)),
             f"w4a16_g128.{shape_name}": (
                 jax.jit(lambda a1, a2, a3:
-                        ops.w4a16_matmul_kmajor(a1, a2, a3, 128)),
-                (xb, w_kmg, sg)),
+                        ops.w4a16_matmul(a1, a2, a3, 128)),
+                (xb, wpg, sg)),
             f"lut4_matmul.{shape_name}": (
                 jax.jit(lambda a1, a2, a3, a4:
-                        ops.lut4_matmul_kmajor(a1, a2, a3, a4)),
-                (aq, a_s, w_km, w_s)),
+                        ops.lut4_matmul(a1, a2, a3, a4)),
+                (aq, a_s, wp, w_s)),
         }
         for name, (fn, fargs) in rows.items():
             us = _time(fn, *fargs)

@@ -50,8 +50,8 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import ArchConfig, Runtime, ServingConfig, get_config  # noqa: E402,E501
-from repro.core.quant import pack_int4, unpack_int4  # noqa: E402
 from repro.kernels import ops, ref  # noqa: E402
+from repro.kernels.packing import pack_kmajor, unpack_kmajor  # noqa: E402,E501
 from repro.kernels.dispatch import ENV_INTERPRET  # noqa: E402
 from repro.kernels.ragged_attention import ragged_attention_xla  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
@@ -99,9 +99,9 @@ KERNEL_REL_RMS_TOL = 1e-2
 #: kernel.  An op missing here means a silent route to plain XLA (such a
 #: route calls no op at all, so only the served phases' own counts show it).
 SERVED_OPS = {
-    "bucketed": ("int4_matmul_fused_kmajor", "flash_prefill",
+    "bucketed": ("int4_matmul_fused", "flash_prefill",
                  "paged_decode_attention"),
-    "ragged": ("int4_matmul_fused_kmajor", "ragged_paged_attention"),
+    "ragged": ("int4_matmul_fused", "ragged_paged_attention"),
 }
 
 #: Four-chip training: the DP x TP run and the one-chip run see the same
@@ -164,12 +164,13 @@ def float_runtime(sc: SmokeConfig) -> Runtime:
 
 def dequantize_packed(params):
     """Every packed weight of a serving tree as the float weight it
-    encodes (per-channel scales)."""
+    encodes (per-channel scales; every served K is even, so the planar
+    layout adds no padding row)."""
     def dequant(w):
         if not (isinstance(w, dict) and "packed" in w):
             return w
         assert w["scale"].shape[-2] == 1, "per-channel scales only"
-        return (unpack_int4(w["packed"], axis=-1).astype(jnp.float32)
+        return (unpack_kmajor(w["packed"]).astype(jnp.float32)
                 * w["scale"].astype(jnp.float32))
 
     return jax.tree.map(dequant, params,
@@ -178,7 +179,7 @@ def dequantize_packed(params):
 
 # ------------------------------------------------------------- phases ----
 def phase_build(sc: SmokeConfig, plan: str = "w4a4_packed"):
-    """(a) Full-width serving params: init, pack, K-major twins."""
+    """(a) Full-width serving params: init and pack."""
     return jax.block_until_ready(
         build_params(sc.cfg, serving_runtime(sc, plan), sc.seed))
 
@@ -290,7 +291,7 @@ def phase_check_kernels(sc: SmokeConfig) -> Dict[str, float]:
     out = {}
 
     x = jax.random.normal(k[0], (B, D), jnp.bfloat16)
-    w = pack_int4(jax.random.randint(k[1], (D, F), -8, 8, jnp.int8), axis=-1)
+    w = pack_kmajor(jax.random.randint(k[1], (D, F), -8, 8, jnp.int8))
     w_scale = jax.random.uniform(k[2], (1, F), jnp.float32, 1e-3, 1e-2)
     out["int4_matmul_fused"] = _rel_rms(
         ops.int4_matmul_fused(x, w, w_scale),

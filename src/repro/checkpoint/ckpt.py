@@ -133,17 +133,19 @@ def restore_checkpoint(directory: str, step: int, like: Any,
 
 
 # ----------------------------------------------------------- quantized ----
-#: manifest tag identifying serving-ready packed checkpoints
-QUANTIZED_FORMAT = "quantized-v1"
+#: manifest tag identifying serving-ready packed checkpoints.  v2 holds the
+#: planar K-major weight layout (kernels/packing.py); v1 held the retired
+#: N-major layout (adjacent output columns per byte).
+QUANTIZED_FORMAT = "quantized-v2"
 
 
 def save_quantized(directory: str, step: int, params, cfg, rt=None,
                    plan=None, min_size: int = 1 << 12) -> str:
     """Quantize-and-save: pack float-master `params` per the active
-    QuantPlan (every quantized-serving site becomes uint8 K-packed nibbles +
-    bf16 scales — ~4x smaller artifacts than float masters) and store the
-    plan itself in the manifest.  Reuses the atomic `.tmp_` + os.replace
-    machinery of `save_checkpoint`.
+    QuantPlan (every quantized-serving site becomes uint8 planar K-major
+    nibbles + bf16 scales — ~4x smaller artifacts than float masters) and
+    store the plan itself in the manifest.  Reuses the atomic `.tmp_` +
+    os.replace machinery of `save_checkpoint`.
 
     Pass either `plan` (a QuantPlan) or `rt` (a Runtime whose
     quant_plan/quant_backend selects one).  Returns the checkpoint path.
@@ -192,6 +194,11 @@ def restore_quantized(directory: str, step: Optional[int] = None,
     path = os.path.join(directory, f"step_{step:08d}")
     with open(os.path.join(path, "manifest.json")) as f:
         manifest = json.load(f)
+    if manifest.get("format") == "quantized-v1":
+        raise ValueError(
+            f"{path} holds the old N-major int4 layout (quantized-v1), "
+            f"which this version no longer reads; re-pack it from float "
+            f"masters with save_quantized")
     assert manifest.get("format") == QUANTIZED_FORMAT, (
         f"{path} is not a quantized checkpoint "
         f"(format={manifest.get('format')!r}); use restore_checkpoint")

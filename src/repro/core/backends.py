@@ -84,8 +84,7 @@ def _int4_backend(w, x2, cfg, tag):
             and cfg.a_bits == 4 and cfg.w_bits == 4:
         # quantize + matmul + dequant in one pallas_call; the weight is
         # packed K-major directly from the quantized master
-        return ops.int4_matmul_fused_kmajor(xf, pack_kmajor(w_q), w_scale,
-                                            tag=tag)
+        return ops.int4_matmul_fused(xf, pack_kmajor(w_q), w_scale, tag=tag)
     a_scale = quant_scale(xf, axis=1, bits=cfg.a_bits)   # per-row
     a_q = quantize(xf, a_scale, bits=cfg.a_bits)
     acc = jnp.dot(a_q, w_q, preferred_element_type=jnp.int32)
@@ -114,8 +113,8 @@ def _lut4_backend(w, x2, cfg, tag):
     a_q = quantize(xf, a_scale, bits=cfg.a_bits)
     # the table kernel is int4-specific; other bit widths keep the XLA path
     if ops.use_pallas() and cfg.a_bits == 4 and cfg.w_bits == 4:
-        return ops.lut4_matmul_kmajor(a_q, a_scale, pack_kmajor(w_q),
-                                      w_scale, tag=tag)
+        return ops.lut4_matmul(a_q, a_scale, pack_kmajor(w_q), w_scale,
+                               tag=tag)
     acc = jnp.dot(a_q, w_q, preferred_element_type=jnp.int32)
     return acc.astype(jnp.float32) * a_scale * w_scale
 
@@ -128,8 +127,8 @@ def _w4a16_backend(w, x2, cfg, tag):
     w_q, w_scale = group_quantize(w, g, bits=cfg.w_bits)
     if ops.use_pallas() and cfg.w_bits == 4:
         rm = 2 * g if w_scale.ndim == 3 else 2
-        return ops.w4a16_matmul_kmajor(x2, pack_kmajor(w_q, rm), w_scale, g,
-                                       tag=tag)
+        return ops.w4a16_matmul(x2, pack_kmajor(w_q, rm), w_scale, g,
+                                tag=tag)
     wf = group_dequantize(w_q, w_scale, g)
     return jnp.dot(x2.astype(jnp.float32), wf,
                    preferred_element_type=jnp.float32)

@@ -31,7 +31,8 @@ call site, and `Runtime.quant_cfg(arch, site)` resolves it to a per-site
 QuantConfig before calling qdense.
 
 Weights are stored as float master copies (training) — serving-time packing
-is done once by `pack_params`/`quant_plan.plan_pack_tree`.
+is done once by `pack_weight_nd` (through `quant_plan.plan_pack_tree`), in
+the kernels' planar K-major layout (`kernels.packing`).
 """
 
 from __future__ import annotations
@@ -43,8 +44,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
-from repro.kernels.packing import prepack_kmajor
-from .quant import pack_int4, quant_scale, quantize, unpack_int4
+from repro.kernels.packing import pack_kmajor, unpack_kmajor
+from .quant import quant_scale, quantize
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,7 +80,7 @@ def qdense(
 ) -> jnp.ndarray:
     """Quantized dense layer. Output dtype follows x.
 
-    `w` may be a pre-packed serving weight (`{"packed": uint8 [K, N/2],
+    `w` may be a pre-packed serving weight (`{"packed": uint8 [K'/2, N],
     "scale": [1, N]}`, from `quant_plan.plan_pack_tree`): weight bytes drop
     4x vs bf16 — the paper's area argument at system level.  Packed backends:
     `w4a16_packed` (dequant + bf16 GEMM) and `w4a4_packed` (dynamic per-token
@@ -117,23 +118,14 @@ def qdense(
     return y.astype(out_dtype)
 
 
-def pack_params(w: jnp.ndarray, cfg: QuantConfig):
-    """One-time serving-side packing of a float weight into (uint8, scales)."""
-    from .quant import group_quantize
-
-    g = cfg.group_size if cfg.group_size else w.shape[0]
-    w_q, w_scale = group_quantize(w, g, bits=cfg.w_bits)
-    return pack_int4(w_q, axis=-1), w_scale
-
-
 def _packed_backend(w, x2, cfg: QuantConfig, tag: str = ""):
     """Serving path: `w` from plan_pack_tree / pack_weight_nd.
 
     On Pallas backends the GEMM runs through the kernels (W4A4: fused
-    activation-quantize; W4A16: per-channel epilogue kernel) using the
-    `packed_km` planar weight when `prepack_tree` added one (else the
-    interleaved weight is relayouted in-graph).  Elsewhere: XLA twins."""
+    activation-quantize; W4A16: per-channel epilogue kernel), which read
+    the planar K-major weight as packed.  Elsewhere: XLA twins."""
     packed, w_scale = w["packed"], w["scale"]
+    K = x2.shape[1]
     # packed weights are int4 by construction; int_sim keeps its documented
     # pure-XLA/pjit contract even on Pallas backends, and non-int4
     # activation configs keep the XLA path (a_bits honored)
@@ -143,22 +135,15 @@ def _packed_backend(w, x2, cfg: QuantConfig, tag: str = ""):
         if kernel_ok and cfg.backend == "lut4" and cfg.a_bits == 4:
             # table-lookup kernel: weights stay packed in-kernel (the tables
             # index the planar byte directly), activations quantized here
-            w_km = w.get("packed_km")
-            if w_km is None:
-                w_km = prepack_kmajor(packed)
             a_scale = quant_scale(xf, axis=1, bits=4)
             a_q = quantize(xf, a_scale, bits=4)
-            return ops.lut4_matmul_kmajor(a_q, a_scale, w_km, w_scale,
-                                          tag=tag)
+            return ops.lut4_matmul(a_q, a_scale, packed, w_scale, tag=tag)
         if kernel_ok and cfg.backend not in ("int_sim", "lut4") \
                 and cfg.a_bits == 4:
-            w_km = w.get("packed_km")
-            if w_km is None:
-                w_km = prepack_kmajor(packed)
-            return ops.int4_matmul_fused_kmajor(xf, w_km, w_scale, tag=tag)
+            return ops.int4_matmul_fused(xf, packed, w_scale, tag=tag)
         a_scale = quant_scale(xf, axis=1, bits=cfg.a_bits)
         a_q = quantize(xf, a_scale, bits=cfg.a_bits)
-        w_q = unpack_int4(packed, axis=-1)
+        w_q = unpack_kmajor(packed)[..., :K, :]
         acc = jnp.dot(a_q, w_q, preferred_element_type=jnp.int32)
         return acc.astype(jnp.float32) * a_scale * w_scale
     if cfg.backend not in ("w4a16", "w4a16_packed"):
@@ -175,15 +160,11 @@ def _packed_backend(w, x2, cfg: QuantConfig, tag: str = ""):
     # w4a16 / w4a16_packed: pack_weight_nd scales are per-output-channel
     # [1, N] or per-group [K//G, 1, N] — the group size is recovered from
     # the scale shape
-    K = x2.shape[1]
     g = K // w_scale.shape[0] if w_scale.ndim == 3 else K
     if kernel_ok:                       # via the epilogue kernel
-        w_km = w.get("packed_km")
-        if w_km is None:
-            w_km = prepack_kmajor(packed, 2 * g if w_scale.ndim == 3 else 2)
-        return ops.w4a16_matmul_kmajor(x2, w_km, w_scale, g, tag=tag)
+        return ops.w4a16_matmul(x2, packed, w_scale, g, tag=tag)
     # dequant + activation-dtype GEMM
-    wf = unpack_int4(packed, axis=-1).astype(jnp.float32)
+    wf = unpack_kmajor(packed)[..., :K, :].astype(jnp.float32)
     if w_scale.ndim == 3:
         N = wf.shape[-1]
         wf = (wf.reshape(K // g, g, N) * w_scale).reshape(K, N)
@@ -202,16 +183,20 @@ PACKABLE_NAMES = frozenset({
 
 
 def pack_weight_nd(w: jnp.ndarray, cfg: QuantConfig):
-    """Pack a [..., K, N] float weight, nibbles packed along N.  Works for
+    """Pack a [..., K, N] float weight into the kernels' planar K-major
+    layout: ``{"packed": uint8 [..., K'/2, N], "scale": ...}``.  Works for
     plain [K,N], layer-stacked [L,K,N] and stacked experts [L,E,K,N].
 
     Scales follow `cfg.group_size`: 0 (or >= K) gives per-output-channel
-    scales [..., 1, N]; a divisor G of K gives per-group scales
-    [..., K//G, 1, N] — the same grouping the on-the-fly w4a16 backend
-    computes, so grouped plans keep their numerics through a quantized
-    checkpoint."""
+    scales [..., 1, N] and K' = K rounded up to even; a divisor G of K
+    gives per-group scales [..., K//G, 1, N] and K' = K rounded up to a
+    multiple of 2G, so each planar half covers whole groups (the padding
+    rows are zero int4 values).  The grouping is the one the on-the-fly
+    w4a16 backend computes, so grouped plans keep their numerics through a
+    quantized checkpoint."""
     K, N = w.shape[-2], w.shape[-1]
     g = cfg.group_size
+    row_mult = 2
     if g and 0 < g < K:
         # same contract as the on-the-fly group_quantize: a group size that
         # doesn't divide K is a plan error, not a silent per-channel
@@ -220,47 +205,8 @@ def pack_weight_nd(w: jnp.ndarray, cfg: QuantConfig):
         wg = w.reshape(*w.shape[:-2], K // g, g, N)
         scale = quant_scale(wg, axis=-2, bits=cfg.w_bits)  # [..., K//g, 1, N]
         q = quantize(wg, scale, bits=cfg.w_bits).reshape(w.shape)
+        row_mult = 2 * g
     else:
         scale = quant_scale(w, axis=-2, bits=cfg.w_bits)   # [..., 1, N]
         q = quantize(w, scale, bits=cfg.w_bits)
-    return {"packed": pack_int4(q, axis=-1), "scale": scale}
-
-
-def prepack_tree(params):
-    """Add a `packed_km` planar K-major twin to every packed serving weight
-    (see kernels/packing.py).  One-time, serving-side: the Pallas kernels
-    then unpack with a shift/mask only — no per-step relayout.  No-op on
-    unpacked leaves; safe to call on any plan_pack_tree output.
-
-    MoE expert weights are skipped: they run through the batched einsum in
-    models/moe.py, never the 2D kernels, so a twin would just double their
-    footprint for the whole serving lifetime.
-
-    Also commits the 16x256 per-nibble product tables to device
-    (``packing.lut4_tables``), so a plan with ``lut4`` sites pays the LUT
-    build at prepack time rather than inside the first serving step."""
-    import jax
-
-    from repro.kernels.packing import lut4_tables, nmajor_to_kmajor
-
-    lut4_tables()
-
-    def maybe(path, d):
-        in_experts = any(
-            str(getattr(p, "key", "")) == "experts" for p in path)
-        if isinstance(d, dict) and "packed" in d and "packed_km" not in d \
-                and not in_experts:
-            # grouped scales [..., K//G, 1, N] need planar halves that cover
-            # whole groups (row_mult = 2G); per-channel [..., 1, N] need 2.
-            # Grouped is one rank deeper than the packed weight (holds for
-            # plain [K, N/2] and layer-stacked [R, K, N/2] alike).
-            rm = 2
-            if d["scale"].ndim == d["packed"].ndim + 1:
-                rm = 2 * (d["packed"].shape[-2] // d["scale"].shape[-3])
-            return {**d, "packed_km": nmajor_to_kmajor(d["packed"], rm)}
-        return d
-
-    return jax.tree_util.tree_map_with_path(
-        maybe, params, is_leaf=lambda n: isinstance(n, dict) and "packed" in n)
-
-
+    return {"packed": pack_kmajor(q, row_mult), "scale": scale}

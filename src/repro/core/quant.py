@@ -87,11 +87,13 @@ def group_dequantize(
 
 
 # ---------------------------------------------------------------------------
-# Nibble packing: the serving-side memory format.  Two signed int4 values per
-# uint8 byte, packed along the given axis (must have even length).  This is
-# the TPU analogue of the paper's area argument: 4-bit packing halves weight
-# bytes vs int8 and quarters them vs bf16, directly scaling the achievable
-# "multiplier array" per unit of HBM bandwidth.
+# Nibble packing along an axis: two signed int4 values per uint8 byte (the
+# axis must have even length).  This is the int4 KV cache's format
+# (models/attention.py); weights use the planar K-major layout of
+# kernels/packing.py.  Either way it is the TPU analogue of the paper's area
+# argument: 4-bit packing halves bytes vs int8 and quarters them vs bf16,
+# directly scaling the achievable "multiplier array" per unit of HBM
+# bandwidth.
 # ---------------------------------------------------------------------------
 
 def pack_int4(q: jnp.ndarray, axis: int = -1) -> jnp.ndarray:

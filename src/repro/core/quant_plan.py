@@ -449,19 +449,12 @@ def layers_per_repeat(params) -> bool:
 
 def pack_for_serving(params, cfg, rt):
     """Serving-side weight preparation under the active plan: pack the
-    sites whose backend pre-packs (legacy ``w4a4_packed``/``w4a16_packed``),
-    then add planar K-major twins on Pallas backends.  No-op when the plan
-    never pre-packs — int_sim/w4a16 sites quantize on the fly from masters
-    unless they come from a quantized checkpoint (checkpoint.restore_quantized
+    sites whose backend pre-packs (legacy ``w4a4_packed``/``w4a16_packed``)
+    into the kernels' planar K-major layout.  No-op when the plan never
+    pre-packs — int_sim/w4a16 sites quantize on the fly from masters unless
+    they come from a quantized checkpoint (checkpoint.restore_quantized
     hands back already-packed trees)."""
-    from repro.kernels import ops
-
-    from .qlinear import prepack_tree
-
     plan = active_plan(cfg, rt)
     if not (plan.backends & SERVE_PACKED):
         return params
-    params = plan_pack_tree(params, cfg, plan, backends=SERVE_PACKED)
-    if ops.use_pallas():
-        params = prepack_tree(params)
-    return params
+    return plan_pack_tree(params, cfg, plan, backends=SERVE_PACKED)

@@ -8,10 +8,10 @@ w4a16_matmul  -- weight-only int4 serving matmul, activation-dtype MXU
 paged_attention -- fused paged-KV decode attention (reads pool pages in
                  place via scalar-prefetched block tables, online softmax)
                  + tiled flash prefill, with bit-exact XLA twins
-packing       -- shared nibble pack/unpack layer (interleaved serialization
-                 vs planar K-major kernel layout) + prepacked-weight cache
+packing       -- the one int4 weight layout (planar K-major) and the
+                 shared nibble pack/unpack helpers
 autotune      -- per-shape (bm, bn, bk) tile search with an on-disk cache
-ops           -- public wrappers: layout conversion, block lookup, dispatch
+ops           -- public wrappers: block lookup, dispatch
                  (Pallas on TPU, interpreter for tests, XLA twin elsewhere)
 ref           -- pure-jnp oracles
 """

@@ -1,5 +1,5 @@
-"""Public entry points for the kernels: layout conversion, block-size
-lookup, and backend dispatch in one place.
+"""Public entry points for the kernels: block-size lookup and backend
+dispatch in one place.
 
 Dispatch (the `interpret` argument):
 
@@ -11,11 +11,11 @@ Dispatch (the `interpret` argument):
             tool, not an execution path: on CPU/GPU the XLA twin is the
             same math at full XLA speed.
 
-Weight layout: callers pass the *serialized* interleaved N-packed format
-(``core.quant.pack_int4``, [K, N//2]); the wrappers convert to the kernels'
-planar K-major layout through ``packing.prepack_kmajor`` (cached per
-concrete array).  Call sites that already hold K-major weights (qdense
-quantizing a float master inline) use the ``*_kmajor`` entry points.
+Weight layout: every GEMM takes its int4 weight in the one packed layout,
+planar K-major ``[K'/2, N]`` uint8 (``packing.pack_kmajor``; K' is K
+rounded up to whole planar halves, to a multiple of 2G for grouped
+scales).  The serving packer (``core.qlinear.pack_weight_nd``) writes that
+layout once; nothing here converts.
 
 Block sizes: resolved per GEMM shape through ``kernels.autotune`` unless
 explicitly overridden (bm=/bn=/bk= kwargs).
@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from repro.observability.metrics import global_registry
 
-from . import autotune, packing, paged_attention, ragged_attention, ref
+from . import autotune, paged_attention, ragged_attention, ref
 from .dispatch import env_interpret
 from .int4_matmul import int4_matmul as _int4_matmul
 from .int4_matmul import int4_matmul_fused as _int4_matmul_fused
@@ -92,74 +92,40 @@ def mul4(a_q, b_q, strategy: str = "onehot",
 def int4_matmul(a_q, a_scale, w_packed, w_scale,
                 interpret: Optional[bool] = None, tag: str = "",
                 bm=None, bn=None, bk=None):
-    """W4A4 matmul with fused dequant epilogue.
-
-    `w_packed`: serialized interleaved [K, N//2] (``core.quant.pack_int4``).
-    """
+    """W4A4 matmul with fused dequant epilogue; `w_packed` planar K-major
+    [ceil(K/2), N] uint8."""
     m = _mode(interpret)
+    _count_dispatch("int4_matmul", m)
     if m == _XLA:
-        _count_dispatch("int4_matmul", m)
         return ref.int4_matmul_ref(a_q, a_scale, w_packed, w_scale)
-    return int4_matmul_kmajor(
-        a_q, a_scale, packing.prepack_kmajor(w_packed), w_scale,
-        interpret=m == _INTERPRET, tag=tag, bm=bm, bn=bn, bk=bk)
-
-
-def int4_matmul_kmajor(a_q, a_scale, w_kmajor, w_scale,
-                       interpret: Optional[bool] = None, tag: str = "",
-                       bm=None, bn=None, bk=None):
-    """W4A4 matmul on planar K-major weights ([ceil(K/2), N] uint8)."""
-    m = _mode(interpret)
-    _count_dispatch("int4_matmul_kmajor", m)
-    if m == _XLA:
-        w_q = packing.unpack_kmajor(w_kmajor)[: a_q.shape[1]]
-        acc = jnp.dot(a_q, w_q, preferred_element_type=jnp.int32)
-        return acc.astype(jnp.float32) * a_scale * w_scale
     M, K = a_q.shape
-    b = _blocks("int4_matmul", M, K, w_kmajor.shape[1], a_q.dtype, 0, tag,
+    b = _blocks("int4_matmul", M, K, w_packed.shape[1], a_q.dtype, 0, tag,
                 {"bm": bm, "bn": bn, "bk": bk})
-    return _int4_matmul(a_q, a_scale, w_kmajor, w_scale,
+    return _int4_matmul(a_q, a_scale, w_packed, w_scale,
                         interpret=m == _INTERPRET, **b)
 
 
 def lut4_matmul(a_q, a_scale, w_packed, w_scale,
                 interpret: Optional[bool] = None, tag: str = "",
                 bm=None, bn=None, bk=None):
-    """Table-lookup W4A4 matmul (`kernels/lut4_matmul.py`).
-
-    `w_packed`: serialized interleaved [K, N//2] (``core.quant.pack_int4``).
-    """
-    m = _mode(interpret)
-    if m == _XLA:
-        _count_dispatch("lut4_matmul", m)
-        # XLA twin: the exact product table is rank-1 (T[a, w] = a*w), so
-        # the lookup-sum collapses to the int8 dot — bit-identical because
-        # integer accumulation is exact (see ref.lut4_matmul_ref).
-        return ref.int4_matmul_ref(a_q, a_scale, w_packed, w_scale)
-    return lut4_matmul_kmajor(
-        a_q, a_scale, packing.prepack_kmajor(w_packed), w_scale,
-        interpret=m == _INTERPRET, tag=tag, bm=bm, bn=bn, bk=bk)
-
-
-def lut4_matmul_kmajor(a_q, a_scale, w_kmajor, w_scale,
-                       interpret: Optional[bool] = None, tag: str = "",
-                       bm=None, bn=None, bk=None):
-    """Table-lookup W4A4 matmul on planar K-major weights.
+    """Table-lookup W4A4 matmul (`kernels/lut4_matmul.py`); `w_packed`
+    planar K-major [ceil(K/2), N] uint8.
 
     Block sizes resolve through ``kernels.autotune`` op ``gemm.lut4``, which
     carries its own candidate set (cost scales with the per-tile lookup loop
     over bk/2 packed rows, so it favors smaller bk than the MXU kernels).
     """
     m = _mode(interpret)
-    _count_dispatch("lut4_matmul_kmajor", m)
+    _count_dispatch("lut4_matmul", m)
     if m == _XLA:
-        w_q = packing.unpack_kmajor(w_kmajor)[: a_q.shape[1]]
-        acc = jnp.dot(a_q, w_q, preferred_element_type=jnp.int32)
-        return acc.astype(jnp.float32) * a_scale * w_scale
+        # XLA twin: the exact product table is rank-1 (T[a, w] = a*w), so
+        # the lookup-sum collapses to the int8 dot — bit-identical because
+        # integer accumulation is exact (see ref.lut4_matmul_ref).
+        return ref.int4_matmul_ref(a_q, a_scale, w_packed, w_scale)
     M, K = a_q.shape
-    b = _blocks("gemm.lut4", M, K, w_kmajor.shape[1], a_q.dtype, 0, tag,
+    b = _blocks("gemm.lut4", M, K, w_packed.shape[1], a_q.dtype, 0, tag,
                 {"bm": bm, "bn": bn, "bk": bk})
-    return _lut4_matmul(a_q, a_scale, w_kmajor, w_scale,
+    return _lut4_matmul(a_q, a_scale, w_packed, w_scale,
                         interpret=m == _INTERPRET, **b)
 
 
@@ -167,75 +133,34 @@ def int4_matmul_fused(x, w_packed, w_scale,
                       interpret: Optional[bool] = None, tag: str = "",
                       bm=None, bn=None, bk=None):
     """Fused activation-quantize W4A4: float x in, quantize + matmul +
-    dequant in one pallas_call (A4 activations never round-trip HBM)."""
+    dequant in one pallas_call (A4 activations never round-trip HBM);
+    `w_packed` planar K-major [ceil(K/2), N] uint8."""
     m = _mode(interpret)
+    _count_dispatch("int4_matmul_fused", m)
     if m == _XLA:
-        _count_dispatch("int4_matmul_fused", m)
         return ref.int4_matmul_fused_ref(x, w_packed, w_scale)
-    return int4_matmul_fused_kmajor(
-        x, packing.prepack_kmajor(w_packed), w_scale,
-        interpret=m == _INTERPRET, tag=tag, bm=bm, bn=bn, bk=bk)
-
-
-def int4_matmul_fused_kmajor(x, w_kmajor, w_scale,
-                             interpret: Optional[bool] = None, tag: str = "",
-                             bm=None, bn=None, bk=None):
-    m = _mode(interpret)
-    _count_dispatch("int4_matmul_fused_kmajor", m)
-    if m == _XLA:
-        # kmajor-holding caller on a non-Pallas backend (e.g. qdense traced
-        # on CPU): same math through the XLA twin
-        a_q, a_scale = _quantize_rows(x)
-        w_q = packing.unpack_kmajor(w_kmajor)[: x.shape[1]]
-        acc = jnp.dot(a_q, w_q, preferred_element_type=jnp.int32)
-        return acc.astype(jnp.float32) * a_scale * w_scale
     M, K = x.shape
-    b = _blocks("int4_matmul_fused", M, K, w_kmajor.shape[1], x.dtype, 0,
+    b = _blocks("int4_matmul_fused", M, K, w_packed.shape[1], x.dtype, 0,
                 tag, {"bm": bm, "bn": bn, "bk": bk})
-    return _int4_matmul_fused(x, w_kmajor, w_scale,
+    return _int4_matmul_fused(x, w_packed, w_scale,
                               interpret=m == _INTERPRET, **b)
 
 
 def w4a16_matmul(x, w_packed, w_scale, group_size: int,
                  interpret: Optional[bool] = None, tag: str = "",
                  bm=None, bn=None, bk=None):
-    """Weight-only int4 matmul with per-group dequant.
-
-    `w_packed`: serialized interleaved [K, N//2] (``core.quant.pack_int4``).
-    """
+    """Weight-only int4 matmul with per-group dequant; `w_packed` planar
+    K-major [K'/2, N] uint8, K' a multiple of 2·group_size when the scales
+    are grouped [K/G, 1, N] (each planar half covers whole groups)."""
     m = _mode(interpret)
+    _count_dispatch("w4a16_matmul", m)
     if m == _XLA:
-        _count_dispatch("w4a16_matmul", m)
         return ref.w4a16_matmul_ref(x, w_packed, w_scale, group_size)
-    # grouped scales: align K to 2*G at repack time so each planar half
-    # covers whole groups (padding rows are zero int4 values)
-    row_mult = 2 * group_size if w_scale.ndim == 3 else 2
-    return w4a16_matmul_kmajor(
-        x, packing.prepack_kmajor(w_packed, row_mult), w_scale, group_size,
-        interpret=m == _INTERPRET, tag=tag, bm=bm, bn=bn, bk=bk)
-
-
-def w4a16_matmul_kmajor(x, w_kmajor, w_scale, group_size: int,
-                        interpret: Optional[bool] = None, tag: str = "",
-                        bm=None, bn=None, bk=None):
-    """W4A16 matmul on planar K-major weights ([ceil(K/2), N] uint8)."""
-    m = _mode(interpret)
-    _count_dispatch("w4a16_matmul_kmajor", m)
-    if m == _XLA:
-        w_q = packing.unpack_kmajor(w_kmajor)[: x.shape[1]]
-        K, N = w_q.shape
-        if w_scale.ndim == 2:
-            w = w_q.astype(jnp.float32) * w_scale
-        else:
-            wg = w_q.reshape(K // group_size, group_size, N)
-            w = (wg.astype(jnp.float32) * w_scale).reshape(K, N)
-        return jnp.dot(x.astype(jnp.float32), w,
-                       preferred_element_type=jnp.float32)
     M, K = x.shape
     g = 0 if w_scale.ndim == 2 else group_size
-    b = _blocks("w4a16_matmul", M, K, w_kmajor.shape[1], x.dtype, g, tag,
+    b = _blocks("w4a16_matmul", M, K, w_packed.shape[1], x.dtype, g, tag,
                 {"bm": bm, "bn": bn, "bk": bk})
-    return _w4a16_matmul(x, w_kmajor, w_scale, group_size,
+    return _w4a16_matmul(x, w_packed, w_scale, group_size,
                          interpret=m == _INTERPRET, **b)
 
 
@@ -326,25 +251,3 @@ def flash_prefill(q, k, v, q_positions, k_positions, *, window: int = 0,
     return paged_attention.flash_prefill(
         q, k, v, q_positions, k_positions, window=window,
         bq=b["bm"], bk=b["bk"], bkv=b["bn"], interpret=m == _INTERPRET)
-
-
-def _quantize_rows(x):
-    from repro.core.quant import quant_scale, quantize
-
-    x32 = x.astype(jnp.float32)
-    a_scale = quant_scale(x32, axis=1, bits=4)
-    return quantize(x32, a_scale, bits=4), a_scale
-
-
-# --- pure-XLA equivalents (identical math; used in multi-device dry-runs) ---
-
-def xla_int4_matmul(a_q, a_scale, w_packed, w_scale):
-    return ref.int4_matmul_ref(a_q, a_scale, w_packed, w_scale)
-
-
-def xla_w4a16_matmul(x, w_packed, w_scale, group_size: int):
-    return ref.w4a16_matmul_ref(x, w_packed, w_scale, group_size)
-
-
-def xla_mul4(a_q, b_q):
-    return ref.mul4_ref(a_q, b_q)

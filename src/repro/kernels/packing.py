@@ -1,33 +1,33 @@
-"""Shared nibble pack/unpack layer for the quantized-GEMM kernels.
+"""The int4 weight layout of the quantized GEMMs, and its nibble helpers.
 
-Two storage layouts for int4 tensors (two values per uint8 byte):
+One layout, planar K-major (``pack_kmajor``): a ``[..., K, N]`` int4
+weight is stored as ``[..., K'/2, N]`` uint8, where contraction rows ``k``
+and ``k + K'/2`` share a byte (K' is K rounded up to whole planar halves).
+The packer (``core.qlinear.pack_weight_nd``) writes it once, quantized
+checkpoints store it, and every GEMM entry point in ``kernels.ops`` reads
+it as is.
 
-  * interleaved N-packed (``core.quant.pack_int4``): adjacent *columns*
-    share a byte.  This is the serialization format (quantized checkpoints,
-    ``plan_pack_tree`` serving weights) — compact and axis-generic, but the
-    in-kernel unpack needs a stack+reshape interleave, which Mosaic lowers
-    as a lane-axis relayout on the matmul critical path.
-  * planar K-major (``pack_kmajor``): contraction rows ``k`` and
-    ``k + K/2`` share a byte.  The low nibbles of a ``[K/2, N]`` tile *are*
-    rows ``[0, K/2)`` and the high nibbles *are* rows ``[K/2, K)`` — the
-    in-kernel unpack is a shift/mask with **no relayout**, and the two
-    planar halves feed two MXU dots that accumulate into the same tile.
+Why planar: the low nibbles of a ``[K/2, N]`` tile *are* rows
+``[0, K/2)`` and the high nibbles *are* rows ``[K/2, K)``, so the
+in-kernel unpack is a shift/mask with **no relayout**, and the two planar
+halves feed two MXU dots that accumulate into the same tile.  Packing two
+adjacent output columns per byte instead would need a stack+reshape
+interleave, which Mosaic lowers as a lane-axis relayout on the matmul's
+critical path.  The N axis stays whole, so a column-sharded weight shards
+its packed bytes the same way.
 
-``prepack_kmajor`` converts serialized weights to the kernel layout once
-per concrete array (cache keyed by ``id()``, weakref-evicted), so a serving
-loop that calls the kernels every step with the same weight pays the
-relayout exactly once instead of per call.
+(The int4 KV cache packs along its last axis with ``core.quant.pack_int4``;
+that is a cache format, not a weight layout.)
 
 This module is self-contained (no repro imports): it is the single home of
-the sign-extend / shift-mask helpers that used to be copy-pasted between
-``int4_matmul.py`` and ``w4a16_matmul.py``.
+the sign-extend / shift-mask helpers shared by ``int4_matmul.py``,
+``w4a16_matmul.py`` and ``lut4_matmul.py``.
 """
 
 from __future__ import annotations
 
 import functools
-import weakref
-from typing import Dict, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,7 +51,8 @@ def unpack_nibbles(p: jnp.ndarray, dtype=jnp.int8
     the same shape as `p`.
 
     The shift primitive shared by every kernel; what the nibbles *mean*
-    (adjacent columns vs planar row halves) is the caller's layout contract.
+    (planar row halves of a weight, adjacent lanes of a KV row) is the
+    caller's layout contract.
     Sign extension runs in int32 — shift the nibble to the top of the word,
     then shift it back arithmetically — because the TPU vector unit has no
     int8 arithmetic (Mosaic refuses an int8 subtract).  The one cast at the
@@ -59,13 +60,6 @@ def unpack_nibbles(p: jnp.ndarray, dtype=jnp.int8
     the activation dtype for W4A16)."""
     w = p.astype(jnp.int32)
     return ((w << 28) >> 28).astype(dtype), ((w << 24) >> 28).astype(dtype)
-
-
-def unpack_interleaved(p: jnp.ndarray) -> jnp.ndarray:
-    """Interleaved N-packed [..., K, N//2] uint8 -> [..., K, N] int8."""
-    lo, hi = unpack_nibbles(p)
-    return jnp.stack([lo, hi], axis=-1).reshape(
-        *p.shape[:-1], p.shape[-1] * 2)
 
 
 # ------------------------------------------------------- planar K-major ----
@@ -89,13 +83,6 @@ def unpack_kmajor(p: jnp.ndarray) -> jnp.ndarray:
     """Inverse of pack_kmajor: [..., K/2, N] uint8 -> [..., K, N] int8."""
     lo, hi = unpack_nibbles(p)
     return jnp.concatenate([lo, hi], axis=-2)
-
-
-@functools.partial(jax.jit, static_argnames="row_mult")
-def nmajor_to_kmajor(w_packed: jnp.ndarray, row_mult: int = 2) -> jnp.ndarray:
-    """Serialized interleaved [..., K, N//2] -> kernel planar [..., K'/2, N]
-    (K' = K rounded up to a multiple of `row_mult`, at least even)."""
-    return pack_kmajor(unpack_interleaved(w_packed), row_mult)
 
 
 # ------------------------------------------- per-nibble product tables -----
@@ -124,8 +111,9 @@ def nibble_product_tables() -> Tuple[np.ndarray, np.ndarray]:
 
 @functools.lru_cache(maxsize=None)
 def lut4_tables() -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Device-resident ``nibble_product_tables()`` (committed once, cached
-    for the life of the process — the \"prepack\" of the LUT backend).
+    """Device-resident ``nibble_product_tables()``, committed on first use
+    (when the first ``lut4`` GEMM is traced) and cached for the life of the
+    process.
 
     ``ensure_compile_time_eval`` keeps the cached values concrete even when
     the first call happens under an outer trace (a tracer must never be
@@ -147,43 +135,6 @@ def table_take(table: jnp.ndarray, rows: jnp.ndarray,
     """
     sel = jnp.take(table, rows, axis=0)          # [m, 256]
     return jnp.take_along_axis(sel, lanes, axis=-1)
-
-
-# ------------------------------------------------- prepacked-weight cache --
-# (id(src), row_mult) -> (weakref to src, kmajor-packed array).  The weakref
-# callback evicts the entry when the source weight is garbage-collected, so
-# the cache never outlives (or pins) the arrays it mirrors.
-_PREPACKED: Dict[Tuple[int, int], Tuple[weakref.ref, jnp.ndarray]] = {}
-
-
-def prepack_kmajor(w_packed: jnp.ndarray, row_mult: int = 2) -> jnp.ndarray:
-    """`nmajor_to_kmajor`, cached by array identity for concrete arrays.
-
-    Tracers (calls under an outer jit) convert inline — XLA sees the repack
-    as part of the traced graph and CSEs/hoists what it can; concrete
-    arrays (eager serving / benchmarks) repack exactly once per weight.
-    """
-    if isinstance(w_packed, jax.core.Tracer):
-        return nmajor_to_kmajor(w_packed, row_mult)
-    key = (id(w_packed), row_mult)
-    hit = _PREPACKED.get(key)
-    if hit is not None and hit[0]() is w_packed:
-        return hit[1]
-    out = jax.block_until_ready(nmajor_to_kmajor(w_packed, row_mult))
-    try:
-        ref = weakref.ref(w_packed, lambda _r, _k=key: _PREPACKED.pop(_k, None))
-    except TypeError:                      # not weakref-able: skip caching
-        return out
-    _PREPACKED[key] = (ref, out)
-    return out
-
-
-def prepack_cache_size() -> int:
-    return len(_PREPACKED)
-
-
-def clear_prepack_cache() -> None:
-    _PREPACKED.clear()
 
 
 # ------------------------------------------------------- tile flattening ---

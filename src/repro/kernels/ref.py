@@ -10,8 +10,7 @@ from __future__ import annotations
 import numpy as np
 import jax.numpy as jnp
 
-from repro.core.quant import unpack_int4
-from .packing import nibble_product_tables, nmajor_to_kmajor, pad_to
+from .packing import nibble_product_tables, pad_to, unpack_kmajor
 
 
 def make_product_lut() -> np.ndarray:
@@ -35,11 +34,11 @@ def mul4_ref(a_q: jnp.ndarray, b_q: jnp.ndarray) -> jnp.ndarray:
 def int4_matmul_ref(
     a_q: jnp.ndarray,          # [M, K] int8 holding int4 values
     a_scale: jnp.ndarray,      # [M, 1] f32
-    w_packed: jnp.ndarray,     # [K, N//2] uint8 (two int4 per byte, packed on N)
+    w_packed: jnp.ndarray,     # [ceil(K/2), N] uint8, planar K-major
     w_scale: jnp.ndarray,      # [1, N] f32
 ) -> jnp.ndarray:
     """W4A4 matmul: integer dot + per-row/per-col scale epilogue -> f32."""
-    w_q = unpack_int4(w_packed, axis=-1)                     # [K, N] int8
+    w_q = unpack_kmajor(w_packed)[: a_q.shape[1]]            # [K, N] int8
     acc = jnp.dot(
         a_q.astype(jnp.int8), w_q, preferred_element_type=jnp.int32
     )
@@ -49,7 +48,7 @@ def int4_matmul_ref(
 def lut4_matmul_ref(
     a_q: jnp.ndarray,          # [M, K] int8 holding int4 values
     a_scale: jnp.ndarray,      # [M, 1] f32
-    w_packed: jnp.ndarray,     # [K, N//2] uint8 (two int4 per byte, packed on N)
+    w_packed: jnp.ndarray,     # [ceil(K/2), N] uint8, planar K-major
     w_scale: jnp.ndarray,      # [1, N] f32
 ) -> jnp.ndarray:
     """Table-formulation W4A4 oracle: every partial product is *read* from
@@ -61,7 +60,7 @@ def lut4_matmul_ref(
     what makes the XLA twin of the `lut4` backend an int8 dot.
     """
     t_lo, t_hi = (jnp.asarray(t) for t in nibble_product_tables())
-    wb = nmajor_to_kmajor(w_packed).astype(jnp.int32)        # [Kh, N]
+    wb = w_packed.astype(jnp.int32)                          # [Kh, N]
     kh = wb.shape[0]
     a = pad_to(a_q, 2 * kh, 1)
     u_lo = (a[:, :kh] & 0xF).astype(jnp.int32)               # [M, Kh]
@@ -74,7 +73,7 @@ def lut4_matmul_ref(
 
 def int4_matmul_fused_ref(
     x: jnp.ndarray,            # [M, K] float activations
-    w_packed: jnp.ndarray,     # [K, N//2] uint8 (two int4 per byte, packed on N)
+    w_packed: jnp.ndarray,     # [ceil(K/2), N] uint8, planar K-major
     w_scale: jnp.ndarray,      # [1, N] f32
 ) -> jnp.ndarray:
     """Oracle for the fused activation-quantize A4 path: dynamic per-row
@@ -89,12 +88,12 @@ def int4_matmul_fused_ref(
 
 def w4a16_matmul_ref(
     x: jnp.ndarray,            # [M, K] bf16/f32
-    w_packed: jnp.ndarray,     # [K, N//2] uint8
+    w_packed: jnp.ndarray,     # [K'/2, N] uint8, planar K-major
     w_scale: jnp.ndarray,      # [K//G, 1, N] f32 (or [1, N] per-channel)
     group_size: int,
 ) -> jnp.ndarray:
     """Weight-only int4 serving matmul: dequantize then bf16 GEMM -> f32."""
-    w_q = unpack_int4(w_packed, axis=-1)                     # [K, N] int8
+    w_q = unpack_kmajor(w_packed)[: x.shape[1]]              # [K, N] int8
     K, N = w_q.shape
     if w_scale.ndim == 2:
         w = w_q.astype(jnp.float32) * w_scale

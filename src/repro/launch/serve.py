@@ -79,8 +79,6 @@ def _quantized_ckpt_report(cfg, rt, ckpt_dir, seed):
     from repro.core.quant_plan import (
         CKPT_PACKED, active_plan, plan_pack_tree,
     )
-    from repro.kernels import ops
-    from repro.core.qlinear import prepack_tree
     from repro.models import forward, init_model
 
     masters = init_model(jax.random.PRNGKey(seed), cfg)
@@ -115,9 +113,6 @@ def _quantized_ckpt_report(cfg, rt, ckpt_dir, seed):
         "load_s": round(load_s, 3),
         "float_save_s": round(float_save_s, 3),
     }
-    if ops.use_pallas():
-        restored = prepack_tree(restored)
-        reference = prepack_tree(reference)
     return restored, reference, report
 
 

@@ -54,24 +54,25 @@ def _expert_ffn(buf, experts, cfg: ArchConfig, rt: Runtime, site: str = "moe"):
     batched einsum, so per-expert backends are not addressable."""
     qc = rt.quant_cfg(cfg, join_site(site, "experts"))
 
-    def dense(w):
+    def dense(w, k: int):
         if isinstance(w, dict):                # packed int4 serving weights
-            from repro.core.quant import unpack_int4
+            from repro.kernels.packing import unpack_kmajor
 
-            q = unpack_int4(w["packed"], axis=-1)
+            q = unpack_kmajor(w["packed"])[..., :k, :]     # [El, k, N]
             return (q.astype(jnp.float32) * w["scale"]).astype(buf.dtype)
         if qc.backend == "fake_quant":
             # per-output-channel fake-quant along each expert's reduction dim
             w = fake_quant(w, axis=1, bits=qc.w_bits)
         return w.astype(buf.dtype)
 
-    h = jnp.einsum("ecd,edf->ecf", buf, dense(experts["w_in"]))
+    D = buf.shape[-1]
+    h = jnp.einsum("ecd,edf->ecf", buf, dense(experts["w_in"], D))
     if "w_gate" in experts:
-        g = jnp.einsum("ecd,edf->ecf", buf, dense(experts["w_gate"]))
+        g = jnp.einsum("ecd,edf->ecf", buf, dense(experts["w_gate"], D))
         h = jax.nn.silu(g) * h
     else:
         h = jax.nn.gelu(h)
-    return jnp.einsum("ecf,efd->ecd", h, dense(experts["w_out"]))
+    return jnp.einsum("ecf,efd->ecd", h, dense(experts["w_out"], h.shape[-1]))
 
 
 def _moe_shard(xf, router_w, experts, *, e_start, n_local, cfg, rt, axis=None,
@@ -147,8 +148,10 @@ def apply_moe(
 
         # Per-leaf spec + FSDP-gather axis.  Expert weights are E-sharded on
         # `model` and (when divisible) sharded on `data` along the gatherable
-        # axis (F for w_in/w_gate and their scales; F for w_out.packed; the
-        # tiny w_out.scale [E,1,D] stays replicated).
+        # axis: F for w_in/w_gate ([E,D,F]; packed [E,D/2,F]) and their
+        # scales [E,1,F]; axis 1 for w_out ([E,F,D]; packed [E,F/2,D], whose
+        # packed rows are gathered whole before the unpack).  The tiny
+        # w_out.scale [E,1,D] stays replicated.
         def leaf_plan(name, leaf):
             ax = 1 if name == "w_out" else 2
             if leaf.ndim == 3 and leaf.shape[ax] % dp == 0 and leaf.shape[ax] > 1:

@@ -102,10 +102,7 @@ def build_params(cfg: ArchConfig, rt: Runtime, seed: int = 0):
 
     Packing is per-site: the plan decides which call sites pre-pack into
     the int4 nibble format (legacy uniform `--quant w4a4_packed` maps to a
-    uniform plan).  On Pallas backends packed weights also get their planar
-    K-major twin (`prepack_tree`) so the kernels' nibble unpack is
-    shift/mask only — the relayout is paid once here, never inside a
-    serving step.  To serve from a quantized checkpoint instead, pass
+    uniform plan).  To serve from a quantized checkpoint instead, pass
     `checkpoint.restore_quantized(dir, cfg=cfg, rt=rt)[0]` as `params` to
     the engine — the cfg/rt arguments assert the runtime's active plan
     matches the plan the checkpoint was saved with."""

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import autotune, ops
-from repro.core.quant import pack_int4
+from repro.kernels.packing import pack_kmajor
 
 
 @pytest.fixture(autouse=True)
@@ -196,7 +196,7 @@ def test_tuned_blocks_flow_into_kernel_call(isolated_cache):
     a_s = jnp.ones((M, 1), jnp.float32)
     wq = jnp.asarray(rng.integers(-8, 8, (K, N), np.int8))
     w_s = jnp.ones((1, N), jnp.float32)
-    wp = pack_int4(wq, -1)
+    wp = pack_kmajor(wq)
 
     autotune._CACHE[autotune.cache_key("int4_matmul", M, K, N, "int8")] = \
         {"bm": 8, "bn": 32, "bk": 64, "us": 1.0}

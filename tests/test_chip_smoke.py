@@ -64,7 +64,7 @@ def test_served_route_off_the_kernels_fails_the_dispatch_check(small):
                          rt=cs.reference_runtime(small))
     cs.check_serve(res)
     with pytest.raises(AssertionError,
-                       match="never dispatched.*int4_matmul_fused_kmajor"):
+                       match="never dispatched.*int4_matmul_fused"):
         cs.check_dispatch(res["dispatch"], cs.SERVED_OPS["bucketed"],
                           mode="interpret")
 

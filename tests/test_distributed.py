@@ -87,6 +87,39 @@ print("OK")
 """)
 
 
+def test_moe_shard_map_packed_experts_match_local():
+    """Packed int4 experts (planar K-major: w_in [E, D/2, F], w_out
+    [E, F/2, D]) through shard_map, FSDP-gathered along their packed rows,
+    serve the same output as the local path."""
+    run_fake_devices(r"""
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs import get_config, Runtime
+from repro.core.qlinear import QuantConfig, pack_weight_nd
+from repro.distributed.sharding import mesh_context
+from repro.launch.mesh import make_mesh
+from repro.models.moe import apply_moe, init_moe
+
+cfg = get_config("arctic-480b").reduced(
+    n_experts=8, d_model=64, d_ff_expert=64, capacity_factor=64.0)
+rt = Runtime(quant_backend="w4a4_packed", compute_dtype="float32")
+p = init_moe(jax.random.PRNGKey(0), cfg)
+qc = QuantConfig(backend="w4a4_packed")
+p["experts"] = {k: pack_weight_nd(w, qc) for k, w in p["experts"].items()}
+assert p["experts"]["w_out"]["packed"].shape == (8, 32, 64)
+x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, 64))
+
+y_local, _ = apply_moe(p, x, cfg, rt)
+
+mesh = make_mesh((2, 4), ("data", "model"))
+with mesh_context(mesh):
+    fn = jax.jit(lambda p, x: apply_moe(p, x, cfg, rt))
+    y_mesh, _ = fn(p, x)
+np.testing.assert_allclose(np.asarray(y_mesh), np.asarray(y_local),
+                           rtol=2e-4, atol=2e-5)
+print("OK")
+""")
+
+
 def test_moe_shard_map_gradients_match_local():
     run_fake_devices(r"""
 import jax, jax.numpy as jnp, numpy as np

@@ -2,8 +2,6 @@
 (interpret mode executes the kernel bodies on CPU; `interpret=None` rows also
 check the ops-level dispatch, which routes to the XLA twins off-TPU)."""
 
-import gc
-
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -17,6 +15,12 @@ RNG = np.random.default_rng(1234)
 
 def rand_int4(shape):
     return jnp.asarray(RNG.integers(-8, 8, size=shape, dtype=np.int8))
+
+
+def pack_grouped(qg, sg, G):
+    """Planar K-major weight for `sg`: whole groups per planar half when
+    the scales are grouped [K/G, 1, N], even K when per-channel [1, N]."""
+    return packing.pack_kmajor(qg, row_mult=2 * G if sg.ndim == 3 else 2)
 
 
 # ---------------------------------------------------------------- lut_mul4 --
@@ -61,7 +65,7 @@ def _int4_case(M, K, N):
     a_scale = jnp.asarray(RNG.random((M, 1), dtype=np.float32) + 0.05)
     wq = rand_int4((K, N if N % 2 == 0 else N + 1))
     w_scale = jnp.asarray(RNG.random((1, wq.shape[1]), dtype=np.float32) + 0.05)
-    return aq, a_scale, pack_int4(wq, axis=-1), w_scale
+    return aq, a_scale, packing.pack_kmajor(wq), w_scale
 
 
 @pytest.mark.parametrize(
@@ -92,7 +96,8 @@ def test_int4_matmul_integer_core_is_exact():
     M = K = N = 128
     aq, wq = rand_int4((M, K)), rand_int4((K, N))
     ones_m, ones_n = jnp.ones((M, 1), jnp.float32), jnp.ones((1, N), jnp.float32)
-    got = ops.int4_matmul(aq, ones_m, pack_int4(wq, -1), ones_n, interpret=True)
+    got = ops.int4_matmul(aq, ones_m, packing.pack_kmajor(wq), ones_n,
+                          interpret=True)
     exp = jnp.dot(aq.astype(jnp.int32), wq.astype(jnp.int32))
     np.testing.assert_array_equal(np.asarray(got).astype(np.int64),
                                   np.asarray(exp).astype(np.int64))
@@ -111,7 +116,7 @@ def test_int4_matmul_fused_quantize(M, K, N, dtype):
     x = jnp.asarray(RNG.standard_normal((M, K)).astype(np.float32)).astype(dtype)
     wq = rand_int4((K, N + N % 2))
     w_scale = jnp.asarray(RNG.random((1, wq.shape[1]), dtype=np.float32) + 0.05)
-    wp = pack_int4(wq, axis=-1)
+    wp = packing.pack_kmajor(wq)
     got = np.asarray(ops.int4_matmul_fused(x, wp, w_scale, interpret=True))
     exp = np.asarray(ref.int4_matmul_fused_ref(x, wp, w_scale))
 
@@ -132,7 +137,7 @@ def test_int4_matmul_fused_quantize(M, K, N, dtype):
 def test_w4a16_sweep(M, K, N, G, dtype):
     w = jnp.asarray(RNG.standard_normal((K, N + N % 2)).astype(np.float32))
     qg, sg = group_quantize(w, G)
-    wp = pack_int4(qg, axis=-1)
+    wp = pack_grouped(qg, sg, G)
     x = jnp.asarray(RNG.standard_normal((M, K)).astype(np.float32)).astype(dtype)
     got = ops.w4a16_matmul(x, wp, sg, G, interpret=True, bm=128, bn=128, bk=128)
     exp = ref.w4a16_matmul_ref(x, wp, sg, G)
@@ -148,7 +153,7 @@ def test_w4a16_per_channel_odd_shapes(M, K, N, blocks):
     w = jnp.asarray(RNG.standard_normal((K, N + N % 2)).astype(np.float32))
     qg, sg = group_quantize(w, K)            # per-channel: scale [1, N]
     assert sg.ndim == 2
-    wp = pack_int4(qg, axis=-1)
+    wp = pack_grouped(qg, sg, K)
     x = jnp.asarray(RNG.standard_normal((M, K)).astype(np.float32))
     got = ops.w4a16_matmul(x, wp, sg, K, interpret=True, **blocks)
     exp = ref.w4a16_matmul_ref(x, wp, sg, K)
@@ -162,7 +167,8 @@ def test_w4a16_grouped_odd_group_count():
     w = jnp.asarray(RNG.standard_normal((K, N)).astype(np.float32))
     qg, sg = group_quantize(w, G)
     assert sg.shape[0] == 3
-    wp = pack_int4(qg, axis=-1)
+    wp = pack_grouped(qg, sg, G)                # K 192 -> 256: 2 x 2 groups
+    assert wp.shape == (128, N)
     x = jnp.asarray(RNG.standard_normal((M, K)).astype(np.float32))
     for blocks in ({}, dict(bm=16, bn=32, bk=128)):
         got = ops.w4a16_matmul(x, wp, sg, G, interpret=True, **blocks)
@@ -179,7 +185,7 @@ def test_w4a16_both_group_size_paths_nondefault_blocks(G):
     g = G if G else K
     qg, sg = group_quantize(w, g)
     assert sg.ndim == (3 if G else 2)
-    wp = pack_int4(qg, axis=-1)
+    wp = pack_grouped(qg, sg, g)
     x = jnp.asarray(RNG.standard_normal((M, K)).astype(np.float32))
     got = ops.w4a16_matmul(x, wp, sg, g, interpret=True, bm=32, bn=32, bk=256)
     exp = ref.w4a16_matmul_ref(x, wp, sg, g)
@@ -230,21 +236,3 @@ def test_kmajor_row_mult_alignment():
     back = packing.unpack_kmajor(km)
     np.testing.assert_array_equal(np.asarray(back[:96]), np.asarray(q))
     assert not np.asarray(back[96:]).any()             # zero int4 padding
-
-
-def test_nmajor_to_kmajor_matches_direct_pack():
-    q = rand_int4((64, 48))
-    np.testing.assert_array_equal(
-        np.asarray(packing.nmajor_to_kmajor(pack_int4(q, -1))),
-        np.asarray(packing.pack_kmajor(q)))
-
-
-def test_prepack_cache_hits_and_weakref_eviction():
-    packing.clear_prepack_cache()
-    wp = pack_int4(rand_int4((64, 48)), -1)
-    first = packing.prepack_kmajor(wp)
-    assert packing.prepack_kmajor(wp) is first         # cache hit
-    assert packing.prepack_cache_size() == 1
-    del wp
-    gc.collect()
-    assert packing.prepack_cache_size() == 0           # weakref eviction

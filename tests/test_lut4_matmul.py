@@ -10,13 +10,12 @@ import pytest
 from repro.checkpoint import restore_quantized, save_quantized
 from repro.configs import Runtime, ServingConfig, get_config
 from repro.core.qlinear import QuantConfig, qdense
-from repro.core.quant import pack_int4
 from repro.core.quant_plan import CKPT_PACKED, get_plan, plan_pack_tree
 from repro.kernels import autotune, ops, ref
 from repro.kernels.lut4_matmul import lut4_matmul
 from repro.kernels.packing import (
     nibble_product_tables,
-    nmajor_to_kmajor,
+    pack_kmajor,
     table_take,
 )
 from repro.models import forward, init_model
@@ -32,7 +31,7 @@ def _rand_case(M, K, N, seed=0):
     w_q = jnp.asarray(rng.integers(-8, 8, (K, N)), jnp.int8)
     a_s = jnp.asarray(rng.uniform(0.01, 1.0, (M, 1)), jnp.float32)
     w_s = jnp.asarray(rng.uniform(0.01, 1.0, (1, N)), jnp.float32)
-    return a_q, a_s, pack_int4(w_q, axis=-1), w_s
+    return a_q, a_s, pack_kmajor(w_q), w_s
 
 
 # --------------------------------------------------------------- tables ----
@@ -84,7 +83,7 @@ def test_table_oracle_bitwise_equals_int_dot(M, K, N):
 def test_kernel_bitwise_parity_odd_shapes(M, K, N):
     a_q, a_s, wp, w_s = _rand_case(M, K, N, seed=M + K + N)
     want = np.asarray(ref.int4_matmul_ref(a_q, a_s, wp, w_s))
-    got = np.asarray(lut4_matmul(a_q, a_s, nmajor_to_kmajor(wp), w_s,
+    got = np.asarray(lut4_matmul(a_q, a_s, wp, w_s,
                                  bm=32, bn=32, bk=16, interpret=True))
     assert np.array_equal(want, got)
 
@@ -95,24 +94,25 @@ def test_kernel_bitwise_parity_odd_shapes(M, K, N):
 def test_kernel_parity_across_block_shapes(blocks):
     a_q, a_s, wp, w_s = _rand_case(48, 96, 160, seed=9)
     want = np.asarray(ref.int4_matmul_ref(a_q, a_s, wp, w_s))
-    got = np.asarray(lut4_matmul(a_q, a_s, nmajor_to_kmajor(wp), w_s,
+    got = np.asarray(lut4_matmul(a_q, a_s, wp, w_s,
                                  interpret=True, **blocks))
     assert np.array_equal(want, got)
 
 
 def test_ops_dispatch_modes(monkeypatch):
-    """interpret / XLA-twin dispatch agree bitwise, for both the serialized
-    and the kmajor entry points, including the env-var override."""
+    """interpret / XLA-twin dispatch of the one entry point agree bitwise,
+    including the env-var override."""
     a_q, a_s, wp, w_s = _rand_case(17, 33, 26, seed=3)
-    wk = nmajor_to_kmajor(wp)
     want = np.asarray(ref.int4_matmul_ref(a_q, a_s, wp, w_s))
-    for call in (lambda **kw: ops.lut4_matmul(a_q, a_s, wp, w_s, **kw),
-                 lambda **kw: ops.lut4_matmul_kmajor(a_q, a_s, wk, w_s, **kw)):
-        assert np.array_equal(want, np.asarray(call(interpret=True)))
-        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-        assert np.array_equal(want, np.asarray(call()))
-        monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
-        assert np.array_equal(want, np.asarray(call()))   # XLA twin on CPU
+
+    def call(**kw):
+        return np.asarray(ops.lut4_matmul(a_q, a_s, wp, w_s, **kw))
+
+    assert np.array_equal(want, call(interpret=True))
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert np.array_equal(want, call())
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
+    assert np.array_equal(want, call())               # XLA twin on CPU
 
 
 # ------------------------------------------------------- plan / serving ----
@@ -242,7 +242,7 @@ def test_lut4_blocks_constraints_and_candidates():
 
 def test_lut4_autotune_tag_roundtrip(isolated_cache):
     """tune() under op gemm.lut4 with a site tag persists, and the exact
-    key ops.lut4_matmul_kmajor looks up wins over the untagged default."""
+    key ops.lut4_matmul looks up wins over the untagged default."""
     cands = autotune.lut4_candidate_blocks(8, 512, 512)
     target = cands[-1]
 

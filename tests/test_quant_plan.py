@@ -245,25 +245,82 @@ def test_ckpt_experts_match_live_serving_semantics():
 
 
 def test_prepack_row_mult_covers_groups():
-    """prepack_tree's planar K-major twin must round K up to whole scale
-    groups (row_mult = 2G), for plain and layer-stacked weights alike;
-    per-channel scales keep row_mult = 2."""
-    from repro.core.qlinear import pack_weight_nd, prepack_tree
+    """pack_weight_nd writes the planar K-major layout with K rounded up to
+    whole scale groups per planar half (row_mult = 2G), for plain and
+    layer-stacked weights alike; per-channel scales round K to even."""
+    from repro.core.qlinear import pack_weight_nd
+    from repro.kernels.packing import unpack_kmajor
 
     rng = np.random.default_rng(2)
     w2 = jnp.asarray(rng.standard_normal((96, 16)), jnp.float32)
     w3 = jnp.asarray(rng.standard_normal((2, 96, 16)), jnp.float32)
     g32 = QuantConfig(backend="w4a16", group_size=32)
-    tree = prepack_tree({
-        "a": {"w_in": pack_weight_nd(w2, g32)},               # [3,1,16] scale
-        "b": {"w_in": pack_weight_nd(w3, g32)},               # [2,3,1,16]
-        "c": {"w_in": pack_weight_nd(w2, QuantConfig(backend="w4a16"))},
-    })
+    a = pack_weight_nd(w2, g32)                   # [3,1,16] scale
+    b = pack_weight_nd(w3, g32)                   # [2,3,1,16]
+    c = pack_weight_nd(w2, QuantConfig(backend="w4a16"))
+    d = pack_weight_nd(w2[:95], QuantConfig(backend="w4a16"))
+    assert a["scale"].shape == (3, 1, 16)
+    assert b["scale"].shape == (2, 3, 1, 16)
     # K=96, G=32 -> K' rounded to 2G=64 -> 128 -> K'/2 = 64 planar rows
-    assert tree["a"]["w_in"]["packed_km"].shape == (64, 16)
-    assert tree["b"]["w_in"]["packed_km"].shape == (2, 64, 16)
-    # per-channel: K'=96 (already even) -> 48 planar rows
-    assert tree["c"]["w_in"]["packed_km"].shape == (48, 16)
+    assert a["packed"].shape == (64, 16) and a["packed"].dtype == jnp.uint8
+    assert b["packed"].shape == (2, 64, 16)
+    # per-channel: K'=96 (already even) -> 48 planar rows; odd K=95 -> 96
+    assert c["packed"].shape == (48, 16)
+    assert d["packed"].shape == (48, 16)
+    # the padding rows are zero int4 values
+    assert not np.asarray(unpack_kmajor(a["packed"])[96:]).any()
+    assert not np.asarray(unpack_kmajor(d["packed"])[95:]).any()
+
+
+def _master_of(params, path):
+    """The float master a packed site came from: a per-repeat split tree
+    (``layers/r{r}/...``) indexes repeat r of the stacked master."""
+    r = None
+    if path[0] == "layers" and path[1][0] == "r" and path[1][1:].isdigit():
+        r, path = int(path[1][1:]), (path[0],) + path[2:]
+    node = params
+    for k in path:
+        node = node[k]
+    return node if r is None else node[r]
+
+
+@pytest.mark.parametrize("group", [0, 32], ids=["per_channel", "g32"])
+@pytest.mark.parametrize("stacked", [True, False], ids=["stacked", "plain"])
+def test_serving_tree_holds_one_layout(monkeypatch, group, stacked):
+    """On a Pallas backend the serving tree holds each packed weight once:
+    exactly {"packed", "scale"} per site (no second layout beside it), and
+    the packed bytes are K'·N/2 per site, K' rounded up to whole planar
+    halves.  d_model 96 with G=32 makes K=96 sites pad to K'=128."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    cfg = get_config("qwen2-0.5b").reduced(n_layers=2, d_model=96)
+    spec = "*=w4a16_packed" + (f"/g{group}" if group else "")
+    if not stacked:                   # non-uniform: per-repeat 2-D weights
+        spec = "block[0].*=float;" + spec
+    params = init_model(jax.random.PRNGKey(0), cfg)
+    tree = qp.pack_for_serving(params, cfg, Runtime(quant_plan=spec,
+                                                    **RT_KW))
+    assert ("r0" not in tree["layers"]) == stacked
+
+    sites, got, want = 0, 0, 0
+    for kp, node in jax.tree_util.tree_flatten_with_path(
+            tree, is_leaf=lambda n: isinstance(n, dict) and "packed" in n)[0]:
+        if not isinstance(node, dict):
+            continue
+        assert set(node) == {"packed", "scale"}, kp
+        path = tuple(str(getattr(k, "key", k)) for k in kp)
+        master = _master_of(params, path)
+        K, N = master.shape[-2:]
+        rm = 2 * group if group and group < K else 2
+        Kp = -(-K // rm) * rm
+        lead = master.shape[:-2]
+        assert node["packed"].shape == (*lead, Kp // 2, N), path
+        assert node["packed"].dtype == jnp.uint8
+        assert node["packed"].ndim == (3 if stacked else 2)
+        sites += 1
+        got += node["packed"].nbytes
+        want += int(np.prod(lead, dtype=np.int64)) * Kp * N // 2
+    assert sites >= 4
+    assert got == want
 
 
 # ---------------------------------------------------------- checkpoints ----
@@ -277,7 +334,7 @@ def test_quantized_ckpt_roundtrip_bit_exact(tmp_path):
     assert not any(".tmp_" in n for n in os.listdir(tmp_path))
 
     restored, manifest = restore_quantized(str(tmp_path))
-    assert manifest["format"] == "quantized-v1"
+    assert manifest["format"] == "quantized-v2"
     assert qp.plan_from_dict(manifest["plan"]) == plan
 
     # the optional plan guard: a Runtime whose active plan differs from the
@@ -300,6 +357,20 @@ def test_quantized_ckpt_roundtrip_bit_exact(tmp_path):
     assert restored["layers"]["r0"]["u0"]["attn"]["wq"].dtype == jnp.float32
     assert restored["layers"]["r1"]["u0"]["attn"]["wq"]["packed"].dtype \
         == jnp.uint8
+
+
+def test_restore_refuses_v1_checkpoint(tmp_path):
+    """A quantized-v1 manifest holds the retired N-major layout: restoring
+    it is refused loudly, naming the way out (re-pack from masters)."""
+    save_quantized(str(tmp_path), 0, _params(), CFG,
+                   plan=get_plan("*=w4a16_packed"))
+    mpath = tmp_path / "step_00000000" / "manifest.json"
+    manifest = json.loads(mpath.read_text())
+    assert manifest["format"] == "quantized-v2"
+    manifest["format"] = "quantized-v1"
+    mpath.write_text(json.dumps(manifest))
+    with pytest.raises(ValueError, match="N-major.*save_quantized"):
+        restore_quantized(str(tmp_path))
 
 
 def test_quantized_ckpt_serves_bit_identical(tmp_path):
